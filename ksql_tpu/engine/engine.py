@@ -2076,8 +2076,11 @@ class KsqlEngine:
                         self.fallback_reasons.get(str(e), 0) + 1
                     )
             except Exception as e:  # noqa: BLE001 — mesh/compile failures
-                # degrade to single-device rather than abort the statement
-                self._on_error("distributed-lowering", e)
+                self._lowering_failed(
+                    "distributed-lowering", e,
+                    self._classify_plan_static(plan, handle),
+                    rungs=("distributed",), count=live(),
+                )
         if executor is None and backend != "oracle":
             from ksql_tpu.compiler.jax_expr import DeviceUnsupported
             from ksql_tpu.runtime.device_executor import DeviceExecutor
@@ -2104,12 +2107,12 @@ class KsqlEngine:
                         self.fallback_reasons.get(str(e), 0) + 1
                     )
             except Exception as e:  # noqa: BLE001 — any construction failure
-                # (XLA compile error, layout bug, OOM sizing) must not abort
-                # the statement when the oracle can still run it; surface it
-                # through the processing log and fall back
                 if backend == "device-only":
                     raise
-                self._on_error("device-lowering", e)
+                self._lowering_failed(
+                    "device-lowering", e,
+                    self._classify_plan_static(plan, handle), count=live(),
+                )
         if executor is None:
             executor = OracleExecutor(
                 plan, self.broker, self.registry,
@@ -2223,6 +2226,33 @@ class KsqlEngine:
             executor.batch_emit_callback = on_emit_batch
             dev.collect_raw_emits = bool(handle.push_batch_listeners)
         return executor
+
+    def _lowering_failed(self, where: str, exc: Exception, decision,
+                         rungs=("device", "distributed"),
+                         count: bool = True) -> None:
+        """An executor build raised something other than DeviceUnsupported.
+
+        Where the static classifier places the plan on the rung that just
+        failed (``rungs``), the plan lowers and the failure is the
+        device's own — an XLA compile error, an allocation, the mesh — so
+        it must not be hidden behind the next rung down: the statement
+        fails (a restart lands in the ERROR/retry ladder instead).  Where
+        the classifier's own probe cannot construct the lowering either
+        (plan analysis raising on both sides, as old serialized plans
+        naming functions the registry has dropped do), the plan never was
+        device-eligible and the next rung is the documented one, logged
+        and counted like a DeviceUnsupported reason."""
+        if decision.backend in rungs:
+            raise KsqlException(
+                f"{where} failed on a plan the static classifier places on "
+                f"the {decision.backend} backend: {type(exc).__name__}: {exc}"
+            ) from exc
+        self._on_error(where, exc)
+        if count:  # a fenced-off rebuild's discarded build does not count
+            reason = f"construction failed: {exc}"
+            self.fallback_reasons[reason] = (
+                self.fallback_reasons.get(reason, 0) + 1
+            )
 
     def _mqo_enabled(self) -> bool:
         return cfg._bool(self.effective_property(cfg.MQO_ENABLE, True))
@@ -4617,7 +4647,10 @@ class KsqlEngine:
             except Exception as e:  # noqa: BLE001
                 if backend == "device-only":
                     raise
-                self._on_error("device-lowering", e)
+                self._lowering_failed(
+                    "device-lowering", e,
+                    self._classify_transient_static(planned.plan),
+                )
         if executor is None:
             self.annotate_serde_semantics(planned.plan)
             executor = OracleExecutor(

@@ -9,14 +9,20 @@ modes are supported (MODE_JSON / MODE_JSON_SINGLE / MODE_DELIMITED);
 rows the native grammar cannot decode bit-identically to the Python
 serde come back with ``row_ok`` False and the caller replays them.
 
-The shared library builds on first use with g++ (no external deps) and is
-cached next to the source; every consumer falls back to the pure-Python
-decode path when the toolchain or build is unavailable.
+The shared library builds on first use with g++ (no external deps) from
+ingest.cc and is cached next to it under a name that carries the source's
+content hash, so a library on disk is always the build of the source on
+disk (file times say nothing after a tree copy).  Every consumer falls back
+to the pure-Python decode path when the toolchain or build is unavailable;
+``build_error()`` says why.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -26,11 +32,12 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "ingest.cc")
-_LIB = os.path.join(_DIR, "_libingest.so")
+#: every build of the library, whatever source it came from (git-ignored)
+LIB_GLOB = os.path.join(_DIR, "_libingest*.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_failed = False
+_error: Optional[str] = None  # why the library is unavailable, once known
 
 # field type codes (mirror ingest.cc FieldType)
 FT_BIGINT, FT_INT, FT_DOUBLE, FT_BOOLEAN, FT_STRING = 0, 1, 2, 3, 4
@@ -49,13 +56,33 @@ _NP_OF = {
 }
 
 
-def _build() -> Optional[ctypes.CDLL]:
-    if not os.path.exists(_LIB) or (
-        os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-    ):
-        cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _LIB]
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    lib = ctypes.CDLL(_LIB)
+def lib_path() -> str:
+    """The library built from ingest.cc as it is on disk now."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_libingest.{digest}.so")
+
+
+def _build() -> ctypes.CDLL:
+    path = lib_path()
+    if not os.path.exists(path):
+        # build beside the target and rename: a concurrent process (test
+        # workers, bench children) sees a whole library or none
+        tmp = f"{path}.{os.getpid()}.tmp"
+        cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
+        try:
+            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+            os.replace(tmp, path)
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+        for stale in glob.glob(LIB_GLOB):
+            if stale != path:
+                try:
+                    os.unlink(stale)
+                except OSError:
+                    pass  # another process still maps it; harmless
+    lib = ctypes.CDLL(path)
     lib.ingest_parse_batch.restype = ctypes.c_void_p
     lib.ingest_parse_batch.argtypes = [
         ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64), ctypes.c_int,
@@ -87,21 +114,34 @@ def _build() -> Optional[ctypes.CDLL]:
 def get_lib() -> Optional[ctypes.CDLL]:
     """The loaded native library, building it on first use; None when the
     toolchain is unavailable (callers use the Python path)."""
-    global _lib, _failed
-    if _lib is not None or _failed:
+    global _lib, _error
+    if _lib is not None or _error is not None:
         return _lib
     with _lock:
-        if _lib is not None or _failed:
+        if _lib is not None or _error is not None:
             return _lib
         try:
             _lib = _build()
-        except Exception:  # noqa: BLE001 — no compiler / bad env: fall back
-            _failed = True
+        except Exception as e:  # noqa: BLE001 — no compiler / bad env: fall back
+            stderr = (getattr(e, "stderr", None) or b"")[-500:]
+            _error = (
+                f"{type(e).__name__}: {e} "
+                + stderr.decode("utf-8", "replace")
+            ).strip()
+            logging.getLogger(__name__).warning(
+                "native ingest unavailable, sources decode per record in "
+                "Python: %s", _error,
+            )
     return _lib
 
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def build_error() -> Optional[str]:
+    """Why ``available()`` is False (None while it is True or untried)."""
+    return _error
 
 
 def parse_json_batch(

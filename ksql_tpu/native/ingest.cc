@@ -16,7 +16,8 @@
 // b"\x00" + utf8, little-endian signed.  The BLAKE2b core below follows
 // RFC 7693.
 //
-// Build: g++ -O3 -shared -fPIC ingest.cc -o _libingest.so  (no deps).
+// Build (native/__init__.py does it on first use, no deps):
+//   g++ -O3 -shared -fPIC -std=c++17 ingest.cc -o _libingest.<sha256 of this file>.so
 
 #include <cstdint>
 #include <cstring>

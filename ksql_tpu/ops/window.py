@@ -30,7 +30,18 @@ from __future__ import annotations
 import math
 from typing import Dict, Tuple
 
+import jax
 import jax.numpy as jnp
+
+
+def running_max(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive running maximum (per-record stream time over a batch).
+
+    Same values as ``jax.lax.cummax``, which lowers to a reduce-window: on
+    int64 the v5e compiler takes 7 s for it at 32,768 elements, 54 s at
+    40,960 and 81 s at 65,536; the scan form takes about 1 s at any of
+    them."""
+    return jax.lax.associative_scan(jnp.maximum, x)
 
 
 def tumbling_starts(ts: jnp.ndarray, size_ms: int) -> jnp.ndarray:

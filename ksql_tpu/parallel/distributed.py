@@ -23,12 +23,8 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # JAX ≥ 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from ksql_tpu.common import faults, tracing
 from ksql_tpu.common.batch import HostBatch
@@ -315,8 +311,7 @@ class DistributedDeviceQuery:
             # the GlobalKTable analog), so stream-side probes stay local and
             # no join-key exchange is needed.  The batch ships pre-stacked
             # [n_shards, ...] (one identical lane per shard) so every array
-            # entering the trace is device-varying — jax.lax.pcast, the
-            # in-trace replicated→varying cast, only exists on newer jax
+            # entering the trace is device-varying
             def local_table_step(state, arrays):
                 state, emits = self.c._trace_table_step(
                     strip(state), strip(arrays)

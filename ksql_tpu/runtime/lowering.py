@@ -51,6 +51,7 @@ from ksql_tpu.execution import expressions as ex
 from ksql_tpu.execution import steps as st
 from ksql_tpu.execution.interpreter import ExpressionCompiler, TypeResolver
 from ksql_tpu.functions.registry import FunctionRegistry
+from ksql_tpu.ops import session_merge
 from ksql_tpu.ops import window as W
 from ksql_tpu.ops.device_aggs import DeviceAgg, compile_device_agg
 from ksql_tpu.ops.hash_store import (
@@ -2146,19 +2147,24 @@ class CompiledDeviceQuery:
             w_lane * width + member.size_ms + member.grace_ms > max_ts_pre
         )
         mask = act_lane & covers & open_w
-        # one lane per distinct (slot, window): sort-based first-occurrence
-        # (two touched slices of one key can cover the same window)
+        # one lane per distinct (slot, window) — two touched slices of one
+        # key can cover the same window — and of those the lowest lane
+        # index, as a stable sort by (slot, window) would pick.  Claimed by
+        # scatter-min into a cell per (slot, window), not sorted: XLA's TPU
+        # sort of these nn lanes (6 operand words) took 3 minutes to
+        # compile at the engine's default capacity.  The ring-wrap cut in
+        # pre_exchange keeps a batch's live slices within ring - 2 of each
+        # other and a window starts at most S - 1 slices before a slice it
+        # covers, so one slot's masked windows span fewer than ring + S
+        # slices and `window mod (ring + S)` names each of them apart.
+        cells = self.slice_ring + S
         eff_slot = jnp.where(mask, slot_lane, dump)
-        eff_w = jnp.where(mask, w_lane, jnp.int64(np.iinfo(np.int64).max))
-        lane_idx = jnp.arange(nn)
-        order = jnp.lexsort((lane_idx, eff_w, eff_slot))
-        so_s, so_w = eff_slot[order], eff_w[order]
-        first = (
-            (so_s != jnp.concatenate([jnp.full((1,), -1, so_s.dtype), so_s[:-1]]))
-            | (so_w != jnp.concatenate([so_w[:1] + 1, so_w[:-1]]))
-        ).at[0].set(True)
-        winner = jnp.zeros(nn, bool).at[order].set(first & (so_s != dump))
-        winner = winner & mask
+        cell = jnp.remainder(w_lane, cells).astype(jnp.int32)
+        lane_idx = jnp.arange(nn, dtype=jnp.int32)
+        claim = jnp.full(
+            (self.store_capacity + 1, cells), nn, jnp.int32
+        ).at[eff_slot, cell].min(lane_idx)
+        winner = mask & (claim[eff_slot, cell] == lane_idx)
         env, row_ts, dec_exceeded = self._combine_windows(
             store, slot_lane, w_lane, member
         )
@@ -3102,11 +3108,11 @@ class CompiledDeviceQuery:
         # stream_time/side_max split
         neg64 = np.iinfo(np.int64).min
         cm_global = jnp.maximum(
-            jax.lax.cummax(jnp.where(arrays["row_valid"], ts, neg64)),
+            W.running_max(jnp.where(arrays["row_valid"], ts, neg64)),
             state["max_ts"],
         )
         cm_side = jnp.maximum(
-            jax.lax.cummax(jnp.where(arrays["row_valid"], ts, neg64)),
+            W.running_max(jnp.where(arrays["row_valid"], ts, neg64)),
             state[f"ss{side}_smax"],
         )
         swin = self.ss_after if side == "l" else self.ss_before
@@ -3511,7 +3517,7 @@ class CompiledDeviceQuery:
         # time in ARRIVAL order — computed before any exchange, matching
         # the oracle's max_ts-at-receive semantics)
         cm = jnp.maximum(
-            jax.lax.cummax(
+            W.running_max(
                 jnp.where(arrays["row_valid"], ts, np.iinfo(np.int64).min)
             ),
             max_ts,
@@ -3553,15 +3559,10 @@ class CompiledDeviceQuery:
         m = n * (S + 1)
         neg = np.iinfo(np.int64).min
 
-        # ---- first active occurrence of each key in the batch
-        order0 = jnp.lexsort((jnp.arange(n), jnp.where(active, khash, 0)))
-        khs = jnp.where(active, khash, 0)[order0]
-        acts = active[order0]
-        firsts = jnp.concatenate(
-            [jnp.ones(1, bool), khs[1:] != khs[:-1]]
-        ) & acts
-        # first active row per key: among actives sorted by (khash, idx)
-        first_occ = jnp.zeros(n, bool).at[order0].set(firsts) & active
+        # ---- the one sort of the step: rows by (key, ts); it also yields
+        # the first active occurrence of each key in the batch
+        row_order = session_merge.sort_rows(khash, ts, active)
+        first_occ = row_order.first_occ
 
         # ---- item arrays: [rows | store session i=0..S-1 per first-occ row]
         it_kh = [jnp.where(active, khash, 0)]
@@ -3614,8 +3615,11 @@ class CompiledDeviceQuery:
         start = jnp.where(alive, start, 0)
         end = jnp.where(alive, end, 0)
 
-        # ---- sort by (key, start) and segmented interval-merge
-        orderm = jnp.lexsort((start, kh))
+        # ---- (key, start) order and segmented interval-merge
+        orderm = session_merge.merged_order(
+            row_order, active,
+            jnp.stack(it_alive[1:]), jnp.stack(it_start[1:]),
+        )
         kh, start, end = kh[orderm], start[orderm], end[orderm]
         alive, isrow, slot = alive[orderm], isrow[orderm], slot[orderm]
         rowidx = rowidx[orderm]
@@ -3905,14 +3909,14 @@ class CompiledDeviceQuery:
         # delta: keeps the cummax scan off the hot path).
         if self.suppress:
             cm = jnp.maximum(
-                jax.lax.cummax(jnp.where(active, ts, np.iinfo(np.int64).min)),
+                W.running_max(jnp.where(active, ts, np.iinfo(np.int64).min)),
                 max_ts,
             )
             active = active & (wstart + wsize + self.grace_ms > cm)
             # emission clock: per-record stream time over ALL raw source
             # rows (pre-filter, pre-expansion; length n not nn — the
             # emission test only needs the sorted watermark value set)
-            cm_emit = jax.lax.cummax(
+            cm_emit = W.running_max(
                 jnp.where(arrays["row_valid"], arrays["ts"], np.iinfo(np.int64).min)
             )
             if emit_clock is not None:

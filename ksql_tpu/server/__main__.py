@@ -34,18 +34,9 @@ def main(argv=None) -> int:
     if args.queries_file:
         props["ksql.queries.file"] = args.queries_file
 
-    import os
+    from ksql_tpu.runtime import compile_cache
 
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        # a preloaded accelerator registration pins the platform at boot;
-        # honor the env var the way tests/bench do
-        import jax
-
-        try:
-            jax.config.update("jax_platforms", plat)
-        except RuntimeError:
-            pass
+    compile_cache.place()
 
     from ksql_tpu.common.config import KsqlConfig
     from ksql_tpu.engine.engine import KsqlEngine

@@ -489,8 +489,12 @@ class SharedPushPipeline:
                 self.backend = "device"
             except DeviceUnsupported:
                 pass
-            except Exception as e:  # noqa: BLE001 — compile failure must
-                engine._on_error(f"push-registry:{self.id}", e)  # not kill
+            except Exception as e:  # noqa: BLE001 — the engine's rule:
+                # only a plan that never lowered takes the oracle quietly
+                engine._lowering_failed(
+                    f"push-registry:{self.id}", e,
+                    engine._classify_transient_static(self._planned.plan),
+                )
         if executor is None:
             engine.annotate_serde_semantics(self._planned.plan)
             executor = OracleExecutor(

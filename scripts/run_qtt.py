@@ -15,21 +15,9 @@ QTT_DIR = "/root/reference/ksqldb-functional-tests/src/test/resources/query-vali
 
 def run_one(fname):
     if os.environ.get("QTT_BACKEND") == "device":
-        # device-mode QTT runs on CPU jax: the one real TPU cannot take 8
-        # compiling workers, and env vars are too late (the environment
-        # preloads jax against the accelerator) — reconfigure explicitly
-        import jax
-
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except RuntimeError as e:
-            import sys
-
-            print(
-                f"WARNING: could not pin QTT worker to CPU jax ({e}); "
-                "device-mode cases may compile on the real accelerator",
-                file=sys.stderr,
-            )
+        # device-mode QTT runs on CPU jax: a chip belongs to one process,
+        # and this runs 8 compiling workers (set before jax is imported)
+        os.environ["JAX_PLATFORMS"] = "cpu"
     from ksql_tpu.tools.qtt import run_file
     path = os.path.join(QTT_DIR, fname)
     try:

@@ -1,7 +1,7 @@
 """BENCH smoke (tier-2, ``slow``-marked): drive bench.py's child entry on
-tiny BENCH_SMOKE=1 sizes so the bench import/shape path — including the
-multi-chip ``engine_e2e_dist`` variant — can't silently rot between
-hardware runs.  Timing values are asserted only for sanity (> 0), never for
+tiny BENCH_SMOKE=1 sizes (its rehearsal, the only way it runs off the
+chip) so the bench import/shape path — including the multi-chip
+``engine_e2e_dist`` variant — can't silently rot between hardware runs.  Timing values are asserted only for sanity (> 0), never for
 magnitude: CI machines are not the benchmark target."""
 
 import os
@@ -69,10 +69,16 @@ def test_bench_watchdog_contains_hung_bench(tmp_path):
         [sys.executable, os.path.join(ROOT, "bench.py")],
         capture_output=True, text=True, timeout=560, cwd=ROOT, env=env,
     )
-    assert proc.returncode == 0, proc.stderr[-2000:]
+    # the hung bench is contained AND counted: the others ran, the process
+    # says one failed
+    assert proc.returncode == 1, proc.stderr[-2000:]
     lines = [l for l in proc.stdout.splitlines() if l.startswith("{")]
     assert lines, proc.stdout
     result = json.loads(lines[-1])
+    # every line names what it ran on, and a rehearsal says it is one
+    assert result["extra"]["platform"] == "cpu"
+    assert result["extra"]["device_kind"] and result["extra"]["devices"] >= 1
+    assert result["extra"]["rehearsal"] is True
     # the headline bench completed and its number survived the hang
     assert result["value"] > 0
     wf = result["extra"]["window_family_events_s"]
@@ -141,3 +147,4 @@ def test_tracing_overhead_under_5pct():
     assert overhead < 0.05, (
         f"tracing overhead {overhead:.1%} (on={t_on:.3f}s off={t_off:.3f}s)"
     )
+

@@ -130,10 +130,14 @@ def test_windowed_pull_from_device_store():
     assert got == {("/a", 0): 1, ("/a", 2000): 1}
 
 
-# -------------------------------------------- fallback on generic failure
+# ------------------------------------- generic failure on a device-eligible plan
 
 
-def test_generic_device_failure_falls_back_to_oracle(monkeypatch):
+def test_generic_device_failure_fails_the_statement(monkeypatch):
+    """A build failure that is not DeviceUnsupported, on a plan the static
+    classifier places on the device, is the device's own (compile error,
+    allocation): the statement fails instead of quietly running on the
+    oracle."""
     import ksql_tpu.runtime.device_executor as dx
 
     def boom(*a, **k):
@@ -142,10 +146,31 @@ def test_generic_device_failure_falls_back_to_oracle(monkeypatch):
     monkeypatch.setattr(dx, "CompiledDeviceQuery", boom)
     e = KsqlEngine(KsqlConfig({RUNTIME_BACKEND: "device"}))
     e.execute_sql(DDL)
+    with pytest.raises(KsqlException, match="device-lowering.*simulated XLA"):
+        e.execute_sql(
+            "CREATE TABLE C AS SELECT URL, COUNT(*) AS CNT FROM PV GROUP BY URL;"
+        )
+    assert not e.queries
+    assert e.fallback_reasons == {}
+
+
+def test_unlowerable_plan_still_takes_the_oracle_rung(monkeypatch):
+    """Where the classifier's own probe cannot construct the lowering
+    either, the plan never was device-eligible: the oracle rung stays,
+    logged and counted."""
+    import ksql_tpu.runtime.lowering as lw
+
+    def boom(self, step):
+        raise RuntimeError("plan analysis broke")
+
+    monkeypatch.setattr(lw.CompiledDeviceQuery, "_analyze", boom)
+    e = KsqlEngine(KsqlConfig({RUNTIME_BACKEND: "device"}))
+    e.execute_sql(DDL)
     e.execute_sql("CREATE TABLE C AS SELECT URL, COUNT(*) AS CNT FROM PV GROUP BY URL;")
     handle = list(e.queries.values())[0]
-    assert handle.backend != "device"
+    assert handle.backend == "oracle"
     assert any("device-lowering" in w for w, _ in e.processing_log)
+    assert list(e.fallback_reasons) == ["construction failed: plan analysis broke"]
     _feed(e, [{"URL": "/a", "UID": 1, "LAT": 1.0}])
     res = e.execute_sql("SELECT * FROM C;")[0]
     assert res.rows == [{"URL": "/a", "CNT": 1}]

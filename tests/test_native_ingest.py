@@ -47,10 +47,9 @@ def test_parse_batch_values_and_fallback():
 def _run_engine(native_on):
     import ksql_tpu.native as nat
 
-    saved = (nat._failed, nat._lib)
-    nat._failed = not native_on
+    saved = (nat._error, nat._lib)
     if not native_on:
-        nat._lib = None
+        nat._error, nat._lib = "disabled by the test", None
     try:
         e = KsqlEngine(KsqlConfig({RUNTIME_BACKEND: "device-only"}))
         e.execute_sql(
@@ -82,7 +81,7 @@ def _run_engine(native_on):
             used,
         )
     finally:
-        nat._failed, nat._lib = saved
+        nat._error, nat._lib = saved
 
 
 def test_engine_parity_native_vs_python():

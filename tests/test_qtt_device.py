@@ -24,6 +24,11 @@ FILES = [
 ]
 
 
+@pytest.mark.skipif(
+    not os.path.isdir(QTT_DIR),
+    reason="the QTT corpus is external to this repo (ksqlDB's "
+    f"query-validation-tests, expected at {QTT_DIR})",
+)
 @pytest.mark.parametrize("fname", FILES)
 def test_device_backend_matches_oracle_on_qtt(fname, monkeypatch):
     from ksql_tpu.tools.qtt import run_file

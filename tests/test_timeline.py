@@ -645,10 +645,11 @@ def _plog_registry():
 
 _CATEGORY_RE = re.compile(r"^[a-z][a-z0-9._-]*$")
 #: literal `where` first-arguments at every emission call site (the
-#: overload manager's ``_note`` forwards into ``_plog_append``): a string
+#: overload manager's ``_note`` forwards into ``_plog_append``, the
+#: engine's ``_lowering_failed`` into ``_on_error``): a string
 #: (or f-string) whose category prefix ends at ':', '{' or the quote
 _EMIT_RE = re.compile(
-    r"(?:_plog_append|_on_error|on_error|_note)\(\s*f?[\"']"
+    r"(?:_plog_append|_on_error|on_error|_note|_lowering_failed)\(\s*f?[\"']"
     r"([a-z][a-z0-9._-]*)(?=[:{\"'])"
 )
 
