@@ -464,8 +464,10 @@ def _drive_served(phase, srv, engine, corpus, sizes, platform, backend,
     rec = engine.trace_recorders.get(qid)
     stages = rec.stage_stats() if rec is not None else {}
     brief = {
-        name: {k: st[k] for k in ("n", "total_ms", "p50_ms", "p99_ms",
-                                  "jit_miss", "jit_hit") if k in st}
+        name: {k: st[k] for k in ("n", "total_ms", "self_ms", "p50_ms", "p99_ms",
+                                  "jit_miss", "jit_hit", "probe_rounds",
+                                  "sampled", "h2d_bytes", "d2h_bytes")
+               if k in st}
         for name, st in stages.items()
     }
     compile_s = round(stages.get("device.compile", {}).get("total_ms", 0.0) / 1e3, 2)

@@ -93,7 +93,7 @@ def selected_workloads(only: str) -> set:
     return out
 
 #: stages the gate enforces (the ISSUE-named compile / execute / exchange
-#: / transfer / sink set plus the push-serving fan-out stages, plus —
+#: / sink set plus the push-serving fan-out stages, plus —
 #: since the line-rate serde PR made both serde edges batch-optimized
 #: hot paths — ``deserialize`` and ``sink.produce``.  Oracle ``stage:*``
 #: chains and poll stay informational: corpus-shaped, not
@@ -101,7 +101,6 @@ def selected_workloads(only: str) -> set:
 GATED_STAGES = frozenset({
     "device.compile",
     "device.execute",
-    "device.transfer",
     "deserialize",
     "exchange",
     "sink.produce",

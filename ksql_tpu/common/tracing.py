@@ -21,23 +21,53 @@ Design constraints:
 * **Cheap when enabled**: hot paths (one call per record) use stage
   *accumulators* (two ``perf_counter`` reads + a dict update), not span
   objects; spans are reserved for per-batch / per-tick boundaries.
+* **Self time is recorded, not derived**: a span on exit adds its duration
+  to its parent's child time and books ``self_ms`` (own duration minus
+  child time) on its stage; a timed ``stage()`` call counts as a child of
+  the span it was made under.  ``tick``'s ``self_ms`` is the part of a
+  tick under no span at all.
+* **One clock with the device trace**: every span, and the tick itself
+  (``ksql.tick#<query>#<seq>``), is also entered as a
+  ``jax.profiler.TraceAnnotation`` once jax is loaded, so a profile of a
+  served query holds the host's spans above the device's operations.  It
+  records nothing while no profiler session is open.
 * **No global registry**: recorders live on the engine
   (``KsqlEngine.trace_recorders``) so concurrent engines in one process
   (tests, sandboxes, multi-node clusters) never share or clobber traces.
   Only the *active* trace rides a thread-local, because executors have no
   engine reference.
 
-Stage naming convention (the seams of ISSUE 3's tentpole):
+Stage naming convention, in tick order (indented: nested under the stage
+above; "span" has a place in time, "total" is an accumulator, "counters"
+is never timed and reports no time):
 
 ==================  ========================================================
-``poll``            Consumer.poll for the tick
-``deserialize``     decode_source_record (all backends)
-``stage:<ctx>``     one oracle ExecutionStep node (Filter/Project/Join/...)
-``device.compile``  a device step that jit-traced/compiled (cache miss)
-``device.execute``  a device step served from the jit cache (hit)
-``device.transfer`` host<->device bytes (h2d_bytes / d2h_bytes counters)
-``exchange``        distributed all-to-all (rows / bytes counters)
-``sink.produce``    SinkWriter.produce (all backends)
+``tick``            the whole poll tick of one query (total, booked when
+                    the tick ends; ``self_ms`` = time under no span)
+``poll``            span: Consumer.poll for the tick (``rows``)
+``process``         span: the per-record loop handing records to the
+                    executor (a full micro-batch runs the stages below
+                    inside it)
+``deserialize``     decode_source_record (total, all backends); a span per
+                    chunk in the native C++ tier
+``stage:<ctx>``     total: one oracle ExecutionStep node (Filter/Join/...)
+``drain``           span: the executor's end-of-tick flush
+``batch.assemble``  span: key decode, column encode, dictionary learn and
+                    the copy into the padded step buffers
+``device.compile``  span: a device step that jit-traced/compiled (miss)
+``device.execute``  span: a device step served from the jit cache (hit)
+``step.dispatch``     span: h2d of the batch + enqueue of the step
+                      (``h2d_bytes``)
+``step.wait``         span: the host blocked on the step's outputs
+``emit.decode``       span: load check, d2h of the emit columns, row
+                      building (``d2h_bytes``)
+``device.step``     counters the step program reports about its own work
+                    (``probe_rounds`` over ``sampled`` load checks)
+``exchange``        counters: distributed all-to-all (rows / bytes)
+``emit.dispatch``   span: emit callbacks, block encode, the per-emit loop
+``sink.produce``    total: SinkWriter.produce (all backends)
+``commit``          span: the tick's commit point (commit cursor, state
+                    epoch, changelog append, query metrics)
 ``poison.skip``     USER-classified records skipped by the poll loop
 ``checkpoint``      engine state snapshot (recorded under ``__engine__``)
 ``push.pipeline.step``  one shared push-registry pipeline pump (poll →
@@ -58,6 +88,7 @@ Stage naming convention (the seams of ISSUE 3's tentpole):
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from collections import deque
@@ -71,19 +102,28 @@ ENGINE_RECORDER = "__engine__"
 #: canonical display order for stage tables (EXPLAIN ANALYZE)
 _STAGE_RANK = {
     "poll": 0,
-    "deserialize": 1,
+    "process": 1,
+    "deserialize": 2,
     # stage:<ctx> ranks 10 (alpha within)
+    "drain": 18,
+    "batch.assemble": 19,
     "device.compile": 20,
     "device.execute": 21,
-    "device.transfer": 22,
-    "exchange": 23,
+    "step.dispatch": 22,
+    "step.wait": 23,
+    "emit.decode": 24,
+    "device.step": 25,
+    "exchange": 26,
+    "emit.dispatch": 29,
     "sink.produce": 30,
+    "commit": 31,
     "push.pipeline.step": 32,
     "push.tap.deliver": 33,
     "push.residual.kernel": 34,
     "poison.skip": 40,
     "checkpoint": 50,
     # cutover.* phases rank 45 (alpha within), below checkpoint
+    "tick": 60,  # the whole tick: the table's total row
 }
 
 
@@ -157,31 +197,56 @@ def jit_cache_size(fns) -> int:
     return n
 
 
+def _annotate(name: str):
+    """Enter a ``jax.profiler.TraceAnnotation`` and return it (its caller
+    exits it), or None until jax is loaded: no profiler session can be
+    open before that, and an oracle-only engine never pays jax's import
+    for its spans.  An annotation keeps the name it was entered with."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation(name)
+    annotation.__enter__()
+    return annotation
+
+
 class _Span:
-    __slots__ = ("trace", "name", "t0", "depth")
+    """One open span.  ``name`` and ``n`` (invocations the stage counts
+    for it) may be reassigned until exit: the device step learns only
+    afterwards whether it compiled, the native decode how many rows of
+    its chunk were good."""
+
+    __slots__ = ("trace", "name", "n", "t0", "depth", "child_s",
+                 "_annotation")
 
     def __init__(self, trace: "TickTrace", name: str):
         self.trace = trace
         self.name = name
+        self.n = 1
+        self.child_s = 0.0
 
     def __enter__(self):
         tr = self.trace
-        self.depth = tr._depth
-        tr._depth += 1
+        self.depth = len(tr._open)
         tr._open.append(self)
+        self._annotation = _annotate(self.name)
         self.t0 = _perf()
         return self
 
     def __exit__(self, *exc):
+        dur = _perf() - self.t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         tr = self.trace
-        tr._depth -= 1
         try:
             tr._open.remove(self)
         except ValueError:
             pass
-        dur = _perf() - self.t0
+        parent = tr._open[-1] if tr._open else tr
+        parent.child_s += dur
         tr.add_span(self.name, self.t0, dur, self.depth)
-        tr.stage(self.name, dur)
+        tr._book(self.name, dur, self.n,
+                 {"self_ms": (dur - self.child_s) * 1000.0})
         return False
 
 
@@ -190,7 +255,7 @@ class TickTrace:
 
     __slots__ = (
         "query_id", "seq", "started_at_ms", "dur_ms", "spans", "stages",
-        "status", "error", "keep", "_t0", "_depth", "_open", "_dumped",
+        "status", "error", "keep", "child_s", "_t0", "_open", "_dumped",
     )
 
     def __init__(self, query_id: str, seq: int):
@@ -205,8 +270,9 @@ class TickTrace:
         self.status = "OK"
         self.error: Optional[str] = None
         self.keep = True  # engine clears for empty ticks (ring hygiene)
+        #: seconds under depth-0 spans and timed stages outside any span
+        self.child_s = 0.0
         self._t0 = _perf()
-        self._depth = 0
         self._open: List[_Span] = []  # spans entered but not yet exited
         self._dumped = False
 
@@ -224,6 +290,17 @@ class TickTrace:
 
     def stage(self, name: str, dur_s: float = 0.0, n: int = 1,
               **counters) -> None:
+        """Accumulate one timed stage invocation; its time counts as a
+        child of the span it was made under (of the tick under none)."""
+        if dur_s:
+            (self._open[-1] if self._open else self).child_s += dur_s
+        self._book(name, dur_s, n, counters)
+
+    def counter(self, name: str, **counters) -> None:
+        self._book(name, 0.0, 0, counters)
+
+    def _book(self, name: str, dur_s: float, n: int,
+              counters: Dict[str, Any]) -> None:
         st = self.stages.get(name)
         if st is None:
             st = self.stages[name] = {"ms": 0.0, "n": 0}
@@ -232,15 +309,12 @@ class TickTrace:
         for k, v in counters.items():
             st[k] = st.get(k, 0) + v
 
-    def counter(self, name: str, **counters) -> None:
-        st = self.stages.get(name)
-        if st is None:
-            st = self.stages[name] = {"ms": 0.0, "n": 0}
-        for k, v in counters.items():
-            st[k] = st.get(k, 0) + v
-
     def finish(self) -> None:
-        self.dur_ms = round((_perf() - self._t0) * 1000.0, 3)
+        """Close the tick: its duration, and the ``tick`` stage whose
+        ``self_ms`` is the time no span or timed stage accounts for."""
+        dur = _perf() - self._t0
+        self.dur_ms = round(dur * 1000.0, 3)
+        self._book("tick", dur, 1, {"self_ms": (dur - self.child_s) * 1000.0})
 
     def to_dict(self) -> Dict[str, Any]:
         # a crash dump serializes mid-tick, before finish()/span exits run:
@@ -279,7 +353,7 @@ class tick:
     active trace and records it into the recorder on exit.  ``tick(None)``
     (tracing disabled) is a no-op that yields None."""
 
-    __slots__ = ("rec", "trace", "_prev")
+    __slots__ = ("rec", "trace", "_prev", "_annotation")
 
     def __init__(self, recorder: Optional["FlightRecorder"]):
         self.rec = recorder
@@ -288,15 +362,18 @@ class tick:
     def __enter__(self) -> Optional[TickTrace]:
         if self.rec is None:
             return None
-        self.trace = self.rec.begin()
+        tr = self.trace = self.rec.begin()
         self._prev = getattr(_TL, "trace", None)
-        _TL.trace = self.trace
-        return self.trace
+        _TL.trace = tr
+        self._annotation = _annotate(f"ksql.tick#{tr.query_id}#{tr.seq}")
+        return tr
 
     def __exit__(self, et, ev, tb):
         tr = self.trace
         if tr is None:
             return False
+        if self._annotation is not None:
+            self._annotation.__exit__(et, ev, tb)
         _TL.trace = self._prev
         if et is not None and tr.status == "OK":
             tr.status = "ERROR"
@@ -369,7 +446,10 @@ class FlightRecorder:
     def stage_stats(self) -> Dict[str, Dict[str, Any]]:
         """Per-stage aggregate: p50/p99 of per-tick stage time over the
         recorder window, plus cumulative invocation counts / total ms /
-        counters since the query started."""
+        counters since the query started.  A stage that was never timed
+        (``n`` = 0: byte and row counters) reports its counters and no
+        ``total_ms``/``p50_ms``/``p99_ms`` — a 0 there would read as
+        "this costs nothing"."""
         with self._lock:
             traces = list(self._ring)
             cum = {name: dict(st) for name, st in self._cum.items()}
@@ -380,15 +460,13 @@ class FlightRecorder:
         out: Dict[str, Dict[str, Any]] = {}
         for name, c in cum.items():
             xs = sorted(per_tick.get(name, []))
-            d: Dict[str, Any] = {
-                "ticks": len(xs),
-                "n": int(c.get("n", 0)),
-                "total_ms": round(float(c.get("ms", 0.0)), 3),
-                "p50_ms": _percentile(xs, 0.50),
-                "p99_ms": _percentile(xs, 0.99),
-            }
+            d: Dict[str, Any] = {"ticks": len(xs), "n": int(c.get("n", 0))}
+            if d["n"]:
+                d["total_ms"] = round(float(c.get("ms", 0.0)), 3)
+                d["p50_ms"] = _percentile(xs, 0.50)
+                d["p99_ms"] = _percentile(xs, 0.99)
             for k, v in c.items():
                 if k not in ("ms", "n"):
-                    d[k] = v
+                    d[k] = round(v, 3) if isinstance(v, float) else v
             out[name] = d
         return out

@@ -240,7 +240,7 @@ _NO_LITERAL = object()
 #: fallback_reasons entry for a distributed query whose source the C++
 #: ingest tier could decode single-device but whose executor kept the
 #: Python HostBatch path.  Since the mesh-aware lane split landed
-#: (DistributedDeviceQuery.process_columns) eligible plans engage the
+#: (DistributedDeviceQuery.split_columns) eligible plans engage the
 #: native tier directly, so this counter staying at zero is itself a
 #: pinned invariant; the constant remains for dashboards and the
 #: regression test that asserts it no longer fires
@@ -3426,41 +3426,44 @@ class KsqlEngine:
                 if alive():
                     self._query_failed(handle, e)
                 return n
-            if per_record and consumed:
-                # drained: every consumed record's emissions are durable.
-                # This end-of-tick pass also amortizes the state epoch for
-                # queries whose per-record snapshots blew the budget —
-                # one epoch per tick keeps commit == epoch consistent.
-                before = committed_idx
-                advance_commit()
-                if epoch_capable and committed_idx > before and alive():
-                    take_epoch_budgeted()
-            if records:
-                if not alive():
-                    return n  # abandoned mid-tick: the fence owns the rest
-                # a healthy tick after a restart closes the incident: the
-                # retry budget bounds CONSECUTIVE failures (crash-loops),
-                # not unrelated transient faults across the query lifetime
-                if handle.restart_count:
-                    handle.restart_count = 0
-                    handle.retry_backoff_ms = 0.0
-                if handle.shard_strikes:
-                    # consecutive-strike semantics: a clean tick clears
-                    # every suspect shard's streak (lifetime totals keep)
-                    handle.shard_strikes = {}
-                if handle.poison_bisect is not None:
-                    # a clean tick ends the bisection: full-size polls
-                    # resume (a later crash re-derives its own window)
-                    handle.poison_bisect = None
-                # tick commit point: everything above is durable in the
-                # in-memory sense — journal the dirty-state delta + this
-                # tick's sink emissions (runtime/changelog.py) so a kill
-                # -9 replays ticks-since-last-checkpoint, not the batch
-                self._changelog_append(handle, executor, consumer)
-                qm = self.metrics.for_query(handle.query_id)
-                qm.messages_in.mark(len(records))
-                qm.latency.record(_time.monotonic() - tick0)
-                qm.last_message_at_ms = int(_time.time() * 1000)
+            # the tick's commit point, as one span: commit cursor, state
+            # epoch, changelog append, query metrics
+            with tracing.span("commit"):
+                if per_record and consumed:
+                    # drained: every consumed record's emissions are durable.
+                    # This end-of-tick pass also amortizes the state epoch for
+                    # queries whose per-record snapshots blew the budget —
+                    # one epoch per tick keeps commit == epoch consistent.
+                    before = committed_idx
+                    advance_commit()
+                    if epoch_capable and committed_idx > before and alive():
+                        take_epoch_budgeted()
+                if records:
+                    if not alive():
+                        return n  # abandoned mid-tick: the fence owns the rest
+                    # a healthy tick after a restart closes the incident: the
+                    # retry budget bounds CONSECUTIVE failures (crash-loops),
+                    # not unrelated transient faults across the query lifetime
+                    if handle.restart_count:
+                        handle.restart_count = 0
+                        handle.retry_backoff_ms = 0.0
+                    if handle.shard_strikes:
+                        # consecutive-strike semantics: a clean tick clears
+                        # every suspect shard's streak (lifetime totals keep)
+                        handle.shard_strikes = {}
+                    if handle.poison_bisect is not None:
+                        # a clean tick ends the bisection: full-size polls
+                        # resume (a later crash re-derives its own window)
+                        handle.poison_bisect = None
+                    # tick commit point: everything above is durable in the
+                    # in-memory sense — journal the dirty-state delta + this
+                    # tick's sink emissions (runtime/changelog.py) so a kill
+                    # -9 replays ticks-since-last-checkpoint, not the batch
+                    self._changelog_append(handle, executor, consumer)
+                    qm = self.metrics.for_query(handle.query_id)
+                    qm.messages_in.mark(len(records))
+                    qm.latency.record(_time.monotonic() - tick0)
+                    qm.last_message_at_ms = int(_time.time() * 1000)
         return n
 
     # ------------------------------------------------------- state epochs
@@ -5254,9 +5257,10 @@ class KsqlEngine:
             rows.append({
                 "stage": name,
                 "count": st_["n"],
-                "p50Ms": st_["p50_ms"],
-                "p99Ms": st_["p99_ms"],
-                "totalMs": st_["total_ms"],
+                # a counter-only stage (never timed) has no time to show
+                "p50Ms": st_.get("p50_ms"),
+                "p99Ms": st_.get("p99_ms"),
+                "totalMs": st_.get("total_ms"),
                 "extra": _json.dumps(extra, sort_keys=True) if extra else "",
             })
         return StatementResult(

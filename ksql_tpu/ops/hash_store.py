@@ -139,6 +139,7 @@ def init_store(layout: StoreLayout) -> Dict[str, jnp.ndarray]:
     return store
 
 
+@jax.named_scope("probe_insert")
 def probe_insert(
     store: Dict[str, jnp.ndarray],
     capacity: int,
@@ -147,11 +148,14 @@ def probe_insert(
     key_reprs: Sequence[jnp.ndarray],
     knull: jnp.ndarray,
     active: jnp.ndarray,
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
-    """Resolve (and create) one slot per active row; returns (store, slots).
+) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray]:
+    """Resolve (and create) one slot per active row; returns (store, slots,
+    rounds).
 
     ``slots`` is int32 per row; inactive/overflowed rows get the dump slot
-    ``capacity``.
+    ``capacity``.  ``rounds`` (int32 scalar) is how many times the probe
+    loop ran: the longest probe sequence among the batch's rows, claim
+    retries included — 0 for a batch with no active row.
     """
     n = khash.shape[0]
     mask = capacity - 1
@@ -197,7 +201,7 @@ def probe_insert(
     # initial carries derive from varying inputs so the loop is well-typed
     # under shard_map's varying-manual-axes tracking (and a no-op otherwise)
     zero_i32 = (khash * 0).astype(jnp.int32)
-    _, occ, grave, kh, ws, slots, done, _ = jax.lax.while_loop(
+    rounds, occ, grave, kh, ws, slots, done, _ = jax.lax.while_loop(
         pending,
         body,
         (
@@ -224,19 +228,21 @@ def probe_insert(
     for i, repr_col in enumerate(key_reprs):
         store[f"key{i}"] = store[f"key{i}"].at[target].set(repr_col)
     store["knull"] = store["knull"].at[target].set(knull)
-    return store, jnp.where(done, slots, dump)
+    return store, jnp.where(done, slots, dump), rounds
 
 
+@jax.named_scope("probe_find")
 def probe_find(
     store: Dict[str, jnp.ndarray],
     capacity: int,
     khash: jnp.ndarray,
     wstart: jnp.ndarray,
     active: jnp.ndarray,
-) -> jnp.ndarray:
-    """Find-only probe (no insertion): one slot per active row, or the dump
-    slot ``capacity`` when the key is absent.  Used by join lookups against
-    a keyed store."""
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Find-only probe (no insertion): (slots, rounds) — one slot per
+    active row, or the dump slot ``capacity`` when the key is absent, and
+    how many times the probe loop ran (as ``probe_insert``).  Used by join
+    lookups against a keyed store."""
     mask = capacity - 1
     dump = jnp.int32(capacity)
     base = (mix64(khash ^ (wstart * _GOLD)) & mask).astype(jnp.int32)
@@ -263,11 +269,11 @@ def probe_find(
         return rounds + 1, slots, done, offset
 
     zero_i32 = (khash * 0).astype(jnp.int32)
-    _, slots, _, _ = jax.lax.while_loop(
+    rounds, slots, _, _ = jax.lax.while_loop(
         pending, body,
         (jnp.sum(zero_i32), zero_i32 + dump, zero_i32 != 0, zero_i32),
     )
-    return jnp.where(active, slots, dump)
+    return jnp.where(active, slots, dump), rounds
 
 
 def _slot_ranks(eff: jnp.ndarray) -> jnp.ndarray:
@@ -524,6 +530,7 @@ def _vec_topk(store, comp, j, contrib, slots, dump):
     store[f"a{j}"] = col.at[tgt].set(top)
 
 
+@jax.named_scope("scatter_combine")
 def scatter_combine(
     store: Dict[str, jnp.ndarray],
     layout: StoreLayout,
@@ -589,6 +596,7 @@ def scatter_combine(
     return store
 
 
+@jax.named_scope("emit_compact")
 def winners_per_slot(slots: jnp.ndarray, active: jnp.ndarray, capacity: int) -> jnp.ndarray:
     """Mask selecting one representative row per distinct touched slot
     (used to emit exactly one change per key per batch)."""

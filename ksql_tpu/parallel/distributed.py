@@ -405,7 +405,7 @@ class DistributedDeviceQuery:
                 for k, v in arrays.items()
             }
             tracing.counter(
-                "device.transfer",
+                "step.dispatch",
                 h2d_bytes=int(sum(v.nbytes for v in arrays.values())),
             )
             self.state, metrics = self._table_step(self.state, arrays)
@@ -446,7 +446,7 @@ class DistributedDeviceQuery:
             self.current_shard = None
         out = {k: np.stack(vs) for k, vs in stacked.items()}
         tracing.counter(
-            "device.transfer",
+            "step.dispatch",
             h2d_bytes=int(sum(v.nbytes for v in out.values())),
         )
         return out
@@ -479,6 +479,12 @@ class DistributedDeviceQuery:
         if "occupancy" in emits:
             self.shard_store_occupancy = (
                 np.asarray(emits["occupancy"]).reshape(nd).astype(np.int64)
+            )
+        if "probe_rounds" in emits and tracing.active() is not None:
+            # the step waits for its slowest shard: the longest probe loop
+            tracing.counter(
+                "device.step", sampled=1,
+                probe_rounds=int(np.asarray(emits["probe_rounds"]).max()),
             )
 
     def process_ss(self, batch: HostBatch, side: str) -> List[SinkEmit]:
@@ -516,13 +522,13 @@ class DistributedDeviceQuery:
             for k, v in emits.items()
         }
 
-    def process_columns(
+    def split_columns(
         self, n, columns, timestamps, offsets=None, partitions=None,
-    ) -> List[SinkEmit]:
+    ) -> Dict[str, np.ndarray]:
         """Mesh-aware native-ingest entry: split decoded (data, valid)
-        column slices round-robin into per-shard lanes and run the
-        sharded step — the columnar analog of encode() + process(), with
-        the same fault seams and per-shard accounting.  Each lane is
+        column slices round-robin into per-shard lanes for
+        ``process_encoded`` — the columnar analog of encode(), with the
+        same fault seams and per-shard accounting.  Each lane is
         assembled at the per-shard static shape; assemble COPIES the
         decoder's slices into fresh padded buffers, so they are never
         aliased into donated jit state."""
@@ -563,10 +569,10 @@ class DistributedDeviceQuery:
             self.current_shard = None
         out = {k: np.stack(vs) for k, vs in stacked.items()}
         tracing.counter(
-            "device.transfer",
+            "step.dispatch",
             h2d_bytes=int(sum(v.nbytes for v in out.values())),
         )
-        return self._process_encoded(out)
+        return out
 
     _seen_overflow = 0
     _batches = 0
@@ -574,50 +580,60 @@ class DistributedDeviceQuery:
     def process(self, batch: HostBatch) -> List[SinkEmit]:
         if self.c.ss_join is not None:
             return self.process_ss(batch, "l")
-        return self._process_encoded(self.encode(batch))
+        return self.process_encoded(self.encode(batch))
 
-    def _process_encoded(self, arrays: Dict[str, np.ndarray]) -> List[SinkEmit]:
+    def process_encoded(self, arrays: Dict[str, np.ndarray]) -> List[SinkEmit]:
         """The sharded step over already-lane-split arrays: session
         slot-growth retry, per-shard accounting, eviction cadence and
-        overflow tripwires — shared by process() and process_columns()."""
-        if self.c.session:
-            while True:
-                new_state, emits = self._step(self.state, arrays)
-                if int(np.asarray(emits["sess_ovf"]).sum()) > 0:
-                    # more concurrent sessions per key than tracked slots on
-                    # some shard: grow, recompile the sharded step, re-run
-                    self.c.session_slots *= 2
-                    self._step = self._build_step()
-                    continue
+        overflow tripwires — shared by process() and the native tier's
+        split_columns()."""
+        with tracing.span("step.dispatch"):
+            new_state, emits = self._step(self.state, arrays)
+        while self.c.session:
+            with tracing.span("step.wait"):
+                overflowed = int(np.asarray(emits["sess_ovf"]).sum()) > 0
+            if not overflowed:
                 break
-            self.state = new_state
-        else:
-            self.state, emits = self._step(self.state, arrays)
-        self._account(emits)
+            # more concurrent sessions per key than tracked slots on some
+            # shard: grow, recompile the sharded step, re-run
+            self.c.session_slots *= 2
+            self._step = self._build_step()
+            with tracing.span("step.dispatch"):
+                new_state, emits = self._step(self.state, arrays)
+        self.state = new_state
+        if not self.c.session:  # the session step's overflow read has waited
+            with tracing.span("step.wait"):
+                jax.block_until_ready(emits)
         if self.c.agg is not None:
             self._batches += 1
             if (
                 self.c.retention_ms is not None
                 and self._batches % self.c.EVICT_INTERVAL == 0
             ):
+                # a dispatch of its own: device.execute's self time
                 self.state = self._evict(self.state)
-            overflow = int(np.asarray(emits["overflow"]).sum())
-            if overflow > self._seen_overflow:
-                self._seen_overflow = overflow
-                raise RuntimeError(
-                    f"sharded state store / exchange overflowed ({overflow} "
-                    "rows lost); raise store_capacity or bucket_capacity"
-                )
-            # online distributed growth is not implemented yet: stop loudly
-            # BEFORE loss once any shard nears saturation
-            occ = int(np.asarray(emits["occupancy"]).max())
-            if occ > 0.6 * self.c.store_capacity:
-                raise RuntimeError(
-                    "sharded state store nearing capacity "
-                    f"({occ}/{self.c.store_capacity} on the fullest shard); "
-                    "restart the query with a larger store_capacity"
-                )
-        return self.c._decode_emits(self._flatten(emits))
+        with tracing.span("emit.decode"):
+            self._account(emits)
+            if self.c.agg is not None:
+                overflow = int(np.asarray(emits["overflow"]).sum())
+                if overflow > self._seen_overflow:
+                    self._seen_overflow = overflow
+                    raise RuntimeError(
+                        f"sharded state store / exchange overflowed "
+                        f"({overflow} rows lost); raise store_capacity or "
+                        "bucket_capacity"
+                    )
+                # online distributed growth is not implemented yet: stop
+                # loudly BEFORE loss once any shard nears saturation
+                occ = int(np.asarray(emits["occupancy"]).max())
+                if occ > 0.6 * self.c.store_capacity:
+                    raise RuntimeError(
+                        "sharded state store nearing capacity "
+                        f"({occ}/{self.c.store_capacity} on the fullest "
+                        "shard); restart the query with a larger "
+                        "store_capacity"
+                    )
+            return self.c._decode_emits(self._flatten(emits))
 
     # -------------------------------------------------- executor-facing API
     def flush_pipeline(self) -> List[SinkEmit]:

@@ -41,6 +41,7 @@ def np_shard_of(khash, n_shards: int):
     return (u % np.uint64(n_shards)).astype(np.int64)
 
 
+@jax.named_scope("exchange")
 def all_to_all_exchange(
     payload: Dict[str, jnp.ndarray],
     dest: jnp.ndarray,

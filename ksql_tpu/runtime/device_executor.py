@@ -22,7 +22,6 @@ executor runs with batch size 1.
 
 from __future__ import annotations
 
-import time as _time
 from typing import Callable, Dict, List, Optional
 
 from ksql_tpu.common import faults, tracing
@@ -199,21 +198,16 @@ class DeviceExecutor:
             return fn(*args, **kw)
         entries = getattr(self.device, "jit_cache_entries", None)
         before = entries() if entries is not None else 0
-        depth = tr._depth
-        tr._depth += 1
-        t0 = _time.perf_counter()
-        try:
-            return fn(*args, **kw)
-        finally:
-            tr._depth = depth
-            dur = _time.perf_counter() - t0
-            missed = (entries() if entries is not None else 0) - before
-            if missed > 0:
-                tr.add_span("device.compile", t0, dur, depth)
-                tr.stage("device.compile", dur, jit_miss=missed)
-            else:
-                tr.add_span("device.execute", t0, dur, depth)
-                tr.stage("device.execute", dur, jit_hit=1)
+        with tr.span("device.execute") as sp:
+            try:
+                return fn(*args, **kw)
+            finally:
+                missed = (entries() if entries is not None else 0) - before
+                if missed > 0:
+                    sp.name = "device.compile"
+                    tr.counter(sp.name, jit_miss=missed)
+                else:
+                    tr.counter(sp.name, jit_hit=1)
 
     # ------------------------------------------------------------- interface
     def process(self, topic: str, record: Record) -> List[SinkEmit]:
@@ -346,7 +340,8 @@ class DeviceExecutor:
                             f(src) for f in self._null_keyers(op)
                         )
                 emit = SinkEmit(key, None, ev.ts, ev.window)
-                self._dispatch([emit])
+                # per record: no emit.dispatch span (spans are per batch)
+                self._dispatch_emits([emit])
                 out.append(emit)
                 return out
             if ev is not None and isinstance(ev, StreamRow) and ev.row is not None:
@@ -413,25 +408,24 @@ class DeviceExecutor:
         for s in range(0, len(records), cap):
             chunk = records[s : s + cap]
             n = len(chunk)
-            t0 = _time.perf_counter() if tr is not None else 0.0
-            try:
-                data, valid, row_ok, learned = native.parse_batch(
-                    [r.value for r in chunk], self._native_fields
-                )
-            except Exception:  # noqa: BLE001 — e.g. invalid UTF-8 in a
-                # learned string: replay the chunk through the per-record
-                # decoder, which drops exactly the offending records
-                data, valid, learned = {}, {}, []
-                row_ok = np.zeros(n, bool)
-            dev.dictionary.learn_pairs(learned)
-            if tr is not None and row_ok.any():
-                # the native tier IS the good rows' deserialize: batch
-                # payloads -> columnar arrays in C++ (the per-record path
-                # records the same stage inside decode_source_record)
-                tr.stage(
-                    "deserialize", _time.perf_counter() - t0,
-                    n=int(row_ok.sum()),
-                )
+            # the native tier IS the good rows' deserialize: batch payloads
+            # -> columnar arrays in C++, one span a chunk whose ``n`` counts
+            # the rows (the per-record path accumulates the same stage
+            # inside decode_source_record)
+            with tracing.span("deserialize") as sp:
+                try:
+                    data, valid, row_ok, learned = native.parse_batch(
+                        [r.value for r in chunk], self._native_fields
+                    )
+                except Exception:  # noqa: BLE001 — e.g. invalid UTF-8 in a
+                    # learned string: replay the chunk through the
+                    # per-record decoder, which drops exactly the
+                    # offending records
+                    data, valid, learned = {}, {}, []
+                    row_ok = np.zeros(n, bool)
+                dev.dictionary.learn_pairs(learned)
+                if tr is not None:
+                    sp.n = int(row_ok.sum())
             i = 0
             while i < n:
                 j = i + 1
@@ -472,39 +466,41 @@ class DeviceExecutor:
 
         dev = self.device
         n = len(chunk)
-        key_cols = list(self.source_step.schema.key_columns)
-        self.stream_time = max(
-            self.stream_time, max(r.timestamp for r in chunk)
-        )
-        label = self._native_fields["format"]
-        self.native_ingest_rows[label] = (
-            self.native_ingest_rows.get(label, 0) + n
-        )
-        spec_names = {spec.name for spec in dev.layout.specs}
-        columns = {
-            name: cv for name, cv in columns.items() if name in spec_names
-        }
-        if key_cols:
-            decoded = self._vectorized_keys(chunk, key_cols)
-            if decoded is None:
-                decoded = self._per_record_keys(chunk, key_cols)
-            for c in key_cols:
-                if c.name not in spec_names:
-                    continue
-                kvals, kok = decoded[c.name]
-                enc = encode_column(kvals, kok, c.type)
-                if enc.dictionary is not None:
-                    dev.dictionary.learn(enc.hashes64, enc.dictionary)
-                    kd = enc.hashes64[enc.data]
-                else:
-                    kd = enc.data
-                columns[c.name] = (kd, kok)
-        emits = self._native_process(
-            n, columns,
-            [r.timestamp for r in chunk],
-            [r.offset for r in chunk],
-            [r.partition for r in chunk],
-        )
+        with tracing.span("batch.assemble"):
+            key_cols = list(self.source_step.schema.key_columns)
+            self.stream_time = max(
+                self.stream_time, max(r.timestamp for r in chunk)
+            )
+            label = self._native_fields["format"]
+            self.native_ingest_rows[label] = (
+                self.native_ingest_rows.get(label, 0) + n
+            )
+            spec_names = {spec.name for spec in dev.layout.specs}
+            columns = {
+                name: cv for name, cv in columns.items() if name in spec_names
+            }
+            if key_cols:
+                decoded = self._vectorized_keys(chunk, key_cols)
+                if decoded is None:
+                    decoded = self._per_record_keys(chunk, key_cols)
+                for c in key_cols:
+                    if c.name not in spec_names:
+                        continue
+                    kvals, kok = decoded[c.name]
+                    enc = encode_column(kvals, kok, c.type)
+                    if enc.dictionary is not None:
+                        dev.dictionary.learn(enc.hashes64, enc.dictionary)
+                        kd = enc.hashes64[enc.data]
+                    else:
+                        kd = enc.data
+                    columns[c.name] = (kd, kok)
+            arrays = self._native_arrays(
+                n, columns,
+                [r.timestamp for r in chunk],
+                [r.offset for r in chunk],
+                [r.partition for r in chunk],
+            )
+        emits = self._native_step(arrays)
         if self._pipelines_held():
             # the double-buffer now holds THIS segment's emissions (the
             # returned emits belong to the previous batch)
@@ -512,14 +508,16 @@ class DeviceExecutor:
         self._dispatch(emits)
         return emits
 
-    def _native_process(self, n, columns, timestamps, offsets, partitions):
-        """Hand a natively decoded columnar segment to the device.
-        ``assemble`` COPIES the slices into fresh padded buffers, so the
-        decoder's output is never aliased into donated jit state.  The
-        distributed executor overrides this with the mesh lane split."""
-        arrays = self.device.layout.assemble(
+    def _native_arrays(self, n, columns, timestamps, offsets, partitions):
+        """The step's input arrays from a natively decoded columnar
+        segment.  ``assemble`` COPIES the slices into fresh padded buffers,
+        so the decoder's output is never aliased into donated jit state.
+        The distributed executor overrides this with the mesh lane split."""
+        return self.device.layout.assemble(
             n, columns, timestamps, offsets=offsets, partitions=partitions
         )
+
+    def _native_step(self, arrays) -> List[SinkEmit]:
         return self._device_step(self.device.process_arrays, arrays)
 
     def _vectorized_keys(self, chunk, key_cols):
@@ -841,13 +839,14 @@ class DeviceExecutor:
         out: List[SinkEmit] = []
         cap = self.device.capacity
         for i in range(0, len(rows), cap):
-            hb = HostBatch.from_rows(
-                schema,
-                rows[i : i + cap],
-                timestamps=ts[i : i + cap],
-                partitions=parts[i : i + cap],
-                offsets=offs[i : i + cap],
-            )
+            with tracing.span("batch.assemble"):
+                hb = HostBatch.from_rows(
+                    schema,
+                    rows[i : i + cap],
+                    timestamps=ts[i : i + cap],
+                    partitions=parts[i : i + cap],
+                    offsets=offs[i : i + cap],
+                )
             emits = self._device_step(self.device.process, hb)
             if self._pipelines_held():
                 # pipelined: the returned emits are the PREVIOUS batch's;
@@ -861,6 +860,10 @@ class DeviceExecutor:
     def _dispatch(self, emits: List[SinkEmit]) -> None:
         if not emits:
             return
+        with tracing.span("emit.dispatch"):
+            self._dispatch_emits(emits)
+
+    def _dispatch_emits(self, emits: List[SinkEmit]) -> None:
         if self.batch_emit_callback is not None:
             # batch boundary first: push pipelines stash the (possibly
             # device-resident) columnar block so their residual kernel can
@@ -948,23 +951,25 @@ class DistributedDeviceExecutor(DeviceExecutor):
         compiled = self.device
         compiled.pipeline = False  # the sharded runner decodes per step
         self.device = DistributedDeviceQuery(compiled, mesh)
-        # the C++ ingest tier stays engaged on the mesh: _native_process
+        # the C++ ingest tier stays engaged on the mesh: _native_arrays
         # routes decoded columns through the sharded runner's own
-        # round-robin lane split (process_columns), so the bypass the
+        # round-robin lane split (split_columns), so the bypass the
         # engine counted through PR 16 no longer exists for eligible plans
         self.native_ingest_bypassed = False
 
-    def _native_process(self, n, columns, timestamps, offsets, partitions):
-        # mesh-aware ingest: hand the decoder's column slices to the
-        # sharded runner, which splits them into per-shard lanes and
-        # assembles each lane at the per-shard static shape (the
-        # single-device whole-batch assemble would bake the wrong
-        # capacity).  process_columns copies every slice into fresh lane
-        # buffers, keeping decoder output out of donated jit state.
-        return self._device_step(
-            self.device.process_columns,
-            n, columns, timestamps, offsets, partitions,
+    def _native_arrays(self, n, columns, timestamps, offsets, partitions):
+        # mesh-aware ingest: the sharded runner splits the decoder's column
+        # slices into per-shard lanes and assembles each lane at the
+        # per-shard static shape (the single-device whole-batch assemble
+        # would bake the wrong capacity).  split_columns copies every
+        # slice into fresh lane buffers, keeping decoder output out of
+        # donated jit state.
+        return self.device.split_columns(
+            n, columns, timestamps, offsets, partitions
         )
+
+    def _native_step(self, arrays) -> List[SinkEmit]:
+        return self._device_step(self.device.process_encoded, arrays)
 
     def suspect_shard(self) -> Optional[int]:
         """Shard lane whose host-side dispatch section is (still) in

@@ -74,14 +74,18 @@ from ksql_tpu.runtime.oracle import DEFAULT_GRACE_MS, SinkEmit
 jax.config.update("jax_enable_x64", True)
 
 
+#: the stage that carries each direction's bytes: the span that moves them
+_TRANSFER_STAGE = {"h2d_bytes": "step.dispatch", "d2h_bytes": "emit.decode"}
+
+
 def _note_transfer(key: str, arrays: Dict[str, Any]) -> None:
-    """Account host<->device bytes on the flight recorder's
-    ``device.transfer`` stage (``.nbytes`` is metadata — no device sync)."""
+    """Account host<->device bytes on the flight recorder, on the stage of
+    the span that moves them (``.nbytes`` is metadata — no device sync)."""
     tr = tracing.active()
     if tr is None:
         return
     tr.counter(
-        "device.transfer",
+        _TRANSFER_STAGE[key],
         **{key: int(sum(getattr(v, "nbytes", 0) for v in arrays.values()))},
     )
 
@@ -1986,6 +1990,7 @@ class CompiledDeviceQuery:
         self.state = {k: jnp.array(v) for k, v in new.items()}
 
     # ----------------------------------------------- sliced fold + combine
+    @jax.named_scope("scatter_combine")
     def _sliced_scatter(
         self,
         store: Dict[str, jnp.ndarray],
@@ -2114,6 +2119,7 @@ class CompiledDeviceQuery:
         ).reshape(1)
         return emits
 
+    @jax.named_scope("emit_compact")
     def _sliced_member_emits(
         self,
         store: Dict[str, jnp.ndarray],
@@ -2309,7 +2315,7 @@ class CompiledDeviceQuery:
         cap_t = jspec.capacity
         dump = jnp.int32(cap_t)
         zeros64 = jnp.zeros(n, jnp.int64)
-        jt, slots = probe_insert(
+        jt, slots, _ = probe_insert(
             dict(state[key]), cap_t, khash, zeros64, [krepr],
             jnp.zeros(n, jnp.int32), act,
         )
@@ -2399,10 +2405,10 @@ class CompiledDeviceQuery:
             contribs.extend(cs)
         zeros64 = jnp.zeros(n, jnp.int64)
         if undo:
-            slots = probe_find(store, cap, khash, zeros64, active)
+            slots, _ = probe_find(store, cap, khash, zeros64, active)
             active = active & (slots != dump)
         else:
-            store, slots = probe_insert(
+            store, slots, _ = probe_insert(
                 store, cap, khash, zeros64, reprs, knull, active
             )
         slot_or_dump = jnp.where(active, slots, dump)
@@ -2548,7 +2554,7 @@ class CompiledDeviceQuery:
         khash = combine_hash([krepr])
         touched = a_new["row_valid"] & kcol.valid
         zeros64 = jnp.zeros(n, jnp.int64)
-        fkl, slots = probe_insert(
+        fkl, slots, _ = probe_insert(
             fkl, cap, khash, zeros64, [krepr], jnp.zeros(n, jnp.int32),
             touched,
         )
@@ -2559,7 +2565,7 @@ class CompiledDeviceQuery:
 
         def right_of(fk):
             rh = combine_hash([_repr64(fk)])
-            rslots = probe_find(
+            rslots, _ = probe_find(
                 fkr, cap, rh, jnp.zeros(n, jnp.int64), fk.valid
             )
             rfound = fk.valid & (rslots != dump) & fkr["live"][rslots]
@@ -2627,7 +2633,7 @@ class CompiledDeviceQuery:
         khash = combine_hash([krepr])
         touched = a_new["row_valid"] & kcol.valid
         zeros64 = jnp.zeros(n, jnp.int64)
-        fkr, slots = probe_insert(
+        fkr, slots, _ = probe_insert(
             fkr, cap, khash, zeros64, [krepr], jnp.zeros(n, jnp.int32),
             touched,
         )
@@ -2843,7 +2849,7 @@ class CompiledDeviceQuery:
         khash = combine_hash([krepr])
         touched = a_new["row_valid"] & kcol.valid
         zeros64 = jnp.zeros(n, jnp.int64)
-        tt, slots = probe_insert(
+        tt, slots, _ = probe_insert(
             tt, cap, khash, zeros64, [krepr], jnp.zeros(n, jnp.int32), touched
         )
         found = slots != dump
@@ -3008,7 +3014,7 @@ class CompiledDeviceQuery:
             khash = combine_hash([krepr])
             look = active & kcol.valid
             cap_t = jspec.capacity
-            slots = probe_find(
+            slots, _ = probe_find(
                 jtab, cap_t, khash, jnp.zeros(n, jnp.int64), look
             )
             found = look & (slots != cap_t)
@@ -3055,6 +3061,7 @@ class CompiledDeviceQuery:
         kcol = JaxExprCompiler(env, n, self.dictionary).compile(key_expr)
         return combine_hash([_repr64(kcol)]), active
 
+    @jax.named_scope("ss_join_match")
     def _trace_ss_step(
         self, side: str, state: Dict[str, jnp.ndarray],
         arrays: Dict[str, jnp.ndarray],
@@ -3420,25 +3427,26 @@ class CompiledDeviceQuery:
     ) -> Tuple[Dict[str, jnp.ndarray], Dict[str, jnp.ndarray]]:
         if self.agg is None:
             n = self.capacity
-            env = self._source_env(arrays)
-            active = arrays["row_valid"]
-            # shared source prefix: the structurally-common leading steps
-            # run ONCE; the primary and every prefix member branch off the
-            # post-prefix env with only their residual suffixes (with no
-            # members the prefix is empty and this is the plain chain)
-            shared_n = self._prefix_shared_len if self.prefix_members else 0
-            env, active = self._apply_ops(
-                self.pre_ops[:shared_n], env, active, n
-            )
-            penv, pactive = env, active
-            env, active = self._apply_ops(
-                self.pre_ops[shared_n:], env, active, n
-            )
-            if self.join is not None:
-                env, active = self._apply_join(
-                    env, active, n, self._jtabs_of(state)
+            with jax.named_scope("source_decode"):
+                env = self._source_env(arrays)
+                active = arrays["row_valid"]
+                # shared source prefix: the structurally-common leading steps
+                # run ONCE; the primary and every prefix member branch off the
+                # post-prefix env with only their residual suffixes (with no
+                # members the prefix is empty and this is the plain chain)
+                shared_n = self._prefix_shared_len if self.prefix_members else 0
+                env, active = self._apply_ops(
+                    self.pre_ops[:shared_n], env, active, n
                 )
-                env, active = self._apply_ops(self.mid_ops, env, active, n)
+                penv, pactive = env, active
+                env, active = self._apply_ops(
+                    self.pre_ops[shared_n:], env, active, n
+                )
+                if self.join is not None:
+                    env, active = self._apply_join(
+                        env, active, n, self._jtabs_of(state)
+                    )
+                    env, active = self._apply_ops(self.mid_ops, env, active, n)
             ts = arrays["ts"]
             batch_max_ts = jnp.max(jnp.where(active, ts, np.iinfo(np.int64).min))
             emits = self._emit_stateless(env, active, ts)
@@ -3540,6 +3548,7 @@ class CompiledDeviceQuery:
             payload[f"c{j}"] = arr
         return payload
 
+    @jax.named_scope("session_merge")
     def post_session_exchange(
         self, state: Dict[str, jnp.ndarray], payload: Dict[str, jnp.ndarray]
     ) -> Tuple[Dict[str, jnp.ndarray], Dict[str, jnp.ndarray]]:
@@ -3578,7 +3587,7 @@ class CompiledDeviceQuery:
         # single device, and stays correct when exchange scrambles rows
         batch_stream_time = jnp.maximum(state["max_ts"], jnp.max(cm))
         for i in range(S):
-            slots_i = probe_find(
+            slots_i, _ = probe_find(
                 state, cap, khash, jnp.full(n, i, jnp.int64), first_occ
             )
             found = first_occ & (slots_i != cap)
@@ -3713,7 +3722,7 @@ class CompiledDeviceQuery:
         winner = boundary & seg_alive[seg]
         sess_ovf = jnp.sum(winner & (rank >= S))
         ins_act = winner & (rank < S)
-        state, ins_slots = probe_insert(
+        state, ins_slots, _ = probe_insert(
             state, cap, kh, rank.astype(jnp.int64),
             [r[seg] for r in seg_reprs],
             jnp.zeros(m, jnp.int32), ins_act,
@@ -3816,69 +3825,71 @@ class CompiledDeviceQuery:
         flat payload is exactly what crosses the ICI all-to-all in the
         multi-chip path (the repartition-topic analog, SURVEY §2.3)."""
         n = self.capacity
-        env = self._source_env(arrays)
-        active = arrays["row_valid"]
-        env, active = self._apply_pre_ops(env, active, n)
-        if self.join is not None:
-            env, active = self._apply_join(env, active, n, jtabs)
-            env, active = self._apply_ops(self.mid_ops, env, active, n)
+        with jax.named_scope("source_decode"):
+            env = self._source_env(arrays)
+            active = arrays["row_valid"]
+            env, active = self._apply_pre_ops(env, active, n)
+            if self.join is not None:
+                env, active = self._apply_join(env, active, n, jtabs)
+                env, active = self._apply_ops(self.mid_ops, env, active, n)
         ts = arrays["ts"]
 
-        # ---------------- window assignment (expand for hopping)
-        w = self.window
-        if w is None:
-            wstart = jnp.zeros(n, jnp.int64)
-            wsize = 0
-            k = 1
-        elif w.window_type == WindowType.TUMBLING:
-            wstart = W.tumbling_starts(ts, w.size_ms)
-            wsize = w.size_ms
-            k = 1
-        elif w.window_type == WindowType.HOPPING and self.sliced:
-            # stream slicing: each row lands in exactly ONE slice; the
-            # per-window combine happens at emission (post_exchange), so
-            # nothing expands before the shuffle
-            wstart = W.slice_starts(ts, self.slice_width)
-            wsize = w.size_ms
-            k = 1
-            # admission = the expansion path's any-window-open rule, per
-            # family member: the NEWEST window covering the record's slice
-            # ends at advance-aligned(ts) + size, and a record whose every
-            # covering window is closed (end + grace <= stream time at
-            # batch start) never reaches state on either path
-            open_any = jnp.zeros(n, bool)
-            for m in self.members:
-                newest = ts - jnp.remainder(ts, m.advance_ms)
-                open_any = open_any | (
-                    newest + m.size_ms + m.grace_ms > max_ts
+        with jax.named_scope("window_assign"):
+            # ---------------- window assignment (expand for hopping)
+            w = self.window
+            if w is None:
+                wstart = jnp.zeros(n, jnp.int64)
+                wsize = 0
+                k = 1
+            elif w.window_type == WindowType.TUMBLING:
+                wstart = W.tumbling_starts(ts, w.size_ms)
+                wsize = w.size_ms
+                k = 1
+            elif w.window_type == WindowType.HOPPING and self.sliced:
+                # stream slicing: each row lands in exactly ONE slice; the
+                # per-window combine happens at emission (post_exchange), so
+                # nothing expands before the shuffle
+                wstart = W.slice_starts(ts, self.slice_width)
+                wsize = w.size_ms
+                k = 1
+                # admission = the expansion path's any-window-open rule, per
+                # family member: the NEWEST window covering the record's slice
+                # ends at advance-aligned(ts) + size, and a record whose every
+                # covering window is closed (end + grace <= stream time at
+                # batch start) never reaches state on either path
+                open_any = jnp.zeros(n, bool)
+                for m in self.members:
+                    newest = ts - jnp.remainder(ts, m.advance_ms)
+                    open_any = open_any | (
+                        newest + m.size_ms + m.grace_ms > max_ts
+                    )
+                # ring-wrap safety cut: live slices must span < slice_ring
+                # slices, or two batch rows could fold different slices into
+                # one ring cell.  The cut sits at the family retention horizon
+                # (ring = retention/width + 2), so it only drops records the
+                # retention pass would evict this batch anyway — evaluated
+                # against the IN-BATCH max ts, the one place the sliced path
+                # is stricter than the expansion path's batch-start clock.
+                batch_max = jnp.maximum(
+                    max_ts,
+                    jnp.max(jnp.where(active, ts, np.iinfo(np.int64).min)),
                 )
-            # ring-wrap safety cut: live slices must span < slice_ring
-            # slices, or two batch rows could fold different slices into
-            # one ring cell.  The cut sits at the family retention horizon
-            # (ring = retention/width + 2), so it only drops records the
-            # retention pass would evict this batch anyway — evaluated
-            # against the IN-BATCH max ts, the one place the sliced path
-            # is stricter than the expansion path's batch-start clock.
-            batch_max = jnp.maximum(
-                max_ts,
-                jnp.max(jnp.where(active, ts, np.iinfo(np.int64).min)),
-            )
-            horizon_ok = (
-                wstart + (self.slice_ring - 1) * self.slice_width > batch_max
-            )
-            active = active & open_any & horizon_ok
-        elif w.window_type == WindowType.HOPPING:
-            wstart, in_win = W.hopping_starts(ts, w.size_ms, w.advance_ms)
-            wsize = w.size_ms
-            k = W.hopping_expansion(w.size_ms, w.advance_ms)
-            env = {
-                name: DCol(W.expand(c.data, k), W.expand(c.valid, k), c.sql_type)
-                for name, c in env.items()
-            }
-            active = W.expand(active, k) & in_win
-            ts = W.expand(ts, k)
-        else:  # pragma: no cover
-            raise DeviceUnsupported(f"window {w.window_type}")
+                horizon_ok = (
+                    wstart + (self.slice_ring - 1) * self.slice_width > batch_max
+                )
+                active = active & open_any & horizon_ok
+            elif w.window_type == WindowType.HOPPING:
+                wstart, in_win = W.hopping_starts(ts, w.size_ms, w.advance_ms)
+                wsize = w.size_ms
+                k = W.hopping_expansion(w.size_ms, w.advance_ms)
+                env = {
+                    name: DCol(W.expand(c.data, k), W.expand(c.valid, k), c.sql_type)
+                    for name, c in env.items()
+                }
+                active = W.expand(active, k) & in_win
+                ts = W.expand(ts, k)
+            else:  # pragma: no cover
+                raise DeviceUnsupported(f"window {w.window_type}")
         nn = n * k
 
         # ---------------- group key
@@ -3969,7 +3980,7 @@ class CompiledDeviceQuery:
             if self.sliced
             else payload["wstart"]
         )
-        store, slots = probe_insert(
+        store, slots, probe_rounds = probe_insert(
             state,
             self.store_capacity,
             payload["khash"],
@@ -4067,6 +4078,9 @@ class CompiledDeviceQuery:
         emits["occupancy"] = jnp.sum(store["occ"] | store["grave"])
         emits["graves"] = jnp.sum(store["grave"])
         emits["overflow"] = store["overflow"]
+        # the store's own account of this step: rounds of its probe loop
+        # (the while_loop's first carry), read beside the load scalars
+        emits["probe_rounds"] = probe_rounds.astype(jnp.int32)
         if self.sliced:
             # host mirror of the stream clock (rides the existing per-batch
             # load readback): lower-bounds the admission floor ensure_ring_for
@@ -4148,6 +4162,7 @@ class CompiledDeviceQuery:
             env["WINDOWEND"] = DCol(ws + size, ones, T.BIGINT)
         return env, row_ts, exceeded
 
+    @jax.named_scope("emit_compact")
     def _emit_agg(
         self,
         store: Dict[str, jnp.ndarray],
@@ -4240,6 +4255,7 @@ class CompiledDeviceQuery:
             out["we"] = env["WINDOWEND"].data
         return out
 
+    @jax.named_scope("evict")
     def _trace_evict(
         self, store: Dict[str, jnp.ndarray]
     ) -> Dict[str, jnp.ndarray]:
@@ -4319,21 +4335,22 @@ class CompiledDeviceQuery:
     def process_arrays(self, arrays: Dict[str, np.ndarray]) -> List[SinkEmit]:
         """One encoded micro-batch through the device step (the entry the
         native ingest tier feeds directly, bypassing HostBatch)."""
-        _note_transfer("h2d_bytes", arrays)
-        if self.sliced:
-            self.ensure_ring_for(arrays["ts"], arrays["row_valid"])
-        if self.session:
-            while True:
-                new_state, emits = self._step(self.state, arrays)
-                if int(emits["sess_ovf"]) > 0:
-                    # more concurrent sessions per key than tracked slots:
-                    # grow and re-run the batch (steps are undonated)
-                    self._grow_sessions()
-                    continue
+        with tracing.span("step.dispatch"):
+            _note_transfer("h2d_bytes", arrays)
+            if self.sliced:
+                self.ensure_ring_for(arrays["ts"], arrays["row_valid"])
+            new_state, emits = self._step(self.state, arrays)
+        while self.session:
+            with tracing.span("step.wait"):
+                overflowed = int(emits["sess_ovf"]) > 0
+            if not overflowed:
                 break
-            self.state = new_state
-        else:
-            self.state, emits = self._step(self.state, arrays)
+            # more concurrent sessions per key than tracked slots: grow
+            # and re-run the batch (steps are undonated)
+            self._grow_sessions()
+            with tracing.span("step.dispatch"):
+                new_state, emits = self._step(self.state, arrays)
+        self.state = new_state
         result: Optional[List[SinkEmit]] = None
         if self.suppress:
             # windows the step closed this batch — emitted BEFORE the
@@ -4351,19 +4368,32 @@ class CompiledDeviceQuery:
         if result is not None:
             self._react_to_load(emits)
             return result
+        react = self.agg is not None
         if self.pipeline and not self.suppress and not self.session:
             emits, self._pending_emits = self._pending_emits, emits
             if emits is None:
                 return []
-            # sample the load check: int() forces a device sync, and in
-            # pipelined mode the 0.75-occupancy growth threshold leaves
-            # several batches of headroom
-            if self.agg is not None and self._batches % 4 == 0:
+            # sample the load check: int() costs a device readback each,
+            # and in pipelined mode the 0.75-occupancy growth threshold
+            # leaves several batches of headroom
+            react = react and self._batches % 4 == 0
+        return self._finish_step(emits, react)
+
+    def _finish_step(
+        self, emits: Dict[str, jnp.ndarray], react: bool
+    ) -> List[SinkEmit]:
+        """The host's half of a step: wait for its outputs, then read the
+        load scalars (``react``) and decode the emitted rows.  The wait is
+        explicit so that its span holds the host blocked on the device and
+        nothing else; the reads that follow would block there anyway."""
+        if not self.session:  # the session step's overflow read has waited
+            with tracing.span("step.wait"):
+                jax.block_until_ready(emits)
+        with tracing.span("emit.decode"):
+            if react:
                 self._react_to_load(emits)
-        elif self.agg is not None:
-            self._react_to_load(emits)
-        self._deliver_members(emits)
-        return self._decode_emits(emits)
+            self._deliver_members(emits)
+            return self._decode_emits(emits)
 
     def _deliver_members(self, emits: Dict[str, jnp.ndarray]) -> None:
         """Decode + deliver the attached members' emission blocks
@@ -4456,10 +4486,7 @@ class CompiledDeviceQuery:
         emits, self._pending_emits = self._pending_emits, None
         if emits is None:
             return []
-        if self.agg is not None:
-            self._react_to_load(emits)
-        self._deliver_members(emits)
-        return self._decode_emits(emits)
+        return self._finish_step(emits, self.agg is not None)
 
     _seen_overflow = 0
     _batches = 0
@@ -4481,6 +4508,14 @@ class CompiledDeviceQuery:
                 "changelog with a larger store"
             )
         occupancy = int(emits["occupancy"])
+        if "probe_rounds" in emits and tracing.active() is not None:
+            # the step's own account of its store work, read where the
+            # host reads the load scalars anyway; ``sampled`` (not ``n``)
+            # is the denominator: pipelined ticks check every 4th batch
+            tracing.counter(
+                "device.step",
+                probe_rounds=int(emits["probe_rounds"]), sampled=1,
+            )
         headroom = self.capacity * self.expansion
         if self.pipeline:
             headroom *= 4  # load checks are sampled every 4th batch

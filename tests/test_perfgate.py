@@ -41,8 +41,6 @@ def _stages(scale=1.0):
                            "totalMs": 400.0, "jit_miss": 2},
         "device.execute": {"p50Ms": 5.0, "p99Ms": 10.0 * scale,
                            "totalMs": 50.0, "jit_hit": 9},
-        "device.transfer": {"p50Ms": 1.0, "p99Ms": 2.0 * scale,
-                            "totalMs": 10.0, "h2d_bytes": 1 << 20},
         "exchange": {"p50Ms": 2.0, "p99Ms": 4.0 * scale, "totalMs": 20.0,
                      "rows": 1000, "bytes": 33000},
         "sink.produce": {"p50Ms": 1.5, "p99Ms": 3.0 * scale,

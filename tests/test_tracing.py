@@ -81,8 +81,10 @@ def test_device_compile_execute_split_and_nesting():
     # the first tick jit-compiles, later dispatches hit the cache
     assert stats["device.compile"]["jit_miss"] >= 1
     assert stats["device.execute"]["jit_hit"] >= 1
-    xfer = stats["device.transfer"]
-    assert xfer["h2d_bytes"] > 0 and xfer["d2h_bytes"] > 0
+    # bytes ride the stage of the span that moves them
+    assert stats["step.dispatch"]["h2d_bytes"] > 0
+    assert stats["emit.decode"]["d2h_bytes"] > 0
+    assert "device.transfer" not in stats
     # span nesting: device steps run INSIDE the process/drain spans
     tk = rec.recent(1)[0]
     depths = {s["name"]: s["depth"] for s in tk["spans"]}
@@ -108,7 +110,7 @@ def test_distributed_stages_and_exchange_bytes():
     # rows crossed the all-to-all to their key-owner shard
     assert stats["exchange"]["rows"] > 0
     assert stats["exchange"]["bytes"] > 0
-    assert stats["device.transfer"]["h2d_bytes"] > 0
+    assert stats["step.dispatch"]["h2d_bytes"] > 0
     # EXPLAIN ANALYZE surfaces the same split + exchange volume (the
     # acceptance-criteria table)
     r = e.execute_sql(f"EXPLAIN ANALYZE {qid};")[0]
@@ -388,3 +390,368 @@ def test_chaos_soak_corrupt_mode_no_silent_loss():
     res = mod.soak(seconds=1.5, seed=7, backend="oracle", rate=400,
                    verbose=False, corrupt=True)
     assert res["ok"], res["message"]
+
+
+# ------------------------------------------- the tick accounts for itself
+COUNT_CTAS = (
+    "CREATE TABLE C AS SELECT URL, COUNT(*) AS CNT FROM PV "
+    "WINDOW TUMBLING (SIZE 1 HOUR) GROUP BY URL EMIT CHANGES;"
+)
+STEP_CHILDREN = ("step.dispatch", "step.wait", "emit.decode")
+
+
+def _device_count_engine(extra=None):
+    e = _engine({cfg.RUNTIME_BACKEND: "device-only", cfg.BATCH_CAPACITY: 256,
+                 **(extra or {})})
+    e.execute_sql(PV_DDL)
+    e.execute_sql(COUNT_CTAS)
+    (handle,) = e.queries.values()
+    assert handle.backend == "device"
+    return e, handle
+
+
+def _drive_ticks(e, ticks, rows=200):
+    t = e.broker.topic("pv")
+    for tick in range(ticks):
+        for i in range(rows):
+            t.produce(Record(
+                key=None, timestamp=tick * rows + i,
+                value=json.dumps({"URL": f"/p{(i * 7 + tick) % 61}", "V": i}),
+            ))
+        e.run_until_quiescent()
+
+
+def _direct_children(span, spans):
+    lo, hi = span["t0Ms"], span["t0Ms"] + span["durMs"]
+    return [
+        s for s in spans
+        if s["depth"] == span["depth"] + 1
+        and lo - 0.002 <= s["t0Ms"] and s["t0Ms"] + s["durMs"] <= hi + 0.002
+    ]
+
+
+def test_tick_accounts_for_itself():
+    """Every kept tick's spans cover it (the time under no span, the tick
+    stage's self time, is a small share) and each stage's recorded self
+    time is its spans' duration minus their direct children and the timed
+    stages accumulated under them."""
+    e, handle = _device_count_engine()
+    _drive_ticks(e, 21)
+    rec = e.trace_recorder(handle.query_id)
+    ticks = rec.recent()[1:]  # without the tick that compiled
+    assert len(ticks) == 20
+    total = sum(t["stages"]["tick"]["ms"] for t in ticks)
+    unattributed = sum(t["stages"]["tick"]["self_ms"] for t in ticks)
+    assert 0 <= unattributed <= 0.10 * total
+    #: timed accumulators (no span of their own) and the span they run under
+    accumulated_under = {"sink.produce": "emit.dispatch"}
+    for t in ticks:
+        spans, stages = t["spans"], t["stages"]
+        assert {"poll", "process", "drain", "commit"} == {
+            s["name"] for s in spans if s["depth"] == 0}
+        top = sum(s["durMs"] for s in spans if s["depth"] == 0)
+        assert stages["tick"]["self_ms"] == pytest.approx(
+            t["durMs"] - top, abs=0.002 * len(spans))
+        expected = {}
+        for s in spans:
+            kids = _direct_children(s, spans)
+            expected[s["name"]] = expected.get(s["name"], 0.0) + (
+                s["durMs"] - sum(k["durMs"] for k in kids))
+        for acc, under in accumulated_under.items():
+            expected[under] -= stages[acc]["ms"]
+        for name, want in expected.items():
+            n_spans = sum(1 for s in spans if s["name"] == name)
+            assert stages[name]["self_ms"] == pytest.approx(
+                want, abs=0.001 * (len(spans) + n_spans)), name
+            assert stages[name]["self_ms"] >= -0.001
+    e.shutdown()
+
+
+def test_device_step_children_nest_and_the_parent_reads_what_it_read():
+    """step.dispatch / step.wait / emit.decode are children of the device
+    step's span, whose own name, total, count and jit_hit keep the
+    parent commit's semantics: two device steps a pipelined tick (the
+    batch's dispatch, then flush_pipeline), the first ever a compile."""
+    e, handle = _device_count_engine()
+    n_ticks = 6
+    _drive_ticks(e, n_ticks)
+    rec = e.trace_recorder(handle.query_id)
+    stats = rec.stage_stats()
+    dev_spans = []
+    for t in rec.recent():
+        spans = t["spans"]
+        steps = [s for s in spans if s["name"].startswith("device.")]
+        assert [s["name"] for s in steps][1:] == ["device.execute"]
+        assert len(steps) == 2
+        dev_spans.extend(steps)
+        dispatch, flush = sorted(steps, key=lambda s: s["t0Ms"])
+        assert [k["name"] for k in _direct_children(dispatch, spans)] == [
+            "step.dispatch"]
+        assert sorted(k["name"] for k in _direct_children(flush, spans)) == [
+            "emit.decode", "step.wait"]
+        for s in spans:
+            if s["name"] in STEP_CHILDREN:
+                assert s["depth"] == dispatch["depth"] + 1
+    compiled = stats["device.compile"]
+    executed = stats["device.execute"]
+    assert compiled["n"] == compiled["jit_miss"] == 1
+    assert executed["n"] == executed["jit_hit"] == 2 * n_ticks - 1
+    assert executed["total_ms"] == pytest.approx(
+        sum(s["durMs"] for s in dev_spans if s["name"] == "device.execute"),
+        abs=0.001 * len(dev_spans))
+    # the children account for the parent
+    parents = executed["total_ms"] + compiled["total_ms"]
+    children = sum(stats[c]["total_ms"] for c in STEP_CHILDREN)
+    rest = executed["self_ms"] + compiled["self_ms"]
+    assert parents == pytest.approx(children + rest, abs=0.01)
+    assert stats["step.dispatch"]["h2d_bytes"] > 0
+    assert stats["emit.decode"]["d2h_bytes"] > 0
+    # the step's own account: one sample a flush, rounds of a real probe loop
+    assert stats["device.step"]["sampled"] == n_ticks
+    assert stats["device.step"]["probe_rounds"] >= n_ticks
+    e.shutdown()
+
+
+def _reference_probe_rounds(table, capacity, keys, active):
+    """Plain linear probing, a batch at a time as the store resolves one:
+    every round each unresolved row looks at its next slot; a slot that
+    holds its key resolves it; of the rows that find a slot empty the first
+    in the batch takes it and the others look again; any other slot sends
+    the row one further.  Returns how many rounds that took."""
+    from ksql_tpu.ops import hash_store as hs
+
+    mask = capacity - 1
+    base = (hs.np_mix64(keys ^ 0) & mask).tolist()
+    keys = keys.tolist()
+    todo = [i for i in range(len(keys)) if active[i]]
+    offset = dict.fromkeys(todo, 0)
+    rounds = 0
+    while todo:
+        rounds += 1
+        claims, left = {}, []
+        for i in todo:
+            slot = (base[i] + offset[i]) & mask
+            held = table.get(slot)
+            if held == keys[i]:
+                continue
+            if held is None:
+                claims.setdefault(slot, i)
+            else:
+                offset[i] += 1
+            left.append(i)
+        for slot, i in claims.items():
+            table[slot] = keys[i]
+            left.remove(i)
+        todo = left
+    return rounds
+
+
+@pytest.mark.parametrize("load", [0.3, 0.6])
+def test_probe_rounds_equal_a_plain_linear_probing_count(load):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ksql_tpu.ops import hash_store as hs
+
+    capacity, batch = 8192, 512
+    store = hs.init_store(hs.StoreLayout(capacity=capacity, num_keys=1, components=()))
+    insert = jax.jit(lambda st, kh, act: hs.probe_insert(
+        st, capacity, kh, jnp.zeros_like(kh), [kh],
+        jnp.zeros(kh.shape, jnp.int32), act))
+    rng = np.random.default_rng(int(load * 100))
+    resident = rng.integers(-2**62, 2**62, int(load * capacity))
+    for lo in range(0, len(resident), batch):
+        chunk, act = np.zeros(batch, np.int64), np.zeros(batch, bool)
+        part = resident[lo:lo + batch]
+        chunk[:len(part)], act[:len(part)] = part, True
+        store, _, _ = insert(store, chunk, act)
+    occ = np.asarray(store["occ"])[:capacity]
+    held = np.asarray(store["khash"])
+    table = {int(s): int(held[s]) for s in np.nonzero(occ)[0]}
+    assert len(table) == len(resident)
+    # the seeded batch: resident keys, new keys, repeats of both, and lanes
+    # that carry no row
+    fresh = rng.integers(-2**62, 2**62, batch // 4)
+    keys = np.concatenate([
+        rng.choice(resident, batch // 4), fresh, rng.choice(fresh, batch // 4),
+        rng.integers(-2**62, 2**62, batch // 4)])
+    active = np.ones(batch, bool)
+    active[-batch // 8:] = False
+    want = _reference_probe_rounds(table, capacity, keys, active)
+    store, _, rounds = insert(store, keys, active)
+    assert int(rounds) == want and want > 1
+    assert int(np.asarray(store["occ"]).sum()) == len(table)
+    # a batch with no valid row runs no round
+    _, _, none = insert(store, keys, np.zeros(batch, bool))
+    assert int(none) == 0
+    found, find_rounds = jax.jit(lambda st, kh: hs.probe_find(
+        st, capacity, kh, jnp.zeros_like(kh), jnp.ones(kh.shape, bool)))(store, keys)
+    assert 1 <= int(find_rounds) <= want
+    assert (np.asarray(found)[active] < capacity).all()
+
+
+def test_trace_disabled_records_no_stage_and_the_step_still_counts():
+    import jax
+
+    e, handle = _device_count_engine({cfg.TRACE_ENABLE: "false"})
+    _drive_ticks(e, 3)
+    assert e.trace_recorders == {}
+    dev = handle.executor.device
+    state, emits = dev._step(dev.state, dev.layout.example())
+    dev.state = state  # the step donates its state
+    assert emits["probe_rounds"].shape == () and int(emits["probe_rounds"]) == 0
+    assert emits["probe_rounds"].dtype == jax.numpy.int32
+    sink = e.broker.topic(handle.plan.physical_plan.topic)
+    assert sum(sink.end_offsets()) > 0
+    e.shutdown()
+
+
+def _lowered_device_query(statements, **sizes):
+    from ksql_tpu.runtime.lowering import CompiledDeviceQuery
+
+    e = _engine({cfg.RUNTIME_BACKEND: "oracle"})
+    results = [r for s in statements for r in e.execute_sql(s)]
+    qid = next(r.query_id for r in results if r.query_id)
+    dev = CompiledDeviceQuery(e.queries[qid].plan, e.registry, **sizes)
+    e.shutdown()
+    return dev
+
+
+_SS_DDL = [
+    "CREATE STREAM LEFTS (ID BIGINT KEY, V BIGINT) "
+    "WITH (KAFKA_TOPIC='lt', VALUE_FORMAT='JSON');",
+    "CREATE STREAM RIGHTS (ID BIGINT KEY, V BIGINT) "
+    "WITH (KAFKA_TOPIC='rt', VALUE_FORMAT='JSON');",
+    "CREATE STREAM J AS SELECT L.ID, L.V AS LV, R.V AS RV FROM LEFTS L "
+    "LEFT JOIN RIGHTS R WITHIN 10 SECONDS GRACE PERIOD 1 SECOND "
+    "ON L.ID = R.ID EMIT CHANGES;",
+]
+_JOIN_DDL = [
+    "CREATE TABLE USERS (ID BIGINT PRIMARY KEY, REGION STRING) "
+    "WITH (KAFKA_TOPIC='users', VALUE_FORMAT='JSON');",
+    "CREATE STREAM CLICKS (USER_ID BIGINT, URL STRING) "
+    "WITH (KAFKA_TOPIC='clicks', VALUE_FORMAT='JSON');",
+    "CREATE STREAM ENRICHED AS SELECT C.USER_ID, C.URL, U.REGION "
+    "FROM CLICKS C LEFT JOIN USERS U ON C.USER_ID = U.ID EMIT CHANGES;",
+]
+
+
+@pytest.mark.parametrize("statements,step,scopes", [
+    ([PV_DDL, "CREATE TABLE C AS SELECT URL, COUNT(*) AS CNT FROM PV "
+              "WINDOW TUMBLING (SIZE 1 HOUR) WHERE V >= 0 GROUP BY URL "
+              "EMIT CHANGES;"], "_step",
+     ["source_decode", "window_assign", "probe_insert", "scatter_combine",
+      "emit_compact"]),
+    ([PV_DDL, COUNT_CTAS], "_evict", ["evict"]),
+    (_JOIN_DDL, "_step", ["source_decode", "probe_find"]),
+    (_SS_DDL, "_ss_l", ["ss_join_match"]),
+    ([PV_DDL, "CREATE TABLE S AS SELECT URL, COUNT(*) AS CNT FROM PV "
+              "WINDOW SESSION (30 SECONDS) GROUP BY URL EMIT CHANGES;"],
+     "_step", ["session_merge", "probe_find", "probe_insert"]),
+], ids=["tumbling-count", "evict", "stream-table-join", "stream-stream-join",
+        "session"])
+def test_operator_scopes_reach_the_step_program(statements, step, scopes):
+    """The step program names its operators (jax.named_scope): each scope
+    the plan uses is in the lowered program's debug info, where XLA takes
+    an operation's ``op_name`` from."""
+    import jax
+
+    dev = _lowered_device_query(statements, capacity=64, store_capacity=1 << 10)
+    state = jax.eval_shape(dev.init_state)
+    args = (state,) if step == "_evict" else (state, dev.layout.array_structs())
+    text = getattr(dev, step).lower(*args).as_text(debug_info=True)
+    missing = [s for s in scopes if f"{s}/" not in text and f'{s}"' not in text]
+    assert not missing, missing
+
+
+def test_exchange_scope_reaches_the_sharded_step():
+    e = _engine({cfg.RUNTIME_BACKEND: "distributed"})
+    e.execute_sql(PV_DDL)
+    e.execute_sql(COUNT_CTAS)
+    (handle,) = e.queries.values()
+    assert handle.backend == "distributed", e.fallback_reasons
+    _feed(e, n=32)
+    dev = handle.executor.device
+    from ksql_tpu.common.batch import HostBatch
+
+    arrays = dev.encode(HostBatch.from_rows(dev.c.layout.schema, []))
+    text = dev._step.lower(dev.state, arrays).as_text(debug_info=True)
+    assert "exchange/" in text and "probe_insert/" in text
+    stats = e.trace_recorder(handle.query_id).stage_stats()
+    # the sharded step's children and its slowest shard's probe loop
+    assert {*STEP_CHILDREN, "device.step"} <= set(stats)
+    assert stats["device.step"]["sampled"] >= 1
+    e.shutdown()
+
+
+def test_counter_only_stage_reports_no_time():
+    """device.step is never timed: it reports its counters and no
+    total/p50/p99 — a 0 there reads as 'this costs nothing' — in
+    stage_stats, EXPLAIN ANALYZE and the Prometheus text."""
+    from ksql_tpu.common.metrics import prometheus_text
+
+    e, handle = _device_count_engine()
+    _drive_ticks(e, 2)
+    qid = handle.query_id
+    stats = e.trace_recorder(qid).stage_stats()
+    assert stats["device.step"]["n"] == 0
+    assert not {"total_ms", "p50_ms", "p99_ms"} & set(stats["device.step"])
+    assert stats["device.step"]["probe_rounds"] > 0
+    assert {"total_ms", "p50_ms", "p99_ms", "self_ms"} <= set(stats["drain"])
+    rows = {r["stage"]: r for r in e.execute_sql(f"EXPLAIN ANALYZE {qid};")[0].rows}
+    assert rows["device.step"]["totalMs"] is None
+    assert "probe_rounds" in rows["device.step"]["extra"]
+    assert rows["drain"]["totalMs"] > 0 and list(rows)[-1] == "tick"
+    text = prometheus_text(e.metrics_snapshot(), {qid: stats})
+    timed = [ln for ln in text.splitlines()
+             if ln.startswith(("ksql_query_stage_ms_total", "ksql_query_stage_latency_ms"))]
+    assert any('stage="drain"' in ln for ln in timed)
+    assert not any('stage="device.step"' in ln for ln in timed)
+    assert f'ksql_query_stage_probe_rounds_total{{query="{qid}",stage="device.step"}}' in text
+    assert f'ksql_query_stage_self_ms_total{{query="{qid}",stage="tick"}}' in text
+    assert f'ksql_query_stage_h2d_bytes_total{{query="{qid}",stage="step.dispatch"}}' in text
+    e.shutdown()
+
+
+def _shape_of(rec):
+    """What a recorder holds, without its times."""
+    ticks = [[(s["name"], s["depth"]) for s in t["spans"]] for t in rec.recent()]
+    stats = {
+        name: {k: v for k, v in st.items()
+               if not k.endswith("_ms") and k != "d2h_bytes"}
+        for name, st in rec.stage_stats().items()
+    }
+    return ticks, stats
+
+
+def test_trace_annotations_leave_the_recorder_unchanged(tmp_path):
+    """Every span is mirrored as a jax.profiler.TraceAnnotation: with a
+    profiler session open the trace holds the tick and its spans on the
+    profiler's clock, and what the recorder keeps is the same either way."""
+    import glob
+
+    from jax import profiler
+
+    shapes = []
+    for session in (False, True):
+        e, handle = _device_count_engine()
+        if session:
+            profiler.start_trace(str(tmp_path))
+        try:
+            _drive_ticks(e, 3)
+        finally:
+            if session:
+                profiler.stop_trace()
+        shapes.append(_shape_of(e.trace_recorder(handle.query_id)))
+        qid = handle.query_id
+        e.shutdown()
+    assert shapes[0] == shapes[1]
+    (xplane,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    names = set()
+    for plane in profiler.ProfileData.from_file(xplane).planes:
+        for line in plane.lines:
+            names.update(ev.name for ev in line.events)
+    assert {f"ksql.tick#{qid}#{seq}" for seq in (1, 2, 3)} <= names
+    assert {"poll", "process", "drain", "device.execute", "step.wait",
+            "emit.decode", "emit.dispatch", "commit"} <= names
