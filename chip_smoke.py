@@ -466,6 +466,7 @@ def _drive_served(phase, srv, engine, corpus, sizes, platform, backend,
     brief = {
         name: {k: st[k] for k in ("n", "total_ms", "self_ms", "p50_ms", "p99_ms",
                                   "jit_miss", "jit_hit", "probe_rounds",
+                                  "probe_lane_rounds",
                                   "sampled", "h2d_bytes", "d2h_bytes")
                if k in st}
         for name, st in stages.items()

@@ -62,7 +62,8 @@ is never timed and reports no time):
 ``emit.decode``       span: load check, d2h of the emit columns, row
                       building (``d2h_bytes``)
 ``device.step``     counters the step program reports about its own work
-                    (``probe_rounds`` over ``sampled`` load checks)
+                    (``probe_rounds`` and ``probe_lane_rounds``, the lanes
+                    those rounds worked on, over ``sampled`` load checks)
 ``exchange``        counters: distributed all-to-all (rows / bytes)
 ``emit.dispatch``   span: emit callbacks, block encode, the per-emit loop
 ``sink.produce``    total: SinkWriter.produce (all backends)

@@ -19,16 +19,21 @@ static target):
 * ``dirty``    bool      — updated since last suppress flush (EMIT FINAL)
 * ``a<j>``     per-aggregate component arrays (see device_aggs.py)
 
-Insert algorithm (per batch, fully vectorized over rows):
+Insert algorithm (per chunk of ``_PROBE_CHUNK`` consecutive lanes of a
+batch, chunks in row order, fully vectorized over a chunk's rows):
 repeat until every active row is resolved — gather candidate slot; if it
 matches, resolve; if empty, *claim* it by scatter-min of the row index and
 let the winner write its key (losers re-examine the slot next round: if the
 winner had the same key they resolve to it, otherwise they advance along
 the probe sequence).  The loop stops as soon as no row is pending, so a
-batch pays for the longest probe sequence it holds, not for a fixed count;
-``MAX_PROBES`` only bounds it.  Rows still unresolved at the bound land in
-the dump slot and are counted in ``overflow`` — the host reacts by growing
-the table (host-side rebuild), the moral equivalent of RocksDB compaction.
+chunk pays for the longest probe sequence it holds, not for a fixed count
+(``MAX_PROBES`` only bounds it), and a batch pays for the chunks that hold
+a row, not for its padded shape: every round costs what its lanes cost,
+0.070 ms at 256 lanes against 6.92 ms at 32,768 on one v5e chip (2^21
+slots at 47 % load; my chip runs, PR 26).  Rows still unresolved at the
+bound land in the dump slot and are counted in ``overflow`` — the host
+reacts by growing the table (host-side rebuild), the moral equivalent of
+RocksDB compaction.
 
 Why the bound is far above what a probe usually takes: linear probing's
 LONGEST sequence grows with the table as well as with its load.  Filling
@@ -139,24 +144,28 @@ def init_store(layout: StoreLayout) -> Dict[str, jnp.ndarray]:
     return store
 
 
-@jax.named_scope("probe_insert")
-def probe_insert(
-    store: Dict[str, jnp.ndarray],
-    capacity: int,
-    khash: jnp.ndarray,
-    wstart: jnp.ndarray,
-    key_reprs: Sequence[jnp.ndarray],
-    knull: jnp.ndarray,
-    active: jnp.ndarray,
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray]:
-    """Resolve (and create) one slot per active row; returns (store, slots,
-    rounds).
+#: lanes one pass of the probe loop works on.  A round costs what its lanes
+#: cost, whether or not their rows still probe (serial gathers and scatters),
+#: so a batch wider than this is probed in chunks and the empty ones skipped.
+#: Read on one v5e chip (my chip runs, PR 26: ``probe_insert`` alone, 2^21
+#: slots at 47 % load, the benchmark cell's key draw), ms a round by lanes:
+#: 32,768: 6.92 | 8,192: 1.77 | 4,096: 0.97 | 2,048: 0.49 | 1,024: 0.28 |
+#: 512: 0.142 | 256: 0.070 | 128: 0.045 — halving the lanes halves a round
+#: down to 256 and cuts it by 1.5-1.6 below: the per-round floor.  Smaller
+#: chunks also waste fewer lanes on rows that are done (each chunk stops at
+#: its own longest chain), so a whole call reads, in ms at chunks of 4,096 /
+#: 1,024 / 256 / 128 lanes: 4,096 rows of 32,768: 23.1 / 22.0 / 17.7 / 18.5
+#: (unchunked 153.8); 480 rows: 17.1 / 5.7 / 3.6 / 3.7; a full 32,768-row
+#: batch: 183 / 168 / 133 / not read (unchunked 215).
+_PROBE_CHUNK = 256
 
-    ``slots`` is int32 per row; inactive/overflowed rows get the dump slot
-    ``capacity``.  ``rounds`` (int32 scalar) is how many times the probe
-    loop ran: the longest probe sequence among the batch's rows, claim
-    retries included — 0 for a batch with no active row.
-    """
+#: store columns ``probe_insert`` reads or writes: what its chunk loop carries
+_PROBE_COLUMNS = ("occ", "grave", "khash", "wstart", "knull", "overflow")
+
+
+def _insert_lanes(store, capacity, khash, wstart, key_reprs, knull, active):
+    """One pass of the probe-and-claim loop over all the lanes given, and
+    the key writes that follow it: (store, slots, rounds)."""
     n = khash.shape[0]
     mask = capacity - 1
     dump = jnp.int32(capacity)
@@ -229,6 +238,82 @@ def probe_insert(
         store[f"key{i}"] = store[f"key{i}"].at[target].set(repr_col)
     store["knull"] = store["knull"].at[target].set(knull)
     return store, jnp.where(done, slots, dump), rounds
+
+
+@jax.named_scope("probe_insert")
+def probe_insert(
+    store: Dict[str, jnp.ndarray],
+    capacity: int,
+    khash: jnp.ndarray,
+    wstart: jnp.ndarray,
+    key_reprs: Sequence[jnp.ndarray],
+    knull: jnp.ndarray,
+    active: jnp.ndarray,
+    *,
+    _chunk: int = _PROBE_CHUNK,
+) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Resolve (and create) one slot per active row; returns (store, slots,
+    rounds, lane_rounds).
+
+    ``slots`` is int32 per row; inactive/overflowed rows get the dump slot
+    ``capacity``.  Rows are probed ``W = min(n, _chunk)`` consecutive lanes
+    at a time, in row order, and a chunk with no active row is not visited.
+    ``rounds`` (int32 scalar) is how many times the probe loop ran, summed
+    over the chunks visited: per chunk the longest probe sequence among
+    its rows, claim retries included — 0 for a batch with no active row.
+    ``lane_rounds`` is the lanes those rounds worked on: every round of a
+    chunk pays for all ``W`` of its lanes, so ``rounds * W``.
+    """
+    n = khash.shape[0]
+    width = min(n, _chunk)
+    if n == width:
+        store, slots, rounds = _insert_lanes(
+            store, capacity, khash, wstart, key_reprs, knull, active
+        )
+        return store, slots, rounds, rounds * width
+    n_chunks = -(-n // width)
+    pad = n_chunks * width - n
+    if pad:  # whole chunks: the padding lanes are inactive
+        khash, wstart, knull, active, *key_reprs = (
+            jnp.pad(x, (0, pad))
+            for x in (khash, wstart, knull, active, *key_reprs)
+        )
+    columns = _PROBE_COLUMNS + tuple(f"key{i}" for i in range(len(key_reprs)))
+    chunk_ids = jnp.arange(n_chunks, dtype=jnp.int32)
+    occupied = jnp.any(active.reshape(n_chunks, width), axis=1)
+
+    def next_chunk(after):
+        """The first chunk past ``after`` that holds an active row."""
+        return jnp.min(
+            jnp.where(occupied & (chunk_ids > after), chunk_ids, n_chunks)
+        )
+
+    def visit(carry):
+        chunk, rounds, tables, slots = carry
+        lo = chunk * width
+        khash_c, wstart_c, knull_c, active_c, *reprs_c = (
+            jax.lax.dynamic_slice_in_dim(x, lo, width)
+            for x in (khash, wstart, knull, active, *key_reprs)
+        )
+        tables, slots_c, rounds_c = _insert_lanes(
+            tables, capacity, khash_c, wstart_c, reprs_c, knull_c, active_c
+        )
+        slots = jax.lax.dynamic_update_slice_in_dim(slots, slots_c, lo, 0)
+        return next_chunk(chunk), rounds + rounds_c, tables, slots
+
+    # initial carries derive from varying inputs, as in ``_insert_lanes``
+    zero_i32 = (khash * 0).astype(jnp.int32)
+    _, rounds, tables, slots = jax.lax.while_loop(
+        lambda carry: carry[0] < n_chunks,
+        visit,
+        (
+            next_chunk(jnp.sum(zero_i32) - 1),
+            jnp.sum(zero_i32),
+            {name: store[name] for name in columns},
+            zero_i32 + jnp.int32(capacity),
+        ),
+    )
+    return {**store, **tables}, slots[:n], rounds, rounds * width
 
 
 @jax.named_scope("probe_find")

@@ -485,6 +485,9 @@ class DistributedDeviceQuery:
             tracing.counter(
                 "device.step", sampled=1,
                 probe_rounds=int(np.asarray(emits["probe_rounds"]).max()),
+                probe_lane_rounds=int(
+                    np.asarray(emits["probe_lane_rounds"]).max()
+                ),
             )
 
     def process_ss(self, batch: HostBatch, side: str) -> List[SinkEmit]:

@@ -2315,7 +2315,7 @@ class CompiledDeviceQuery:
         cap_t = jspec.capacity
         dump = jnp.int32(cap_t)
         zeros64 = jnp.zeros(n, jnp.int64)
-        jt, slots, _ = probe_insert(
+        jt, slots, _, _ = probe_insert(
             dict(state[key]), cap_t, khash, zeros64, [krepr],
             jnp.zeros(n, jnp.int32), act,
         )
@@ -2408,7 +2408,7 @@ class CompiledDeviceQuery:
             slots, _ = probe_find(store, cap, khash, zeros64, active)
             active = active & (slots != dump)
         else:
-            store, slots, _ = probe_insert(
+            store, slots, _, _ = probe_insert(
                 store, cap, khash, zeros64, reprs, knull, active
             )
         slot_or_dump = jnp.where(active, slots, dump)
@@ -2554,7 +2554,7 @@ class CompiledDeviceQuery:
         khash = combine_hash([krepr])
         touched = a_new["row_valid"] & kcol.valid
         zeros64 = jnp.zeros(n, jnp.int64)
-        fkl, slots, _ = probe_insert(
+        fkl, slots, _, _ = probe_insert(
             fkl, cap, khash, zeros64, [krepr], jnp.zeros(n, jnp.int32),
             touched,
         )
@@ -2633,7 +2633,7 @@ class CompiledDeviceQuery:
         khash = combine_hash([krepr])
         touched = a_new["row_valid"] & kcol.valid
         zeros64 = jnp.zeros(n, jnp.int64)
-        fkr, slots, _ = probe_insert(
+        fkr, slots, _, _ = probe_insert(
             fkr, cap, khash, zeros64, [krepr], jnp.zeros(n, jnp.int32),
             touched,
         )
@@ -2849,7 +2849,7 @@ class CompiledDeviceQuery:
         khash = combine_hash([krepr])
         touched = a_new["row_valid"] & kcol.valid
         zeros64 = jnp.zeros(n, jnp.int64)
-        tt, slots, _ = probe_insert(
+        tt, slots, _, _ = probe_insert(
             tt, cap, khash, zeros64, [krepr], jnp.zeros(n, jnp.int32), touched
         )
         found = slots != dump
@@ -3722,7 +3722,7 @@ class CompiledDeviceQuery:
         winner = boundary & seg_alive[seg]
         sess_ovf = jnp.sum(winner & (rank >= S))
         ins_act = winner & (rank < S)
-        state, ins_slots, _ = probe_insert(
+        state, ins_slots, _, _ = probe_insert(
             state, cap, kh, rank.astype(jnp.int64),
             [r[seg] for r in seg_reprs],
             jnp.zeros(m, jnp.int32), ins_act,
@@ -3980,7 +3980,7 @@ class CompiledDeviceQuery:
             if self.sliced
             else payload["wstart"]
         )
-        store, slots, probe_rounds = probe_insert(
+        store, slots, probe_rounds, probe_lane_rounds = probe_insert(
             state,
             self.store_capacity,
             payload["khash"],
@@ -4079,8 +4079,9 @@ class CompiledDeviceQuery:
         emits["graves"] = jnp.sum(store["grave"])
         emits["overflow"] = store["overflow"]
         # the store's own account of this step: rounds of its probe loop
-        # (the while_loop's first carry), read beside the load scalars
+        # and the lanes they worked on, read beside the load scalars
         emits["probe_rounds"] = probe_rounds.astype(jnp.int32)
+        emits["probe_lane_rounds"] = probe_lane_rounds.astype(jnp.int32)
         if self.sliced:
             # host mirror of the stream clock (rides the existing per-batch
             # load readback): lower-bounds the admission floor ensure_ring_for
@@ -4514,7 +4515,9 @@ class CompiledDeviceQuery:
             # is the denominator: pipelined ticks check every 4th batch
             tracing.counter(
                 "device.step",
-                probe_rounds=int(emits["probe_rounds"]), sampled=1,
+                probe_rounds=int(emits["probe_rounds"]),
+                probe_lane_rounds=int(emits["probe_lane_rounds"]),
+                sampled=1,
             )
         headroom = self.capacity * self.expansion
         if self.pipeline:

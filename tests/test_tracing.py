@@ -565,7 +565,7 @@ def test_probe_rounds_equal_a_plain_linear_probing_count(load):
         chunk, act = np.zeros(batch, np.int64), np.zeros(batch, bool)
         part = resident[lo:lo + batch]
         chunk[:len(part)], act[:len(part)] = part, True
-        store, _, _ = insert(store, chunk, act)
+        store, _, _, _ = insert(store, chunk, act)
     occ = np.asarray(store["occ"])[:capacity]
     held = np.asarray(store["khash"])
     table = {int(s): int(held[s]) for s in np.nonzero(occ)[0]}
@@ -578,13 +578,19 @@ def test_probe_rounds_equal_a_plain_linear_probing_count(load):
         rng.integers(-2**62, 2**62, batch // 4)])
     active = np.ones(batch, bool)
     active[-batch // 8:] = False
-    want = _reference_probe_rounds(table, capacity, keys, active)
-    store, _, rounds = insert(store, keys, active)
+    # the store resolves a batch a chunk of lanes at a time, in row order
+    width = min(batch, hs._PROBE_CHUNK)
+    want = sum(
+        _reference_probe_rounds(
+            table, capacity, keys[lo:lo + width], active[lo:lo + width])
+        for lo in range(0, batch, width))
+    store, _, rounds, lane_rounds = insert(store, keys, active)
     assert int(rounds) == want and want > 1
+    assert int(lane_rounds) == want * width
     assert int(np.asarray(store["occ"]).sum()) == len(table)
     # a batch with no valid row runs no round
-    _, _, none = insert(store, keys, np.zeros(batch, bool))
-    assert int(none) == 0
+    _, _, none, no_lanes = insert(store, keys, np.zeros(batch, bool))
+    assert int(none) == 0 and int(no_lanes) == 0
     found, find_rounds = jax.jit(lambda st, kh: hs.probe_find(
         st, capacity, kh, jnp.zeros_like(kh), jnp.ones(kh.shape, bool)))(store, keys)
     assert 1 <= int(find_rounds) <= want
