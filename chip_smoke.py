@@ -466,7 +466,9 @@ def _drive_served(phase, srv, engine, corpus, sizes, platform, backend,
     brief = {
         name: {k: st[k] for k in ("n", "total_ms", "self_ms", "p50_ms", "p99_ms",
                                   "jit_miss", "jit_hit", "probe_rounds",
-                                  "probe_lane_rounds",
+                                  "probe_lane_rounds", "find_rounds",
+                                  "join_rows", "join_matched", "rows",
+                                  "steps", "grows",
                                   "sampled", "h2d_bytes", "d2h_bytes")
                if k in st}
         for name, st in stages.items()
@@ -569,6 +571,11 @@ def _twin(statements: Sequence[str], feeds, backend: str, platform: str,
                 execute_ms=round(st.get("device.execute", {}).get("total_ms", 0.0), 1),
             )
             check(comp.get("jit_miss", 0) > 0, f"{name}: no compile recorded")
+            # what the step programs count about themselves (joins too)
+            facts.update({
+                stage: {k: v for k, v in st[stage].items() if k not in ("n", "ticks")}
+                for stage in ("table.upsert", "device.step") if stage in st
+            })
         sink = e.broker.topic(handle.plan.physical_plan.topic)
         return [
             (r.key, r.value, r.timestamp, r.window) for r in sink.all_records()

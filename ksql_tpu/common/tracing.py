@@ -58,12 +58,19 @@ is never timed and reports no time):
 ``device.execute``  span: a device step served from the jit cache (hit)
 ``step.dispatch``     span: h2d of the batch + enqueue of the step
                       (``h2d_bytes``)
-``step.wait``         span: the host blocked on the step's outputs
+``step.wait``         span: the host blocked on the step's outputs (a
+                      join-table step reads its four load scalars there)
 ``emit.decode``       span: load check, d2h of the emit columns, row
                       building (``d2h_bytes``)
+``table.grow``        span: a join table doubled (host rebuild; the steps
+                      recompile at their next call)
 ``device.step``     counters the step program reports about its own work
                     (``probe_rounds`` and ``probe_lane_rounds``, the lanes
-                    those rounds worked on, over ``sampled`` load checks)
+                    those rounds worked on, over ``sampled`` load checks;
+                    a stream-table join's lookups: ``find_rounds``,
+                    ``join_rows`` probed, ``join_matched``)
+``table.upsert``    counters of the join-table steps (``rows``, ``steps``,
+                    ``probe_rounds``, ``probe_lane_rounds``, ``grows``)
 ``exchange``        counters: distributed all-to-all (rows / bytes)
 ``emit.dispatch``   span: emit callbacks, block encode, the per-emit loop
 ``sink.produce``    total: SinkWriter.produce (all backends)
@@ -113,8 +120,10 @@ _STAGE_RANK = {
     "step.dispatch": 22,
     "step.wait": 23,
     "emit.decode": 24,
-    "device.step": 25,
-    "exchange": 26,
+    "table.grow": 25,
+    "device.step": 26,
+    "table.upsert": 27,
+    "exchange": 28,
     "emit.dispatch": 29,
     "sink.produce": 30,
     "commit": 31,

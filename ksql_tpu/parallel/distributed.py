@@ -404,12 +404,25 @@ class DistributedDeviceQuery:
                 )
                 for k, v in arrays.items()
             }
+            with tracing.span("step.dispatch"):
+                tracing.counter(
+                    "step.dispatch",
+                    h2d_bytes=int(sum(v.nbytes for v in arrays.values())),
+                )
+                self.state, metrics = self._table_step(self.state, arrays)
+            with tracing.span("step.wait"):
+                # one blocking read of the load scalars; every replica folds
+                # the same batch, so the slowest sets the pace
+                load = {
+                    k: int(np.asarray(v).max())
+                    for k, v in jax.device_get(metrics).items()
+                }
             tracing.counter(
-                "step.dispatch",
-                h2d_bytes=int(sum(v.nbytes for v in arrays.values())),
+                "table.upsert", rows=hb.num_rows, steps=1,
+                probe_rounds=load["probe_rounds"],
+                probe_lane_rounds=load["probe_lane_rounds"],
             )
-            self.state, metrics = self._table_step(self.state, arrays)
-        occ = int(np.asarray(metrics["occupancy"]).max())
+        occ = load["occupancy"]
         if occ > 0.6 * self.c.table_store_capacity:
             raise RuntimeError(
                 "replicated join-table store nearing capacity "
@@ -488,6 +501,15 @@ class DistributedDeviceQuery:
                 probe_lane_rounds=int(
                     np.asarray(emits["probe_lane_rounds"]).max()
                 ),
+            )
+        if "find_rounds" in emits and tracing.active() is not None:
+            # a stream-table join's lookups: the longest loop among the
+            # shards, the rows of all of them
+            tracing.counter(
+                "device.step", sampled=1,
+                find_rounds=int(np.asarray(emits["find_rounds"]).max()),
+                join_rows=int(np.asarray(emits["join_rows"]).sum()),
+                join_matched=int(np.asarray(emits["join_matched"]).sum()),
             )
 
     def process_ss(self, batch: HostBatch, side: str) -> List[SinkEmit]:

@@ -804,10 +804,12 @@ class DeviceExecutor:
                 "rows": [], "ts": [], "del": [], "parts": [], "offs": []
             }
             for i in range(0, len(rows), cap):
-                hb = HostBatch.from_rows(
-                    schema, rows[i : i + cap], timestamps=ts[i : i + cap],
-                    partitions=parts[i : i + cap], offsets=offs[i : i + cap],
-                )
+                with tracing.span("batch.assemble"):
+                    hb = HostBatch.from_rows(
+                        schema, rows[i : i + cap], timestamps=ts[i : i + cap],
+                        partitions=parts[i : i + cap],
+                        offsets=offs[i : i + cap],
+                    )
                 self._device_step(
                     self.device.process_table,
                     hb, np.asarray(dels[i : i + cap], bool), idx=j,

@@ -2315,7 +2315,7 @@ class CompiledDeviceQuery:
         cap_t = jspec.capacity
         dump = jnp.int32(cap_t)
         zeros64 = jnp.zeros(n, jnp.int64)
-        jt, slots, _, _ = probe_insert(
+        jt, slots, rounds, lane_rounds = probe_insert(
             dict(state[key]), cap_t, khash, zeros64, [krepr],
             jnp.zeros(n, jnp.int32), act,
         )
@@ -2346,6 +2346,8 @@ class CompiledDeviceQuery:
         metrics = {
             "occupancy": jnp.sum(occ | grave),
             "overflow": jt["overflow"],
+            "probe_rounds": rounds,
+            "probe_lane_rounds": lane_rounds,
         }
         return state, metrics
 
@@ -2930,21 +2932,33 @@ class CompiledDeviceQuery:
         if idx < 0:
             idx += len(self.join_chain)
         jspec = self.join_chain[idx]
-        arrays = jspec.layout.encode(batch)
-        pad = np.zeros(self.capacity, bool)
-        pad[: len(deletes)] = deletes
-        arrays["delete"] = pad
-        _note_transfer("h2d_bytes", arrays)
-        self.state, metrics = self._table_steps[idx](self.state, arrays)
-        overflow = int(metrics["overflow"])
-        if overflow > jspec.seen_overflow:
-            jspec.seen_overflow = overflow
+        with tracing.span("batch.assemble"):
+            arrays = jspec.layout.encode(batch)
+            pad = np.zeros(self.capacity, bool)
+            pad[: len(deletes)] = deletes
+            arrays["delete"] = pad
+        with tracing.span("step.dispatch"):
+            _note_transfer("h2d_bytes", arrays)
+            self.state, metrics = self._table_steps[idx](self.state, arrays)
+        with tracing.span("step.wait"):
+            # one blocking read of the step's four load scalars: the host
+            # waits for the step here, and for nothing else
+            load = {k: int(v) for k, v in jax.device_get(metrics).items()}
+        tracing.counter(
+            "table.upsert", rows=batch.num_rows, steps=1,
+            probe_rounds=load["probe_rounds"],
+            probe_lane_rounds=load["probe_lane_rounds"],
+        )
+        if load["overflow"] > jspec.seen_overflow:
+            jspec.seen_overflow = load["overflow"]
             raise QueryRuntimeException(
-                f"device join-table store overflowed ({overflow} rows); "
-                "growth failed to keep pace with key cardinality"
+                f"device join-table store overflowed ({load['overflow']} "
+                "rows); growth failed to keep pace with key cardinality"
             )
-        if int(metrics["occupancy"]) + self.capacity > 0.75 * jspec.capacity:
-            self._grow_table(idx=idx)
+        if load["occupancy"] + self.capacity > 0.75 * jspec.capacity:
+            with tracing.span("table.grow"):
+                self._grow_table(idx=idx)
+            tracing.counter("table.upsert", grows=1)
 
     _table_seen_overflow = 0
 
@@ -2998,11 +3012,15 @@ class CompiledDeviceQuery:
     def _apply_join(
         self, env: Dict[str, DCol], active: jnp.ndarray, n: int,
         jtabs: Dict[str, Dict[str, jnp.ndarray]],
+        stats: Optional[Dict[str, jnp.ndarray]] = None,
     ) -> Tuple[Dict[str, DCol], jnp.ndarray]:
         """Per-row probe of each join store in chain order (an n-way join is
         a sequence of probes with its between-ops applied before each):
         gather right-side columns for matches; INNER drops non-matches,
-        LEFT null-pads (StreamTableJoinNode semantics, oracle.py)."""
+        LEFT null-pads (StreamTableJoinNode semantics, oracle.py).  Into
+        ``stats``, where given, go the probes' own counts, summed over the
+        chain: ``find_rounds`` of ``probe_find``'s loop, ``join_rows``
+        probed and ``join_matched`` found, each an int32 scalar."""
         from ksql_tpu.parser.ast_nodes import JoinType
 
         for idx, jspec in enumerate(self.join_chain):
@@ -3014,10 +3032,17 @@ class CompiledDeviceQuery:
             khash = combine_hash([krepr])
             look = active & kcol.valid
             cap_t = jspec.capacity
-            slots, _ = probe_find(
+            slots, rounds = probe_find(
                 jtab, cap_t, khash, jnp.zeros(n, jnp.int64), look
             )
             found = look & (slots != cap_t)
+            if stats is not None:
+                for name, count in (
+                    ("find_rounds", rounds),
+                    ("join_rows", jnp.sum(look)),
+                    ("join_matched", jnp.sum(found)),
+                ):
+                    stats[name] = stats.get(name, 0) + count.astype(jnp.int32)
             if jspec.step.join_type == JoinType.INNER:
                 active = found
             for col in jspec.cols:
@@ -3442,14 +3467,16 @@ class CompiledDeviceQuery:
                 env, active = self._apply_ops(
                     self.pre_ops[shared_n:], env, active, n
                 )
+                join_stats: Dict[str, jnp.ndarray] = {}
                 if self.join is not None:
                     env, active = self._apply_join(
-                        env, active, n, self._jtabs_of(state)
+                        env, active, n, self._jtabs_of(state), join_stats
                     )
                     env, active = self._apply_ops(self.mid_ops, env, active, n)
             ts = arrays["ts"]
             batch_max_ts = jnp.max(jnp.where(active, ts, np.iinfo(np.int64).min))
             emits = self._emit_stateless(env, active, ts)
+            emits.update(join_stats)
             for m in self.prefix_members:
                 menv, mact = self._apply_ops(
                     m.pre_ops[shared_n:], penv, pactive, n
@@ -4331,7 +4358,9 @@ class CompiledDeviceQuery:
     def process(self, batch: HostBatch) -> List[SinkEmit]:
         if self.ss_join is not None:
             return self.process_ss(batch, "l")
-        return self.process_arrays(self.layout.encode(batch))
+        with tracing.span("batch.assemble"):
+            arrays = self.layout.encode(batch)
+        return self.process_arrays(arrays)
 
     def process_arrays(self, arrays: Dict[str, np.ndarray]) -> List[SinkEmit]:
         """One encoded micro-batch through the device step (the entry the
@@ -4393,8 +4422,22 @@ class CompiledDeviceQuery:
         with tracing.span("emit.decode"):
             if react:
                 self._react_to_load(emits)
+            self._note_join_stats(emits)
             self._deliver_members(emits)
             return self._decode_emits(emits)
+
+    _JOIN_STATS = ("find_rounds", "join_rows", "join_matched")
+
+    def _note_join_stats(self, emits: Dict[str, jnp.ndarray]) -> None:
+        """Book the stream-table join's own counts of a step (the lookup
+        loop's rounds, rows probed, rows matched) on ``device.step``: one
+        read of three scalars after the wait, every step."""
+        if "find_rounds" in emits and tracing.active() is not None:
+            counts = jax.device_get([emits[k] for k in self._JOIN_STATS])
+            tracing.counter(
+                "device.step", sampled=1,
+                **{k: int(v) for k, v in zip(self._JOIN_STATS, counts)},
+            )
 
     def _deliver_members(self, emits: Dict[str, jnp.ndarray]) -> None:
         """Decode + deliver the attached members' emission blocks

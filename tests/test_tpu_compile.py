@@ -17,6 +17,8 @@ touches the topology at import, and the one worker that is handed this
 file loads the library.  The compiles run in the test's own process.
 """
 
+import json
+import os
 import re
 import time
 
@@ -117,6 +119,33 @@ def test_config1_tumbling_count_at_the_smokes_size(one_chip):
     step = _compile(dev._step, _state(dev, one_chip), arrays)
     assert len(re.findall(r"= .* while\(", step.as_text())) == 2
     _compile(dev._evict, _state(dev, one_chip))
+
+
+def test_clicks_join_cell_stream_step_and_table_step(one_chip):
+    """The benchmark cell ``clicks_join.backlog``'s two programs at its
+    shapes: 32,768 lanes against the join table at the 2^16 slots it starts
+    with and the generator's ten users never outgrow.  The stream step's one
+    loop is ``probe_find``'s, the table step's two are ``probe_insert``'s
+    chunk loop and its probe loop; both carry their own counts out."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark/configs/clicks_users_join.json")) as f:
+        config = json.load(f)
+    dev = _lowered(config["statements"],
+                   capacity=config["engine_props"]["ksql.batch.capacity"])
+    assert dev.capacity == 32_768 and dev.table_store_capacity == 1 << 16
+    assert sorted(c.name for c in dev.table_cols) == [
+        "USERS_ORIGINAL_GENDER", "USERS_ORIGINAL_REGIONID"]
+    state = _state(dev, one_chip)
+    arrays = _on(one_chip, dev.layout.array_structs())
+    step = _compile(dev._step, state, arrays)
+    assert len(re.findall(r"= .* while\(", step.as_text())) == 1
+    emits = jax.eval_shape(dev._trace_step, state, arrays)[1]
+    assert {"find_rounds", "join_rows", "join_matched"} <= set(emits)
+    table_arrays = _on(one_chip, dev._table_array_structs())
+    table_step = _compile(dev._table_step, state, table_arrays)
+    assert len(re.findall(r"= .* while\(", table_step.as_text())) == 2
+    metrics = jax.eval_shape(dev._trace_table_step, state, table_arrays)[1]
+    assert set(metrics) == {"occupancy", "overflow", "probe_rounds", "probe_lane_rounds"}
 
 
 def test_config2_hopping_multi_udaf_double(one_chip):
