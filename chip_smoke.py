@@ -362,10 +362,18 @@ def _drive_served(phase, srv, engine, corpus, sizes, platform, backend,
     # one more tick flushes the emissions the double-buffer still holds
     sink = engine.broker.topic(handle.plan.physical_plan.topic)
     last, stable_since = -1, time.perf_counter()
-    while time.perf_counter() - stable_since < 0.5:
+    while True:
         size = sum(sink.end_offsets())
         if size != last:
             last, stable_since = size, time.perf_counter()
+        elif time.perf_counter() - stable_since >= 0.5:
+            # a tick holds the server's engine lock from its poll to its
+            # last sink record (a block's records land together, at the
+            # end of its dispatch): with the lock in hand no tick is
+            # half-way, whatever the sink's size said meanwhile
+            with srv.engine_lock:
+                if sum(sink.end_offsets()) == size:
+                    break
         time.sleep(0.05)
         fail_fast()
     warm_n = n - t_first[1] if t_first else 0

@@ -116,6 +116,18 @@ class QueryProgress:
         self.e2e.record(seconds)
         self.e2e_hist.record(seconds)
 
+    def record_emit_block(self, event_ts_ms: List[int],
+                          now_ms: Optional[int] = None) -> None:
+        """``record_e2e`` for each emission of a block and one
+        ``note_materialized``, against one read of the clock: within a
+        block the per-emit reads differ by the dispatch loop's own
+        microseconds."""
+        now_ms = _now_ms() if now_ms is None else now_ms
+        seconds = [max(now_ms - ts, 0) / 1000.0 for ts in event_ts_ms]
+        self.e2e.record_block(seconds)
+        self.e2e_hist.record_block(seconds)
+        self.materialized_at_ms = now_ms
+
     def note_materialized(self, now_ms: Optional[int] = None) -> None:
         """One materialized-state write (the engine's emit callback): the
         freshness clock for replicas whose sink is disabled (standbys have

@@ -18,7 +18,7 @@ import bisect
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 RATE_WINDOW_S = 30.0
 
@@ -81,6 +81,13 @@ class LatencyHistogram:
             self._samples.append(seconds * 1000.0)
             self._sorted = None
 
+    def record_block(self, seconds: List[float]) -> None:
+        """``record`` for each of a block's samples, in order, under one
+        hold of the lock."""
+        with self._lock:
+            self._samples.extend(s * 1000.0 for s in seconds)
+            self._sorted = None
+
     def percentile(self, p: float) -> Optional[float]:
         with self._lock:
             if not self._samples:
@@ -113,6 +120,16 @@ class E2eHistogram:
             self.counts[idx] += 1
             self.count += 1
             self.sum_s += seconds
+
+    def record_block(self, seconds: List[float]) -> None:
+        """``record`` for each of a block's samples under one hold of the
+        lock."""
+        bounds, counts = self.bounds, self.counts
+        with self._lock:
+            for s in seconds:
+                counts[bisect.bisect_left(bounds, s)] += 1
+            self.count += len(seconds)
+            self.sum_s += sum(seconds)
 
     def percentile(self, p: float) -> Optional[float]:
         """Interpolated percentile in ms (the +Inf bucket clamps to the

@@ -72,8 +72,12 @@ is never timed and reports no time):
 ``table.upsert``    counters of the join-table steps (``rows``, ``steps``,
                     ``probe_rounds``, ``probe_lane_rounds``, ``grows``)
 ``exchange``        counters: distributed all-to-all (rows / bytes)
-``emit.dispatch``   span: emit callbacks, block encode, the per-emit loop
-``sink.produce``    total: SinkWriter.produce (all backends)
+``emit.dispatch``   span: block encode, then the emit callbacks and the sink
+                    produce, for the block at once or emit by emit (``rows``
+                    dispatched, ``block_rows`` of them as a block)
+``sink.produce``    total: SinkWriter's block encode, and its produce: one
+                    stage a block (``n`` = its records) on the block path,
+                    one an emit in the per-emit loop (all backends)
 ``commit``          span: the tick's commit point (commit cursor, state
                     epoch, changelog append, query metrics)
 ``poison.skip``     USER-classified records skipped by the poll loop

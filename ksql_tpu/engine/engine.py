@@ -1965,6 +1965,29 @@ class KsqlEngine:
                     self._on_error("scalable-push", exc)  # subscriber must
                     # not take down the persistent query
 
+        def on_emit_block(emits: List[SinkEmit]) -> bool:
+            """on_emit for a whole emission block in one pass: the same
+            writes in emit order, one mark and one clock read for the
+            block.  False, with nothing done, while a push listener is
+            subscribed: it sees each emit before the next is produced,
+            so its caller goes through on_emit emit by emit."""
+            if handle.push_listeners:
+                return False
+            if not fence["live"]:
+                return True  # fenced-off zombie executor: drop the block
+            materialized = handle.materialized
+            for e in emits:
+                materialized[(_hashable(e.key), e.window)] = (
+                    e.row, e.window, e.key, e.ts
+                )
+            qmetrics.messages_out.mark(len(emits))
+            if handle.progress is not None:
+                handle.progress.record_emit_block([e.ts for e in emits])
+            return True
+
+        # the executor finds the block twin on the callback it was given
+        on_emit.block = on_emit_block
+
         def on_query_error(where: str, exc: Exception) -> None:
             qmetrics.errors.mark(1)
             self._on_error(where, exc)

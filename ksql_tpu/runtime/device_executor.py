@@ -60,6 +60,10 @@ class DeviceExecutor:
         self.plan = plan
         self.broker = broker
         self.on_error = on_error or (lambda expr, e: None)
+        # per-emit callback; where it carries a ``block`` attribute (the
+        # engine's on_emit does), that is its twin for a whole emission
+        # block, which _dispatch_emits calls when nothing wants the emits
+        # one at a time
         self.emit_callback = emit_callback
         # batch-granularity emit hook (fused tap residuals): called once
         # per decoded emission batch, before the per-emit callback fan-out
@@ -872,20 +876,42 @@ class DeviceExecutor:
             # evaluate it before the rows fan out one at a time below
             self.batch_emit_callback(emits)
         # block-batched sink encode: serialize the emission block's values
-        # column-at-a-time up front; the per-emit loop below keeps its
-        # exact interleaving (callbacks, emit_seq ordinals, fault context,
-        # retries) and just skips the row serializer where precoded
-        precoded = self.sink_writer.encode_batch(emits)
-        if precoded is None:
+        # column-at-a-time up front
+        writer = self.sink_writer
+        precoded = writer.encode_batch(emits)
+        callback = self.emit_callback
+        # the callback's twin for a whole block (the engine hangs it on
+        # its on_emit); it declines, untouched, when a subscriber wants
+        # to see each emit before the next is produced
+        block_callback = getattr(callback, "block", None)
+        block_rows = 0
+        if writer.block_ready(precoded) and (
+            callback is None
+            or (block_callback is not None and block_callback(emits))
+        ):
+            # block dispatch: nothing observable asks for per-emit
+            # treatment, so the callbacks ran in one pass and the sink
+            # takes one append; a failed append entered nothing and
+            # leaves the block to the per-emit produce and its retries
+            if writer.produce_block(emits, precoded):
+                block_rows = len(emits)
+            else:
+                for e, v in zip(emits, precoded):
+                    writer.produce(e, precoded=v)
+        # else the per-emit loop keeps its exact interleaving (callbacks,
+        # emit_seq ordinals, fault context, retries) and just skips the
+        # row serializer where precoded
+        elif precoded is None:
             for e in emits:
-                if self.emit_callback is not None:
-                    self.emit_callback(e)
-                self.sink_writer.produce(e)
+                if callback is not None:
+                    callback(e)
+                writer.produce(e)
         else:
             for e, v in zip(emits, precoded):
-                if self.emit_callback is not None:
-                    self.emit_callback(e)
-                self.sink_writer.produce(e, precoded=v)
+                if callback is not None:
+                    callback(e)
+                writer.produce(e, precoded=v)
+        tracing.counter("emit.dispatch", rows=len(emits), block_rows=block_rows)
 
 
 class DistributedDeviceExecutor(DeviceExecutor):

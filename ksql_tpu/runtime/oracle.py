@@ -35,7 +35,7 @@ from ksql_tpu.execution import steps as st
 from ksql_tpu.execution.interpreter import ExpressionCompiler, TypeResolver
 from ksql_tpu.functions.registry import FunctionRegistry
 from ksql_tpu.parser.ast_nodes import JoinType, WindowType
-from ksql_tpu.runtime.topics import Broker, Record
+from ksql_tpu.runtime.topics import Broker, Record, Topic
 from ksql_tpu.serde import formats as fmt
 from ksql_tpu.functions.udafs import _hashable
 
@@ -1047,6 +1047,13 @@ def _apply_path_default(row, path, default):
     return rec(row, 0)
 
 
+def _wrapped(obj, name: str, cls) -> bool:
+    """True when ``obj.<name>`` is no longer ``cls``'s own method: set on
+    the instance (tests and operators wrap the produce seams so) or
+    overridden below ``cls``."""
+    return getattr(getattr(obj, name), "__func__", None) is not getattr(cls, name)
+
+
 #: sentinel for SinkWriter.produce's ``precoded`` parameter — None is a
 #: meaningful precoded value (a tombstone's payload), so absence needs
 #: its own marker
@@ -1278,6 +1285,68 @@ class SinkWriter:
         finally:
             self._precoded = _UNSET
 
+    def _key_serializer(self):
+        """The sink's key serializer as the sink step stands now."""
+        formats = self.sink_step.formats
+        return fmt.key_serializer(
+            formats.key_format, self.sink_step.schema.key_columns,
+            wrapped=getattr(formats, "key_wrapped", False),
+            delimiter=getattr(formats, "key_delimiter", None),
+        )
+
+    def block_ready(self, precoded: Optional[list]) -> bool:
+        """Whether ``produce_block`` may take the block ``encode_batch``
+        precoded: nothing observable wants its emits produced one at a
+        time.  That is a standby writer (it produces nothing), armed
+        faults (the ``sink.produce`` and ``topic.produce`` points fire per
+        emit, with its ordinal), a wrapped ``produce`` / ``_produce`` or
+        ``Topic.produce``, ordinals still under the effectively-once
+        fence, a sink timestamp column, and a row that ``encode_batch``
+        left to the row serializer."""
+        step = self.sink_step
+        return not (
+            precoded is None
+            or not self.enabled
+            or faults.armed()
+            or self.emit_seq < self.fence_seq
+            or step.timestamp_column
+            or _wrapped(self, "produce", SinkWriter)
+            or _wrapped(self, "_produce", SinkWriter)
+            or _wrapped(self.broker.topic(step.topic), "produce", Topic)
+            or any(v is _UNSET for v in precoded)
+        )
+
+    def produce_block(self, emits: List[SinkEmit], precoded: list) -> bool:
+        """``produce(e, precoded=v)`` for a whole emission block (one that
+        is ``block_ready``) in one append to the sink topic: the same
+        records in the same order, the same ``emit_seq`` and
+        ``journal_buf``, one ``sink.produce`` stage.  A failed append
+        enters nothing into the log: it counts as the block's first
+        attempt, is reported like a retry, and returns False for the
+        caller to send the block through ``produce``."""
+        step = self.sink_step
+        tr = tracing.active()
+        t0 = _time.perf_counter() if tr is not None else 0.0
+        serialize = self._key_serializer()
+        keys = [serialize(e.key) for e in emits]
+        try:
+            records = self.broker.topic(step.topic).produce_block(
+                keys, precoded, [e.ts for e in emits], [e.window for e in emits],
+            )
+        except Exception as exc:  # noqa: BLE001 — as a failed produce
+            self.retries_used += 1
+            self.on_error(f"sink-produce-retry:{step.topic}", exc)
+            return False
+        self.emit_seq += len(records)
+        if self.journal_buf is not None:
+            name = step.topic
+            self.journal_buf.extend(
+                (name, r.key, r.value, r.timestamp, r.window) for r in records
+            )
+        if tr is not None:
+            tr.stage("sink.produce", _time.perf_counter() - t0, n=len(records))
+        return True
+
     def _produce(self, e: SinkEmit) -> None:
         precoded = self._precoded
         self.emit_seq += 1
@@ -1315,11 +1384,7 @@ class SinkWriter:
                 if row is not None
                 else None
             )
-        key = fmt.serialize_key(
-            self.sink_step.formats.key_format, e.key, schema.key_columns,
-            wrapped=getattr(self.sink_step.formats, "key_wrapped", False),
-            delimiter=getattr(self.sink_step.formats, "key_delimiter", None),
-        )
+        key = self._key_serializer()(e.key)
         ts = e.ts
         if self.sink_step.timestamp_column and e.row is not None:
             tv = e.row.get(self.sink_step.timestamp_column)

@@ -69,6 +69,48 @@ class Topic:
             part.append(record)
             return record
 
+    def produce_block(self, keys: List[Any], values: List[Any],
+                      timestamps: List[int],
+                      windows: List[Optional[Tuple[int, int]]]) -> List[Record]:
+        """Append one record per ``(key, value, timestamp, window)`` under
+        one hold of the lock: the partitions, offsets and ``seq`` that as
+        many ``produce`` calls of partition -1 records give, each Record
+        built once.  All or nothing: every record is built before any
+        partition is extended.  ``topic.produce`` fault points fire per
+        record, so a caller sends its records through ``produce`` while
+        faults are armed."""
+        with self._lock:
+            seq = self._seq
+            if self.num_partitions == 1:
+                part = self.partitions[0]
+                out = [
+                    Record(k, v, t, 0, o, s, (), w)
+                    for o, s, k, v, t, w in zip(
+                        range(len(part), len(part) + len(keys)),
+                        range(seq, seq + len(keys)),
+                        keys, values, timestamps, windows,
+                    )
+                ]
+                part.extend(out)
+            else:
+                ends = [len(p) for p in self.partitions]
+                total = sum(ends)
+                out = []
+                for k, v, t, w in zip(keys, values, timestamps, windows):
+                    # partition_for, with a null key's "current size" kept
+                    # in hand (the lock is held: nothing else appends)
+                    p = (
+                        total if k is None else stable_hash64(k)
+                    ) % self.num_partitions
+                    out.append(Record(k, v, t, p, ends[p], seq, (), w))
+                    ends[p] += 1
+                    total += 1
+                    seq += 1
+                for r in out:
+                    self.partitions[r.partition].append(r)
+            self._seq += len(out)
+            return out
+
     def read(self, partition: int, offset: int, max_records: int = 1024) -> List[Record]:
         with self._lock:
             out = self.partitions[partition][offset : offset + max_records]

@@ -16,7 +16,7 @@ import json
 import math
 import re
 import struct
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ksql_tpu.common import faults
 from ksql_tpu.common.errors import SerdeException
@@ -803,37 +803,54 @@ def _of(
     return cls()
 
 
-def serialize_key(key_format: str, key: Tuple[Any, ...], key_columns,
-                  wrapped: bool = False, delimiter: Optional[str] = None) -> Any:
-    """Serialize a key tuple to its on-topic representation.
+def key_serializer(key_format: str, key_columns, wrapped: bool = False,
+                   delimiter: Optional[str] = None) -> Callable[[Tuple[Any, ...]], Any]:
+    """The function from a key tuple to its on-topic representation, with
+    the format, the columns and the delimiter resolved once.
 
     Single key columns are unwrapped for every format that supports it
     (SerdeFeaturesFactory.buildKeyFeatures); PROTOBUF stays wrapped.
     DELIMITED keys are CSV text; envelope formats with multiple key columns
-    produce a column-name-keyed object."""
+    produce a column-name-keyed object.  An empty key is a source record's
+    null key payload passed through untouched (Kafka Streams forwards the
+    original null key bytes)."""
     cols = list(key_columns)
     if not cols:
-        return None
-    if not key:
-        # source record key payload was null and passed through untouched
-        # (Kafka Streams forwards the original null key bytes)
-        return None
+        return lambda key: None
     kf = key_format.upper()
     if kf == "DELIMITED":
-        if all(v is None for v in key):
-            return None
         named = {"SPACE": " ", "TAB": "\t"}
         d = named.get(str(delimiter).upper(), delimiter) if delimiter else ","
-        return DelimitedFormat(d).serialize(
-            {c.name: v for c, v in zip(cols, key)}, cols
-        )
-    if len(cols) == 1 and kf != "PROTOBUF" and not wrapped:
-        return key[0]
-    if kf in ("PROTOBUF", "PROTOBUF_NOSR"):
-        if all(v is None for v in key):
-            return None  # null key message
-        return {c.name: _proto3_default(v, c.type) for c, v in zip(cols, key)}
-    return {c.name: v for c, v in zip(cols, key)}
+        serde = DelimitedFormat(d)
+
+        def serialize(key):
+            if not key or all(v is None for v in key):
+                return None
+            return serde.serialize({c.name: v for c, v in zip(cols, key)}, cols)
+
+    elif len(cols) == 1 and kf != "PROTOBUF" and not wrapped:
+        def serialize(key):
+            return key[0] if key else None
+
+    elif kf in ("PROTOBUF", "PROTOBUF_NOSR"):
+        def serialize(key):
+            if not key or all(v is None for v in key):
+                return None  # null key message
+            return {c.name: _proto3_default(v, c.type) for c, v in zip(cols, key)}
+
+    else:
+        def serialize(key):
+            if not key:
+                return None
+            return {c.name: v for c, v in zip(cols, key)}
+
+    return serialize
+
+
+def serialize_key(key_format: str, key: Tuple[Any, ...], key_columns,
+                  wrapped: bool = False, delimiter: Optional[str] = None) -> Any:
+    """Serialize one key tuple (``key_serializer`` applied once)."""
+    return key_serializer(key_format, key_columns, wrapped, delimiter)(key)
 
 
 def deserialize_key(key_format: str, payload: Any, key_columns,
