@@ -183,9 +183,9 @@ def url_of(k: int) -> str:
 
 
 def make_corpus(seed: int, sizes: Sizes) -> Corpus:
-    """Page views from ``seed``: half from the Zipf(1.3) hot set ``bench.py``
-    draws, half uniform over the URL universe, shuffled together; event
-    times rise evenly across three one-hour windows."""
+    """Page views from ``seed``: half from a Zipf(1.3) hot set, half uniform
+    over the URL universe, shuffled together; event times rise evenly
+    across three one-hour windows."""
     import numpy as np
 
     n = sizes.events
@@ -471,22 +471,12 @@ def _drive_served(phase, srv, engine, corpus, sizes, platform, backend,
     # ---- where the time went, by the system's own spans
     rec = engine.trace_recorders.get(qid)
     stages = rec.stage_stats() if rec is not None else {}
-    brief = {
-        name: {k: st[k] for k in ("n", "total_ms", "self_ms", "p50_ms", "p99_ms",
-                                  "jit_miss", "jit_hit", "probe_rounds",
-                                  "probe_lane_rounds", "find_rounds",
-                                  "join_rows", "join_matched", "rows",
-                                  "steps", "grows",
-                                  "sampled", "h2d_bytes", "d2h_bytes")
-               if k in st}
-        for name, st in stages.items()
-    }
     compile_s = round(stages.get("device.compile", {}).get("total_ms", 0.0) / 1e3, 2)
     check("device.compile" in stages, "no device.compile span recorded")
     import jax
 
     mem = jax.devices()[0].memory_stats() or {}
-    say(phase, step="spans", compile_seconds=compile_s, stages=brief)
+    say(phase, step="spans", compile_seconds=compile_s, stages=stages)
     say(phase, step="memory",
         peak_bytes_in_use=mem.get("peak_bytes_in_use", "not reported"),
         bytes_limit=mem.get("bytes_limit", "not reported"),

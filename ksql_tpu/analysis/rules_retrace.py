@@ -1,7 +1,7 @@
 """jit-retrace rule: patterns that force XLA recompiles or per-call traces.
 
-Every perf direction in ROADMAP (sliced windows, distributed parity, the
-bench regression gate) lives or dies on avoiding silent recompilation —
+Every perf direction in ROADMAP (sliced windows, distributed parity)
+lives or dies on avoiding silent recompilation —
 and until PR 8 the only signal was the ``jit_miss`` counter AFTER the
 throughput had already collapsed.  This rule shifts the bug class left,
 flagging inside the jit-traced call tree (``_trace_*`` functions, ``@jit``

@@ -67,7 +67,7 @@ def _build() -> ctypes.CDLL:
     path = lib_path()
     if not os.path.exists(path):
         # build beside the target and rename: a concurrent process (test
-        # workers, bench children) sees a whole library or none
+        # workers, a second entry point) sees a whole library or none
         tmp = f"{path}.{os.getpid()}.tmp"
         cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
         try:

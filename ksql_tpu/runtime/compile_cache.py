@@ -2,7 +2,7 @@
 
 Every step program is a ``jax.jit`` that XLA compiles at its first
 dispatch — seconds each on the TPU — and every entry point is its own
-process (the server, each ``bench.py`` child, ``chip_smoke.py``, the test
+process (the server, ``benchmark/run.py``, ``chip_smoke.py``, the test
 workers).  A persistent cache lets the second process skip the compile,
 but only if the directory does not move: its path is part of the cache
 key, so a temp name, a pid or a timestamp in it means it never hits.

@@ -36,7 +36,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 #: the tier-1 sweep surface: every tree that feeds the running system
-DEFAULT_PATHS = ["ksql_tpu", "scripts", "bench.py"]
+DEFAULT_PATHS = ["ksql_tpu", "scripts"]
 
 
 def _fingerprint(finding, root: str) -> str:

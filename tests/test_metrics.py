@@ -5,9 +5,14 @@ counts, consumer lag, engine aggregates, surfaced through
 KsqlEngine.metrics_snapshot() and the REST /metrics endpoint."""
 
 import json
+import os
 
+from ksql_tpu.common import config as cfg
+from ksql_tpu.common.config import KsqlConfig
 from ksql_tpu.engine.engine import KsqlEngine
 from ksql_tpu.runtime.topics import Record
+
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 
 def _engine_with_data(n=5, bad=0):
@@ -174,3 +179,64 @@ def test_classifier_markers_are_word_bounded():
 
     assert classify_error(XlaRuntimeError("device wedged")) == "SYSTEM"
     assert classify_error(Exception("failed to deserialize record")) == "USER"
+
+
+# --------------------------------------------- metrics exposition registry
+def test_metrics_registry_complete():
+    """ISSUE satellite: every Prometheus series name a representative
+    engine run emits must be documented in metrics_registry.json — new
+    series land with their registry entry or this fails."""
+    import re
+
+    from ksql_tpu.common.metrics import prometheus_text
+    from ksql_tpu.server.rest import PushQuerySession
+
+    registry = json.load(
+        open(os.path.join(ROOT, "metrics_registry.json"))
+    )["series"]
+    e = KsqlEngine(KsqlConfig({
+        cfg.RUNTIME_BACKEND: "device",
+        cfg.BATCH_CAPACITY: 1024,
+    }))
+    e.execute_sql(
+        "CREATE STREAM PV (URL STRING, V BIGINT) "
+        "WITH (kafka_topic='pv', value_format='JSON');"
+    )
+    e.execute_sql(
+        "CREATE TABLE C AS SELECT URL, COUNT(*) AS CNT FROM PV "
+        "GROUP BY URL EMIT CHANGES;"
+    )
+    e.session_properties["auto.offset.reset"] = "latest"
+    sess = PushQuerySession(e, "SELECT URL FROM PV WHERE V > 1 EMIT CHANGES;")
+    t = e.broker.topic("pv")
+    for i in range(200):
+        t.produce(Record(
+            key=None, value=json.dumps({"URL": f"/p{i % 7}", "V": i}),
+            timestamp=i,
+        ))
+    while e.poll_once():
+        pass
+    sess.poll()
+    snap = e.metrics_snapshot()
+    stages = {
+        qid: rec.stage_stats() for qid, rec in e.trace_recorders.items()
+    }
+    txt = prometheus_text(snap, stages, server={
+        "requests": 3, "errors": 0, "statements-executed": 2,
+        "queries-started": 1,
+    })
+    emitted = {
+        m.group(1)
+        for m in re.finditer(
+            r"^([a-zA-Z_:][a-zA-Z0-9_:]*)[{ ]", txt, re.M
+        )
+        if not m.group(0).startswith("#")
+    }
+    assert emitted, "representative run emitted no series"
+    unlisted = sorted(emitted - set(registry))
+    assert not unlisted, (
+        f"Prometheus series missing from metrics_registry.json: "
+        f"{unlisted} — document them there (name -> meaning) to land"
+    )
+    sess.close()
+    e.shutdown()

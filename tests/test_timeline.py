@@ -10,6 +10,8 @@ registry hygiene gate, and the obs_report renderer."""
 import json
 import os
 import re
+import subprocess
+import sys
 import time
 import urllib.error
 import urllib.request
@@ -49,6 +51,27 @@ class _FakeTrace:
 
 
 # ------------------------------------------------------------- unit: fold
+def test_the_fold_set_is_the_timelines_own():
+    """The stages every tick's fold keeps are named here and decided in
+    ``common/timeline.py`` itself: in a fresh interpreter it imports no
+    other module of the program (it once took the set from a gate's)."""
+    assert tlm.FOLD_STAGES == {
+        "device.compile", "device.execute", "deserialize", "exchange",
+        "sink.produce", "push.pipeline.step", "push.tap.deliver",
+        "push.residual.kernel", "poll",
+    }
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, ksql_tpu.common.timeline\n"
+         "print(sorted(m for m in sys.modules"
+         " if m.startswith('ksql_tpu') or 'gate' in m))"],
+        capture_output=True, text=True, timeout=120, cwd=_REPO_ROOT,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == str(
+        ["ksql_tpu", "ksql_tpu.common", "ksql_tpu.common.timeline"])
+
+
 def test_interval_rollover_and_cursor_contract():
     tl = TimelineStore("q1", interval_ms=100, ring=16)
     # interval 0: two ticks; interval 1: one error tick; interval 2 opens
@@ -335,8 +358,8 @@ def test_engine_folds_ticks_into_timeline_inline():
     assert "poll" in f["stages"]
     st = tl.stats()
     assert st["folds"] >= 1
-    # fold is cheap: self-measured overhead well under the 2% gate the
-    # bench asserts (generous bound here to stay timing-robust)
+    # fold is cheap: self-measured overhead under the tick time it
+    # folded (a generous bound, to stay timing-robust)
     assert st["foldMs"] < max(st["tickMsFolded"], 1.0)
 
 

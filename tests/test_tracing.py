@@ -7,11 +7,12 @@ forwarding)."""
 
 import json
 import re
+import urllib.request
 
 import pytest
 
 from ksql_tpu.common import config as cfg
-from ksql_tpu.common import faults
+from ksql_tpu.common import faults, tracing
 from ksql_tpu.common.config import KsqlConfig
 from ksql_tpu.engine.engine import KsqlEngine
 from ksql_tpu.runtime.topics import Record
@@ -761,3 +762,242 @@ def test_trace_annotations_leave_the_recorder_unchanged(tmp_path):
     assert {f"ksql.tick#{qid}#{seq}" for seq in (1, 2, 3)} <= names
     assert {"poll", "process", "drain", "device.execute", "step.wait",
             "emit.decode", "emit.dispatch", "commit"} <= names
+
+
+# ------------------------------------------- tracing: push-registry spans
+def test_query_trace_serves_push_pipeline_and_tap_spans():
+    """ISSUE acceptance: /query-trace over the shared pipeline's id shows
+    the push.pipeline.step pump span and push.tap.deliver delivery span,
+    with rows + sampled ring lag counters."""
+    from ksql_tpu.server.rest import KsqlServer, PushQuerySession
+
+    e = KsqlEngine(KsqlConfig({
+        cfg.RUNTIME_BACKEND: "oracle",
+    }))
+    e.execute_sql(
+        "CREATE STREAM S (ID BIGINT, V BIGINT) "
+        "WITH (kafka_topic='s', value_format='JSON');"
+    )
+    e.session_properties["auto.offset.reset"] = "latest"
+    sess = PushQuerySession(e, "SELECT ID FROM S WHERE V > 0 EMIT CHANGES;")
+    assert sess.shared
+    pipe = sess.tap.pipeline
+    t = e.broker.topic("s")
+    for i in range(8):
+        t.produce(Record(key=None, value=json.dumps({"ID": i, "V": i}),
+                         timestamp=i))
+    rows = sess.poll()
+    assert len(rows) == 7  # V > 0
+    s = KsqlServer(engine=e, port=0)
+    s.start()
+    try:
+        # pump ticks on <pipe>, tap-delivery ticks on <pipe>/taps —
+        # separate rings so N delivering taps can't evict the pump's
+        # ticks (and its gated p99 window) under fan-out
+        stages = {}
+        spans = set()
+        for rec_id in (pipe.id, pipe.id + "/taps"):
+            with urllib.request.urlopen(
+                f"{s.url}/query-trace/{rec_id}"
+            ) as r:
+                body = json.loads(r.read())
+            assert body["ticks"], f"{rec_id} recorder must retain ticks"
+            for tk in body["ticks"]:
+                spans.update(sp["name"] for sp in tk["spans"])
+                for name, st in tk["stages"].items():
+                    for k, v in st.items():
+                        stages.setdefault(name, {}).setdefault(k, 0)
+                        if isinstance(v, (int, float)):
+                            stages[name][k] += v
+        assert {"push.pipeline.step", "push.tap.deliver"} <= spans
+        # the pump counted its ring appends, the tap its deliveries and
+        # a per-poll ring-lag sample
+        assert stages["push.pipeline.step"]["rows"] == 8
+        assert stages["push.tap.deliver"]["rows"] == 7
+        assert "ring_lag" in stages["push.tap.deliver"]
+    finally:
+        sess.close()
+        s.stop()
+
+
+def test_listener_mode_emits_land_on_upstream_recorder():
+    """In listener mode the ring appends ride the UPSTREAM query's tick:
+    its flight recorder shows push.pipeline.step rows."""
+    from ksql_tpu.server.rest import PushQuerySession
+
+    e = KsqlEngine(KsqlConfig({cfg.RUNTIME_BACKEND: "oracle"}))
+    e.execute_sql(
+        "CREATE STREAM S (ID BIGINT, V BIGINT) "
+        "WITH (kafka_topic='s', value_format='JSON');"
+    )
+    e.execute_sql(
+        "CREATE STREAM MAT AS SELECT ID, V FROM S EMIT CHANGES;"
+    )
+    qid = list(e.queries)[0]
+    e.session_properties["auto.offset.reset"] = "latest"
+    # a session over the RUNNING query's sink attaches in listener mode
+    sess = PushQuerySession(e, "SELECT ID FROM MAT EMIT CHANGES;")
+    assert sess.shared and sess.tap.pipeline.mode == "listener"
+    t = e.broker.topic("s")
+    for i in range(5):
+        t.produce(Record(key=None, value=json.dumps({"ID": i, "V": i}),
+                         timestamp=i))
+    sess.poll()
+    st = e.trace_recorder(qid).stage_stats()
+    assert st.get("push.pipeline.step", {}).get("rows", 0) >= 5
+    sess.close()
+    e.shutdown()
+
+
+# --------------------------------------------- tracing: cutover phase spans
+def test_query_trace_serves_reshard_cutover_phase_spans(tmp_path):
+    """A live rescale cutover (2 -> 4 shards through the supervised
+    drain/cutover ladder) lands phase spans — drain / checkpoint /
+    rebuild / restore plus the reshard's gather / repartition / insert —
+    on the query's flight recorder (served by /query-trace), and the
+    rescale.done /alerts evidence event carries the per-phase ms."""
+    from ksql_tpu.server.rest import KsqlServer
+
+    from tests.test_device_parity import DDL, gen_rows
+
+    e = KsqlEngine(KsqlConfig({
+        cfg.RUNTIME_BACKEND: "distributed",
+        cfg.BATCH_CAPACITY: 64,
+        cfg.STATE_SLOTS: 1024,
+        cfg.DEVICE_SHARDS: 2,
+        cfg.STATE_CHECKPOINT_DIR: str(tmp_path),
+        cfg.QUERY_RETRY_BACKOFF_INITIAL_MS: 1,
+    }))
+    e.execute_sql(DDL)
+    e.execute_sql(
+        "CREATE TABLE C AS SELECT URL, COUNT(*) AS CNT FROM PAGE_VIEWS "
+        "WINDOW TUMBLING (SIZE 1 HOUR) GROUP BY URL EMIT CHANGES;"
+    )
+    h = list(e.queries.values())[0]
+    assert h.backend == "distributed"
+    t = e.broker.topic("page_views")
+    for row, ts in gen_rows(40, seed=5):
+        t.produce(Record(key=None, value=json.dumps(row), timestamp=ts))
+    e.run_until_quiescent()
+    qid = h.query_id
+    e._rescale_query(h, 4, "grow")
+    assert h.state == "ERROR" and h.pending_rescale is not None
+    for _ in range(50):
+        e.poll_once()
+        if h.state == "RUNNING" and h.pending_rescale is None:
+            break
+    assert h.state == "RUNNING"
+    assert h.executor.device.n_shards == 4
+    s = KsqlServer(engine=e, port=0)
+    s.start()
+    try:
+        with urllib.request.urlopen(f"{s.url}/query-trace/{qid}") as r:
+            body = json.loads(r.read())
+        spans = {
+            sp["name"] for tk in body["ticks"] for sp in tk["spans"]
+        }
+        assert {
+            "cutover.drain", "cutover.checkpoint", "cutover.rebuild",
+            "cutover.restore", "cutover.gather", "cutover.repartition",
+            "cutover.insert",
+        } <= spans, spans
+    finally:
+        s.stop()
+    done = [ev for ev in h.progress.events if ev["kind"] == "rescale.done"]
+    assert done, list(h.progress.events)
+    phases = done[-1]["phasesMs"]
+    assert done[-1]["from"] == 2 and done[-1]["to"] == 4
+    # the whole cutover is phase-attributed: initiation phases (stashed
+    # by _rescale_query) merged with the rebuild tick's spans
+    assert {"cutover.checkpoint", "cutover.rebuild",
+            "cutover.restore", "cutover.gather"} <= set(phases)
+    assert phases["cutover.rebuild"] > 0
+    e.shutdown()
+
+
+# ----------------------------------------------------- deadline auto-sizing
+def test_deadline_hint_fires_when_timeout_below_cold_compile_p99(tmp_path):
+    """ISSUE satellite: a configured tick/rebuild deadline below the
+    observed cold-compile p99 logs a deadline.hint plog entry + /alerts
+    evidence NAMING the observed value on rebuild completion."""
+    # the tick deadline (1s) is far above any real oracle tick here — no
+    # spurious deadline fires — but BELOW the 5s cold-compile p99 seeded
+    # onto the recorder, so the hint must fire for the TICK knob; the
+    # rebuild deadline stays disabled (0) and must stay hint-silent
+    e = KsqlEngine(KsqlConfig({
+        cfg.RUNTIME_BACKEND: "oracle",
+        cfg.STATE_CHECKPOINT_DIR: str(tmp_path),
+        cfg.QUERY_RETRY_BACKOFF_INITIAL_MS: 0,
+        cfg.QUERY_TICK_TIMEOUT_MS: 1000,
+        # hint-only is opt-in since the ISSUE-13 posture flip: autosize
+        # defaults ON and would RAISE the knob instead of hinting
+        cfg.DEADLINE_AUTOSIZE: False,
+    }))
+    e.execute_sql(
+        "CREATE STREAM S (ID BIGINT, V BIGINT) "
+        "WITH (kafka_topic='s', value_format='JSON');"
+    )
+    e.execute_sql(
+        "CREATE TABLE C AS SELECT ID, COUNT(*) AS CNT FROM S "
+        "GROUP BY ID EMIT CHANGES;"
+    )
+    qid = list(e.queries)[0]
+    h = e.queries[qid]
+    t = e.broker.topic("s")
+    t.produce(Record(key=None, value='{"ID":1,"V":1}', timestamp=1))
+    e.run_until_quiescent()
+    # seed an observed cold compile (the oracle never compiles): 5s p99
+    rec = e.trace_recorder(qid)
+    with tracing.tick(rec):
+        tracing.stage("device.compile", 5.0, jit_miss=1)
+    with faults.inject("stage.process", count=1):
+        t.produce(Record(key=None, value='{"ID":2,"V":2}', timestamp=2))
+        e.poll_once()
+    assert h.state == "ERROR"
+    h.retry_at_ms = 0
+    for _ in range(10):
+        e.poll_once()
+        if h.state == "RUNNING":
+            break
+    assert h.state == "RUNNING"
+    hints = [p for p in e.processing_log
+             if str(p[0]).startswith("deadline.hint")]
+    assert hints, "hint plog entry must land on rebuild completion"
+    assert cfg.QUERY_TICK_TIMEOUT_MS in hints[-1][1]
+    assert "5000ms" in hints[-1][1]  # names the observed value
+    evs = [ev for ev in h.progress.events if ev["kind"] == "deadline.hint"]
+    assert evs and evs[-1]["knob"] == cfg.QUERY_TICK_TIMEOUT_MS
+    assert evs[-1]["configuredMs"] == 1000
+    assert evs[-1]["observedColdCompileP99Ms"] == 5000.0
+    # the DISABLED rebuild deadline must never produce a hint
+    assert all(
+        ev["knob"] != cfg.QUERY_REBUILD_TIMEOUT_MS for ev in evs
+    )
+    e.shutdown()
+
+
+def test_no_deadline_hint_when_deadlines_disabled(tmp_path):
+    e = KsqlEngine(KsqlConfig({
+        cfg.RUNTIME_BACKEND: "oracle",
+        cfg.QUERY_RETRY_BACKOFF_INITIAL_MS: 0,
+    }))
+    e.execute_sql(
+        "CREATE STREAM S (ID BIGINT, V BIGINT) "
+        "WITH (kafka_topic='s', value_format='JSON');"
+    )
+    e.execute_sql("CREATE STREAM P AS SELECT ID FROM S EMIT CHANGES;")
+    qid = list(e.queries)[0]
+    h = e.queries[qid]
+    rec = e.trace_recorder(qid)
+    with tracing.tick(rec):
+        tracing.stage("device.compile", 0.500, jit_miss=1)
+    t = e.broker.topic("s")
+    with faults.inject("stage.process", count=1):
+        t.produce(Record(key=None, value='{"ID":1,"V":1}', timestamp=1))
+        e.poll_once()
+    h.retry_at_ms = 0
+    e.poll_once()
+    assert h.state == "RUNNING"
+    assert not [p for p in e.processing_log
+                if str(p[0]).startswith("deadline.hint")]
+    e.shutdown()
