@@ -56,12 +56,12 @@ is never timed and reports no time):
                     the copy into the padded step buffers
 ``device.compile``  span: a device step that jit-traced/compiled (miss)
 ``device.execute``  span: a device step served from the jit cache (hit)
-``step.dispatch``     span: h2d of the batch + enqueue of the step
-                      (``h2d_bytes``)
+``step.dispatch``     span: h2d of the batch + enqueue of the step and of
+                      its emits' host copies (``h2d_bytes``)
 ``step.wait``         span: the host blocked on the step's outputs (a
                       join-table step reads its four load scalars there)
-``emit.decode``       span: load check, d2h of the emit columns, row
-                      building (``d2h_bytes``)
+``emit.decode``       span: the step's emits read back in one transfer,
+                      load check, row building (``d2h_bytes``)
 ``table.grow``        span: a join table doubled (host rebuild; the steps
                       recompile at their next call)
 ``device.step``     counters the step program reports about its own work

@@ -4370,6 +4370,11 @@ class CompiledDeviceQuery:
             if self.sliced:
                 self.ensure_ring_for(arrays["ts"], arrays["row_valid"])
             new_state, emits = self._step(self.state, arrays)
+            if not self.session and not self.suppress:
+                # the read-back of _finish_step, queued behind the step: the
+                # copies run as soon as it ends, not when the host asks
+                for leaf in emits.values():
+                    leaf.copy_to_host_async()
         while self.session:
             with tracing.span("step.wait"):
                 overflowed = int(emits["sess_ovf"]) > 0
@@ -4386,7 +4391,13 @@ class CompiledDeviceQuery:
             # windows the step closed this batch — emitted BEFORE the
             # retention pass / store growth below, which remap or reset
             # slots (dirty already cleared in-trace; values stay resident)
-            idx = np.nonzero(np.asarray(emits["suppress_emit"]))[0]
+            # one read: the store-shaped mask and the load scalars (the
+            # rows come from the store, not from the emit columns)
+            host = jax.device_get({
+                k: v for k, v in emits.items()
+                if k == "suppress_emit" or not v.ndim
+            })
+            idx = np.nonzero(host["suppress_emit"])[0]
             result = self._emit_slots(idx)
         if self.agg is not None:
             self._batches += 1
@@ -4396,47 +4407,52 @@ class CompiledDeviceQuery:
             ):
                 self.state = self._evict(self.state)
         if result is not None:
-            self._react_to_load(emits)
+            self._react_to_load(host)
             return result
         react = self.agg is not None
         if self.pipeline and not self.suppress and not self.session:
             emits, self._pending_emits = self._pending_emits, emits
             if emits is None:
                 return []
-            # sample the load check: int() costs a device readback each,
-            # and in pipelined mode the 0.75-occupancy growth threshold
-            # leaves several batches of headroom
+            # sample the load check: in pipelined mode the 0.75-occupancy
+            # growth threshold leaves several batches of headroom
             react = react and self._batches % 4 == 0
         return self._finish_step(emits, react)
 
     def _finish_step(
         self, emits: Dict[str, jnp.ndarray], react: bool
     ) -> List[SinkEmit]:
-        """The host's half of a step: wait for its outputs, then read the
-        load scalars (``react``) and decode the emitted rows.  The wait is
-        explicit so that its span holds the host blocked on the device and
-        nothing else; the reads that follow would block there anyway."""
+        """The host's half of a step: wait for its outputs, read them back
+        in one transfer, then check the load (``react``) and decode the
+        emitted rows from the host copy — a blocking read a leaf cost
+        0.4-1.4 ms each, ~11 ms a step (``PERF.md`` §6, PR 31).  The wait
+        is explicit so that its span holds the host blocked on the device
+        and nothing else."""
         if not self.session:  # the session step's overflow read has waited
             with tracing.span("step.wait"):
                 jax.block_until_ready(emits)
         with tracing.span("emit.decode"):
+            host = jax.device_get(emits)
             if react:
-                self._react_to_load(emits)
-            self._note_join_stats(emits)
-            self._deliver_members(emits)
-            return self._decode_emits(emits)
+                self._react_to_load(host)
+            self._note_join_stats(host)
+            self._deliver_members(host)
+            if self.collect_raw_emits:
+                # the raw block gathers on the device: decode from the
+                # step's own arrays, whose host copies the read has cached
+                host = {k: emits[k] for k in host}
+            return self._decode_emits(host)
 
     _JOIN_STATS = ("find_rounds", "join_rows", "join_matched")
 
-    def _note_join_stats(self, emits: Dict[str, jnp.ndarray]) -> None:
+    def _note_join_stats(self, emits: Dict[str, np.ndarray]) -> None:
         """Book the stream-table join's own counts of a step (the lookup
-        loop's rounds, rows probed, rows matched) on ``device.step``: one
-        read of three scalars after the wait, every step."""
+        loop's rounds, rows probed, rows matched) on ``device.step``, every
+        step, from the step's read-back."""
         if "find_rounds" in emits and tracing.active() is not None:
-            counts = jax.device_get([emits[k] for k in self._JOIN_STATS])
             tracing.counter(
                 "device.step", sampled=1,
-                **{k: int(v) for k, v in zip(self._JOIN_STATS, counts)},
+                **{k: int(emits[k]) for k in self._JOIN_STATS},
             )
 
     def _deliver_members(self, emits: Dict[str, jnp.ndarray]) -> None:
