@@ -473,6 +473,14 @@ def _drive_served(phase, srv, engine, corpus, sizes, platform, backend,
     stages = rec.stage_stats() if rec is not None else {}
     compile_s = round(stages.get("device.compile", {}).get("total_ms", 0.0) / 1e3, 2)
     check("device.compile" in stages, "no device.compile span recorded")
+    if shards:
+        # every event crossed the all-to-all once, in whole buckets
+        exch, buckets = stages.get("exchange", {}), shards * shards * ex.device.bucket_capacity
+        check(exch.get("rows") == len(corpus.payloads)
+              and exch["rows"] / shards <= exch["rows_fullest_shard"] <= exch["rows"]
+              and exch["lanes"] == exch["steps"] * buckets
+              and exch["wire_bytes"] > exch["bytes"] > 0,
+              "exchange counters", exch)
     import jax
 
     mem = jax.devices()[0].memory_stats() or {}

@@ -71,7 +71,14 @@ is never timed and reports no time):
                     ``join_rows`` probed, ``join_matched``)
 ``table.upsert``    counters of the join-table steps (``rows``, ``steps``,
                     ``probe_rounds``, ``probe_lane_rounds``, ``grows``)
-``exchange``        counters: distributed all-to-all (rows / bytes)
+``exchange``        counters of the mesh's all-to-all, booked a step from
+                    the per-shard row counts the host reads anyway and from
+                    static shapes (``steps``; ``rows`` received, all shards;
+                    ``rows_fullest_shard``, the most one shard received;
+                    ``bytes``, an estimate: rows x the payload's row width;
+                    ``lanes`` = n_shards^2 x ``bucket_capacity``, what the
+                    collective ships whatever the rows, and ``wire_bytes``,
+                    those lanes at the payload's row width)
 ``emit.dispatch``   span: block encode, then the emit callbacks and the sink
                     produce, for the block at once or emit by emit (``rows``
                     dispatched, ``block_rows`` of them as a block)

@@ -109,11 +109,11 @@ class DistributedDeviceQuery:
         self.shard_watermark_ms = np.full(nd, -1, np.int64)
         self.last_pull_slots_decoded = 0
         self.shards_touched_last_pull: List[int] = []
-        # per-row wire estimate for the all-to-all payload (8B data + 1B
-        # mask per layout column, plus ts/khash/active lanes) — feeds the
-        # flight recorder's exchange-bytes counter; the exchange itself is
-        # fused inside the jitted step, so bytes are derived, not measured
-        self._exch_row_bytes = 9 * len(compiled.layout.specs) + 24
+        # bytes one row of each step's all-to-all payload takes, by step
+        # ("" the keyed step, "l" / "r" a stream-stream join's sides):
+        # noted from the payload's own arrays when the step is traced
+        # (_exchange), so a step that has not run yet has none
+        self._exch_row_bytes: Dict[str, int] = {}
         self._qid = str(getattr(compiled.plan, "query_id", "") or "")
         # suspect-shard marker: set while a shard lane's host-side dispatch
         # section runs, cleared when the per-shard section completes.  A
@@ -151,6 +151,20 @@ class DistributedDeviceQuery:
         ]
         fns.extend((self.__dict__.get("_ss_steps") or {}).values())
         return self.c.jit_cache_entries() + tracing.jit_cache_size(fns)
+
+    def _exchange(self, payload, dest, step: str = ""):
+        """One step's all-to-all (called while the step is traced): the
+        received payload, the rows a full bucket turned away, the rows
+        this shard received.  Notes the payload's row width for
+        ``_account``: shapes and dtypes are static, nothing is read."""
+        self._exch_row_bytes[step] = sum(
+            v.dtype.itemsize * int(np.prod(v.shape[1:]))
+            for v in payload.values()
+        )
+        recv, ovf = all_to_all_exchange(
+            payload, dest, self.n_shards, self.bucket_capacity
+        )
+        return recv, ovf, jnp.sum(recv["active"].astype(jnp.int64))
 
     def __getattr__(self, name: str):
         # executor-facing delegation: anything not distributed-specific
@@ -192,10 +206,7 @@ class DistributedDeviceQuery:
                 # owning their key, the interval-merge runs shard-local
                 payload = self.c.pre_session_exchange(state["max_ts"], arrays)
                 dest = shard_of(payload["khash"], nd)
-                recv, ovf = all_to_all_exchange(
-                    payload, dest, nd, self.bucket_capacity
-                )
-                exch = jnp.sum(recv["active"].astype(jnp.int64))
+                recv, ovf, exch = self._exchange(payload, dest)
                 state, emits = self.c.post_session_exchange(state, recv)
                 state["overflow"] = state["overflow"] + ovf
                 emits["overflow"] = state["overflow"]
@@ -208,10 +219,7 @@ class DistributedDeviceQuery:
                     ),
                 )
                 dest = shard_of(payload["khash"], nd)
-                recv, ovf = all_to_all_exchange(
-                    payload, dest, nd, self.bucket_capacity
-                )
-                exch = jnp.sum(recv["active"].astype(jnp.int64))
+                recv, ovf, exch = self._exchange(payload, dest)
                 state, emits = self.c.post_exchange(state, recv)
                 # fold exchange overflow in before emits surface it, so the
                 # batch that dropped rows is the batch that reports them
@@ -267,10 +275,7 @@ class DistributedDeviceQuery:
                         jnp.where(arrays["row_valid"], arrays["ts"], neg)
                     )
                     gmax = jax.lax.pmax(batch_max, SHARD_AXIS)
-                    recv, ovf = all_to_all_exchange(
-                        payload, dest, nd, self.bucket_capacity
-                    )
-                    exch = jnp.sum(recv["active"].astype(jnp.int64))
+                    recv, ovf, exch = self._exchange(payload, dest, side)
                     recv["row_valid"] = recv.pop("active")
                     state, emits = trace(state, recv)
                     state["max_ts"] = jnp.maximum(state["max_ts"], gmax)
@@ -464,8 +469,9 @@ class DistributedDeviceQuery:
         )
         return out
 
-    def _account(self, emits: Dict[str, jnp.ndarray]) -> None:
-        """Fold one sharded step's emits into the per-shard stat gauges."""
+    def _account(self, emits: Dict[str, jnp.ndarray], step: str = "") -> None:
+        """Fold one sharded step's emits (``step`` as ``_exchange`` was
+        told) into the per-shard stat gauges."""
         if faults.armed():
             # whole-collective seam: the all-to-all is fused inside the
             # jitted step, so its host boundary is this accounting pass —
@@ -481,13 +487,23 @@ class DistributedDeviceQuery:
                 np.asarray(emits["exch_rows"]).reshape(nd).astype(np.int64)
             )
             self.shard_exchange_rows += per_shard
-            total = int(per_shard.sum())
-            if total:
-                # fused into the sharded step, so no separate timing — the
-                # volume counters are what EXPLAIN ANALYZE / Prometheus need
+            row_bytes = self._exch_row_bytes.get(step)
+            if row_bytes is not None:
+                # fused into the sharded step, so no separate timing: the
+                # volume counters are what EXPLAIN ANALYZE, Prometheus and
+                # the benchmark's exchange metrics read.  ``bytes`` is an
+                # estimate of what had to cross (rows received x the
+                # payload's row width); ``wire_bytes`` is what the
+                # collective ships whatever the rows: every (source,
+                # destination) bucket at its full ``bucket_capacity``
+                total = int(per_shard.sum())
+                lanes = nd * nd * self.bucket_capacity
                 tracing.counter(
-                    "exchange", rows=total,
-                    bytes=total * self._exch_row_bytes,
+                    "exchange", steps=1, rows=total,
+                    rows_fullest_shard=int(per_shard.max()),
+                    bytes=total * row_bytes,
+                    lanes=lanes, wire_bytes=lanes * row_bytes,
+                    bucket_capacity=self.bucket_capacity,
                 )
         if "occupancy" in emits:
             self.shard_store_occupancy = (
@@ -520,7 +536,7 @@ class DistributedDeviceQuery:
         layout = self.c.layout if side == "l" else self.c.right_layout
         arrays = self.encode(batch, layout=layout)
         self.state, emits = self._ss_steps[side](self.state, arrays)
-        self._account(emits)
+        self._account(emits, side)
         lost = int(np.asarray(emits["ss_lost"]).sum())
         movf = int(np.asarray(emits["ss_matchovf"]).sum())
         xovf = int(np.asarray(emits["ss_exch_ovf"]).sum())

@@ -1016,11 +1016,13 @@ class DistributedDeviceExecutor(DeviceExecutor):
             "rows-in": d.shard_rows_in.tolist(),
             "rows-out": d.shard_rows_out.tolist(),
             "exchange-rows": d.shard_exchange_rows.tolist(),
-            # exchanged volume at the mesh's estimated row width — the
-            # telemetry timeline's per-shard bytes series and the
-            # ksql_shard_exchange_bytes Prometheus gauge
+            # exchanged volume at the payload's row width (noted when the
+            # step was traced: 0 until it has run; a stream-stream join's
+            # two sides give the wider) — the telemetry timeline's
+            # per-shard bytes series and the ksql_shard_exchange_bytes
+            # Prometheus gauge
             "exchange-bytes": [
-                int(r * d._exch_row_bytes)
+                r * max(d._exch_row_bytes.values(), default=0)
                 for r in d.shard_exchange_rows.tolist()
             ],
             "store-occupancy": d.shard_store_occupancy.tolist(),

@@ -98,7 +98,8 @@ class Run:
     def __init__(self, config_name: str, feed):
         self.config = _json(next(c["file"] for c in BENCH["configs"]
                                  if c["name"] == config_name))
-        engine = KsqlEngine(KsqlConfig(dict(self.config["rehearse"]["engine_props"])))
+        engine = KsqlEngine(KsqlConfig({**self.config["engine_props"],
+                                        **self.config["rehearse"]["engine_props"]}))
         server = KsqlServer(engine=engine, port=0)  # never started
         for statement in self.config["statements"]:
             engine.execute_sql(statement)
@@ -146,6 +147,13 @@ def clicks_users_join():
     return Run("clicks_users_join", _clicks_users_join_feed)
 
 
+@pytest.fixture(scope="module")
+def pageviews_count_mesh4():
+    """The same statements and feed on four of ``conftest.py``'s virtual
+    devices (``ksql.runtime.backend=distributed``)."""
+    return Run("pageviews_count_mesh4", _pageviews_count_feed)
+
+
 # ------------------------------------------------------------------ the cases
 @pytest.mark.parametrize("metric_name", sorted(LAYER_METRICS))
 def test_layer_metric_reads_what_the_program_books(metric_name, request):
@@ -154,7 +162,8 @@ def test_layer_metric_reads_what_the_program_books(metric_name, request):
         # the fixture above of that name: a PR that adds a configuration
         # to BENCHMARK.json adds its run here
         run = request.getfixturevalue(config)
-        assert run.state == "RUNNING" and run.backend == "device"
+        assert run.state == "RUNNING"
+        assert run.backend == run.config["engine_props"].get("ksql.runtime.backend", "device")
         absent = [n for n in _span_names(metric, "num", "den") if n not in run.spans]
         assert not absent, (
             f"{metric_name} ({config}) reads {absent}; the program booked "
@@ -163,13 +172,14 @@ def test_layer_metric_reads_what_the_program_books(metric_name, request):
         assert not empty, f"{metric_name} ({config}) divides by {empty}: read 0"
 
 
-def test_the_harness_finds_what_it_reaches_for(pageviews_count, clicks_users_join):
+def test_the_harness_finds_what_it_reaches_for(pageviews_count, clicks_users_join,
+                                               pageviews_count_mesh4):
     """What ``benchmark/run.py`` takes hold of besides the stages:
     ``FlightRecorder.observer`` and each trace's ``_t0`` and spans
     (``TickLog``), ``KsqlServer.engine_lock`` (the fill, ``_quiescent``) and
     the executor's ``_native_fields``, held to the configuration's
     ``native_ingest``."""
-    for run in (pageviews_count, clicks_users_join):
+    for run in (pageviews_count, clicks_users_join, pageviews_count_mesh4):
         kept = run.stage_stats["tick"]["n"]
         assert kept >= N_POLLS and len(run.traces) == kept
         for trace in run.traces:

@@ -239,10 +239,14 @@ def test_fused_tap_kernel_sixteen_lanes(one_chip):
         e.shutdown()
 
 
-def test_four_shard_step_exchanges_and_shards_its_state(topo):
+@pytest.mark.parametrize("store_capacity", [1 << 21, 1 << 20],
+                         ids=["smoke_slots", "pv_count_mesh4_slots"])
+def test_four_shard_step_exchanges_and_shards_its_state(topo, store_capacity):
     """The shard_map step of config #1 on a Mesh of the four described
-    chips.  DistributedDeviceQuery places its state as it is built, which
-    a described device cannot take, so the state here is shapes."""
+    chips, at the smoke's slots a shard and at the cell ``pv_count.mesh4``'s
+    (``benchmark/configs/pageviews_count_mesh4.json``).
+    DistributedDeviceQuery places its state as it is built, which a
+    described device cannot take, so the state here is shapes."""
     from ksql_tpu.parallel.distributed import DistributedDeviceQuery
     from ksql_tpu.parallel.mesh import SHARD_AXIS
 
@@ -263,7 +267,7 @@ def test_four_shard_step_exchanges_and_shards_its_state(topo):
             return stacked(jax.eval_shape(self.c.init_state))
 
     # the mesh splits the smoke's 32,768-row host batch into four lanes
-    dev = _lowered(TUMBLING, capacity=32_768 // n_shards, store_capacity=1 << 21)
+    dev = _lowered(TUMBLING, capacity=32_768 // n_shards, store_capacity=store_capacity)
     dist = ShapesForState(dev, mesh)
     arrays = stacked(dev.layout.array_structs())
     compiled = _compile(dist._step, dist.state, arrays)
