@@ -45,9 +45,11 @@ is never timed and reports no time):
 ``tick``            the whole poll tick of one query (total, booked when
                     the tick ends; ``self_ms`` = time under no span)
 ``poll``            span: Consumer.poll for the tick (``rows``)
-``process``         span: the per-record loop handing records to the
-                    executor (a full micro-batch runs the stages below
-                    inside it)
+``process``         span: the hand-over of a poll's records to the executor
+                    (a block for the records it only buffers, the
+                    per-record loop for the rest; ``rows`` handed,
+                    ``block_rows`` of them in a block; a full micro-batch
+                    runs the stages below inside it)
 ``deserialize``     decode_source_record (total, all backends); a span per
                     chunk in the native C++ tier
 ``stage:<ctx>``     total: one oracle ExecutionStep node (Filter/Join/...)

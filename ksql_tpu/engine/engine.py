@@ -3201,14 +3201,14 @@ class KsqlEngine:
         epoch_capable = (
             per_record and stateful and hasattr(executor, "state_epoch")
         )
-        # consumed entries: (topic, partition, offset, handed_idx) —
-        # handed_idx is None for records SKIPPED without entering the
-        # executor (replay-without-record), which are durable immediately;
+        # consumed[i] is the handed index of records[i], the i-th record of
+        # the tick's poll — None for a record SKIPPED without entering the
+        # executor (replay-without-record), which is durable immediately;
         # a handed record is durable once the executor has flushed it
         # (its handed_idx < handed - pending()).  The commit cursor only
         # advances over a contiguous durable prefix, so a skip sitting
         # between still-buffered records can never commit them early.
-        consumed: List[Tuple[str, int, int, Optional[int]]] = []
+        consumed: List[Optional[int]] = []
         committed_idx = 0
         handed = 0
         # per-record state epochs degrade gracefully on big state: once one
@@ -3231,10 +3231,11 @@ class KsqlEngine:
             nonlocal committed_idx
             durable_handed = handed - pending()
             while committed_idx < len(consumed):
-                tn_, p_, off_, hidx = consumed[committed_idx]
+                hidx = consumed[committed_idx]
                 if hidx is not None and hidx >= durable_handed:
                     break
-                commit[(tn_, p_)] = off_ + 1
+                tn_, r_ = records[committed_idx]
+                commit[(tn_, r_.partition)] = r_.offset + 1
                 committed_idx += 1
 
         def take_epoch_budgeted() -> None:
@@ -3307,117 +3308,179 @@ class KsqlEngine:
                 # rollback needs)
                 take_epoch_budgeted()
             tick0 = _time.monotonic()
+            # the hand-over, at two granularities: a run of one topic's
+            # records that the executor will only buffer goes over as a
+            # block (``buffer_block`` says how many of them it took), the
+            # rest record by record.  A taken record needs none of the
+            # per-record bookkeeping: it cannot fail, and the commit cursor
+            # cannot pass it before a later step flushes it.  Poison replay,
+            # bisection and per-record epochs want every record looked at.
+            block_fn = None
+            if not (handle.poison_skip or epoch_capable
+                    or handle.poison_bisect is not None):
+                block_fn = getattr(executor, "buffer_block", None)
+            block_rows = 0
+            pos = run_end = 0
+            offer = False
             with tracing.span("process"):
-                for topic, rec_ in records:
-                    rkey = (topic, rec_.partition, rec_.offset)
-                    if rkey in handle.poison_skip:
-                        # replay-without-record: this record poisoned a
-                        # previous attempt on a micro-batched backend; the
-                        # replay drops it so state never re-absorbs it
-                        if alive():
-                            handle.poison_skip.discard(rkey)
-                        self._on_error(
-                            f"poison:{handle.query_id}:{topic}",
-                            KsqlException(
-                                "replay-without-record: skipping poison "
-                                f"record {topic}-{rec_.partition}"
-                                f"@{rec_.offset}"
-                            ),
-                        )
-                        if tick is not None:
-                            tick.stage("poison.skip", 0.0)
-                        consumed.append((*rkey, None))
+                try:
+                    while pos < len(records):
+                        topic, rec_ = records[pos]
+                        if block_fn is not None and pos >= run_end:
+                            # a new run of one topic's records
+                            run_end = pos + 1
+                            while (run_end < len(records)
+                                   and records[run_end][0] == topic):
+                                run_end += 1
+                            offer = True
+                        if offer:
+                            try:
+                                took = block_fn(
+                                    topic,
+                                    [r for _, r in records[pos:run_end]],
+                                )
+                            except Exception as e:  # noqa: BLE001 — no one
+                                # record is attributable: as a failed drain
+                                if self._is_poison(e) and alive():
+                                    self._note_poison_bisect(
+                                        handle, replay_window()
+                                    )
+                                rewind_to_commit()
+                                if alive():
+                                    self._query_failed(handle, e)
+                                return n
+                            if not took:
+                                # the executor is unsure: the loop as it
+                                # is for the rest of the run
+                                offer = False
+                            else:
+                                consumed.extend(range(handed, handed + took))
+                                handed += took
+                                n += took
+                                block_rows += took
+                                pos += took
+                                # once a block: a record dropped at decode
+                                # leaves nothing pending behind it
+                                note_durable()
+                                if pos >= run_end:
+                                    continue
+                                # the record the block stopped at goes
+                                # through the loop; the next offer follows
+                                topic, rec_ = records[pos]
+                        pos += 1
+                        rkey = (topic, rec_.partition, rec_.offset)
+                        if rkey in handle.poison_skip:
+                            # replay-without-record: this record poisoned a
+                            # previous attempt on a micro-batched backend; the
+                            # replay drops it so state never re-absorbs it
+                            if alive():
+                                handle.poison_skip.discard(rkey)
+                            self._on_error(
+                                f"poison:{handle.query_id}:{topic}",
+                                KsqlException(
+                                    "replay-without-record: skipping poison "
+                                    f"record {topic}-{rec_.partition}"
+                                    f"@{rec_.offset}"
+                                ),
+                            )
+                            if tick is not None:
+                                tick.stage("poison.skip", 0.0)
+                            consumed.append(None)
+                            n += 1
+                            note_durable()
+                            continue
+                        # computed regardless of the commit knob: poison
+                        # attribution (below) must not blame the flush-trigger
+                        # record for a batched flush error when earlier records
+                        # are still buffered
+                        pending_before = pending()
+                        try:
+                            executor.process(topic, rec_)
+                        except Exception as e:  # noqa: BLE001
+                            if self._is_poison(e):
+                                is_oracle = handle.backend == "oracle"
+                                record_sync = is_oracle or bool(
+                                    getattr(executor, "record_synchronous",
+                                            False)
+                                )
+                                # atomic rollback needs an epoch matching the
+                                # EXACT pre-record state (taken after the last
+                                # handed record); a stale epoch must not
+                                # un-absorb earlier records' state
+                                rolled = (
+                                    stateful and epoch_capable
+                                    and handed == last_epoch_handed
+                                    and self._rollback_epoch(
+                                        handle, executor, alive
+                                    )
+                                )
+                                if record_sync and (not stateful or rolled):
+                                    # atomic in-place skip: stores rolled back
+                                    # to the pre-record epoch (stateless paths
+                                    # have nothing to diverge)
+                                    self._on_error(
+                                        f"poison:{handle.query_id}:{topic}", e
+                                    )
+                                    self.metrics.for_query(
+                                        handle.query_id
+                                    ).errors.mark(1)
+                                    if tick is not None:
+                                        tick.stage("poison.skip", 0.0)
+                                    handed += 1
+                                    consumed.append(handed - 1)
+                                    n += 1  # offset advanced: skipping IS
+                                    note_durable()  # progress
+                                    continue
+                                if is_oracle and not epoch_capable:
+                                    # legacy PR-1 posture (commit-per-record
+                                    # off): skip in place, absorbed state
+                                    # stands — the documented one-record
+                                    # divergence, preferred over crash-looping
+                                    self._on_error(
+                                        f"poison:{handle.query_id}:{topic}", e
+                                    )
+                                    self.metrics.for_query(
+                                        handle.query_id
+                                    ).errors.mark(1)
+                                    if tick is not None:
+                                        tick.stage("poison.skip", 0.0)
+                                    handed += 1
+                                    consumed.append(handed - 1)
+                                    n += 1
+                                    continue
+                                if (record_sync or pending_before == 0) \
+                                        and alive():
+                                    # attributable to exactly this record, but
+                                    # its state absorption cannot roll back:
+                                    # restart and replay WITHOUT the record
+                                    handle.poison_skip.add(rkey)
+                                    self._on_error(
+                                        f"poison:{handle.query_id}:{topic}",
+                                        KsqlException(
+                                            "poison record will be dropped on "
+                                            f"replay: {type(e).__name__}: {e}"
+                                        ),
+                                    )
+                                elif alive():
+                                    # NON-attributable: earlier records are
+                                    # still buffered in the batched flush, any
+                                    # of them may be the poison — halve the
+                                    # replay window for the next attempt
+                                    self._note_poison_bisect(
+                                        handle, replay_window()
+                                    )
+                            rewind_to_commit()
+                            if alive():
+                                self._query_failed(handle, e)
+                            return n
+                        handed += 1
+                        consumed.append(handed - 1)
                         n += 1
                         note_durable()
-                        continue
-                    # computed regardless of the commit knob: poison
-                    # attribution (below) must not blame the flush-trigger
-                    # record for a batched flush error when earlier records
-                    # are still buffered
-                    pending_before = pending()
-                    try:
-                        executor.process(topic, rec_)
-                    except Exception as e:  # noqa: BLE001
-                        if self._is_poison(e):
-                            is_oracle = handle.backend == "oracle"
-                            record_sync = is_oracle or bool(
-                                getattr(executor, "record_synchronous",
-                                        False)
-                            )
-                            # atomic rollback needs an epoch matching the
-                            # EXACT pre-record state (taken after the last
-                            # handed record); a stale epoch must not
-                            # un-absorb earlier records' state
-                            rolled = (
-                                stateful and epoch_capable
-                                and handed == last_epoch_handed
-                                and self._rollback_epoch(
-                                    handle, executor, alive
-                                )
-                            )
-                            if record_sync and (not stateful or rolled):
-                                # atomic in-place skip: stores rolled back
-                                # to the pre-record epoch (stateless paths
-                                # have nothing to diverge)
-                                self._on_error(
-                                    f"poison:{handle.query_id}:{topic}", e
-                                )
-                                self.metrics.for_query(
-                                    handle.query_id
-                                ).errors.mark(1)
-                                if tick is not None:
-                                    tick.stage("poison.skip", 0.0)
-                                handed += 1
-                                consumed.append((*rkey, handed - 1))
-                                n += 1  # offset advanced: skipping IS
-                                note_durable()  # progress
-                                continue
-                            if is_oracle and not epoch_capable:
-                                # legacy PR-1 posture (commit-per-record
-                                # off): skip in place, absorbed state
-                                # stands — the documented one-record
-                                # divergence, preferred over crash-looping
-                                self._on_error(
-                                    f"poison:{handle.query_id}:{topic}", e
-                                )
-                                self.metrics.for_query(
-                                    handle.query_id
-                                ).errors.mark(1)
-                                if tick is not None:
-                                    tick.stage("poison.skip", 0.0)
-                                handed += 1
-                                consumed.append((*rkey, handed - 1))
-                                n += 1
-                                continue
-                            if (record_sync or pending_before == 0) \
-                                    and alive():
-                                # attributable to exactly this record, but
-                                # its state absorption cannot roll back:
-                                # restart and replay WITHOUT the record
-                                handle.poison_skip.add(rkey)
-                                self._on_error(
-                                    f"poison:{handle.query_id}:{topic}",
-                                    KsqlException(
-                                        "poison record will be dropped on "
-                                        f"replay: {type(e).__name__}: {e}"
-                                    ),
-                                )
-                            elif alive():
-                                # NON-attributable: earlier records are
-                                # still buffered in the batched flush, any
-                                # of them may be the poison — halve the
-                                # replay window for the next attempt
-                                self._note_poison_bisect(
-                                    handle, replay_window()
-                                )
-                        rewind_to_commit()
-                        if alive():
-                            self._query_failed(handle, e)
-                        return n
-                    handed += 1
-                    consumed.append((*rkey, handed - 1))
-                    n += 1
-                    note_durable()
+                finally:
+                    tracing.counter(
+                        "process", rows=handed, block_rows=block_rows
+                    )
             try:
                 drain = getattr(executor, "drain", None)
                 if drain is not None:
@@ -3430,9 +3493,10 @@ class KsqlEngine:
                     # a deterministic USER error inside the batched device
                     # flush: no single record is attributable — unless
                     # bisection already narrowed the window to one
-                    nondurable = consumed[committed_idx:]
-                    if len(nondurable) == 1 and replay_window() == 1:
-                        rk = nondurable[0][:3]
+                    if (len(consumed) - committed_idx == 1
+                            and replay_window() == 1):
+                        tn_, r_ = records[committed_idx]
+                        rk = (tn_, r_.partition, r_.offset)
                         handle.poison_skip.add(rk)
                         handle.poison_bisect = None
                         self._on_error(

@@ -34,6 +34,7 @@ from ksql_tpu.runtime.oracle import (
     SinkEmit,
     SinkWriter,
     StreamRow,
+    _wrapped,
     decode_source_record,
 )
 from ksql_tpu.runtime.topics import Broker, Record
@@ -319,17 +320,10 @@ class DeviceExecutor:
                 ev is not None
                 and isinstance(ev, StreamRow)
                 and ev.row is None
-                and self.device.agg is None
-                and self.device.join is None
-                and self.device.ss_join is None
-                and not any(
-                    isinstance(op, st.StreamFilter) for op in self.device.pre_ops
-                )
+                and self._passes_null_rows()
             ):
-                # null-value stream records pass filter-less projections
-                # through unchanged (oracle SelectNode); a repartition
-                # recomputes the key from the key columns alone
-                # (SelectKeyNode null-row semantics); filters drop them
+                # a repartition recomputes the key from the key columns
+                # alone (SelectKeyNode null-row semantics)
                 out.extend(self._run_batch() if self._rows else [])
                 key = ev.key
                 for op in self.device.pre_ops:
@@ -388,6 +382,79 @@ class DeviceExecutor:
                 if len(self._rrows) >= self.device.capacity:
                     out.extend(self._run_right_batch())
         return out
+
+    def _passes_null_rows(self) -> bool:
+        """Null-value stream records pass filter-less projections through
+        unchanged (oracle SelectNode), dispatched at once; filters,
+        aggregations and joins drop them."""
+        dev = self.device
+        return (
+            dev.agg is None and dev.join is None and dev.ss_join is None
+            and not any(isinstance(op, st.StreamFilter) for op in dev.pre_ops)
+        )
+
+    def buffer_block(self, topic: str, records: List[Record]) -> int:
+        """``process(topic, r)`` for the leading records of one topic's
+        polled run that it would only buffer: each would have returned
+        ``[]``, run no device step, dispatched no emit, flushed no other
+        buffer and raised nothing.  Returns how many were taken (0 when
+        unsure); the caller goes on from there through ``process``, which
+        keeps the record that fills a micro-batch, so the flush and its
+        commit point fall where they always did.  The plan-shape reads
+        happen once a block, not once a record."""
+        dev = self.device
+        cap = dev.capacity
+        if (
+            cap <= 1  # record-synchronous: every record is a device step
+            or faults.armed()  # device.dispatch fires per record
+            or _wrapped(self, "process", DeviceExecutor)
+            or topic != self.source_step.topic
+            or topic in self._join_topics
+            or dev.fk_join is not None or dev.tt_join is not None
+            or dev.table_mode or dev.table_agg
+            or self._rrows or any(b["rows"] for b in self._tbuf)
+        ):
+            return 0
+        if self._native_fields is not None:
+            # native tier: the payloads wait in _raw for the C++ decoder
+            if self._rows:
+                return 0
+            run = records[: max(cap - 1 - len(self._raw), 0)]
+            for i, r in enumerate(run):
+                if not isinstance(r.value, (str, bytes)):
+                    run = run[:i]  # a tombstone or dict flushes _raw first
+                    break
+            self._raw.extend(run)
+            return len(run)
+        step = self.source_step
+        if (
+            self._raw or dev.flatmap is not None
+            or not isinstance(step, st.StreamSource)
+            or self._passes_null_rows()
+        ):
+            # a UDTF explode and a windowed re-import stay per record, and
+            # so does a plan that passes null-value records through: they
+            # dispatch at once
+            return 0
+        # Python tier, stream side: decode into the row buffers
+        rows, on_error = self._rows, self.on_error
+        stream_time = self.stream_time
+        taken = 0
+        for r in records:
+            if len(rows) >= cap - 1:
+                break  # this record may fill the batch
+            ev = decode_source_record(step, r, on_error)
+            taken += 1
+            if ev is None or ev.row is None:
+                continue  # dropped, as process() drops it
+            if ev.ts > stream_time:
+                stream_time = ev.ts
+            rows.append(ev.row)
+            self._ts.append(ev.ts)
+            self._parts.append(r.partition)
+            self._offsets.append(r.offset)
+        self.stream_time = stream_time
+        return taken
 
     # --------------------------------------------------- native ingest tier
     def _native_ingest_spec(self):
