@@ -62,13 +62,23 @@ is never timed and reports no time):
                       its emits' host copies (``h2d_bytes``)
 ``step.wait``         span: the host blocked on the step's outputs (a
                       join-table step reads its four load scalars there)
+``store.evict``       span: a retention pass of the window store (every
+                      64th batch, and off cadence when the load check finds
+                      the store at 0.75: ``off_cadence``); the span holds
+                      the enqueue, the device's part lands in the next wait
 ``emit.decode``       span: the step's emits read back in one transfer,
-                      load check, row building (``d2h_bytes``)
+                      load check, row building (``d2h_bytes``; ``lanes``,
+                      the emit mask's length, and ``rows`` decoded from it)
+``store.compact``       span: the in-place compaction after an off-cadence
+                        pass (host rebuild of the store without its graves)
 ``table.grow``        span: a join table doubled (host rebuild; the steps
                       recompile at their next call)
 ``device.step``     counters the step program reports about its own work
                     (``probe_rounds`` and ``probe_lane_rounds``, the lanes
                     those rounds worked on, over ``sampled`` load checks;
+                    the load scalars read there: ``occupancy``, slots taken,
+                    and ``graves`` among them, the mesh's of its fullest
+                    shard;
                     a stream-table join's lookups: ``find_rounds``,
                     ``join_rows`` probed, ``join_matched``)
 ``table.upsert``    counters of the join-table steps (``rows``, ``steps``,
@@ -132,17 +142,19 @@ _STAGE_RANK = {
     "device.execute": 21,
     "step.dispatch": 22,
     "step.wait": 23,
-    "emit.decode": 24,
-    "table.grow": 25,
-    "device.step": 26,
-    "table.upsert": 27,
-    "exchange": 28,
-    "emit.dispatch": 29,
-    "sink.produce": 30,
-    "commit": 31,
-    "push.pipeline.step": 32,
-    "push.tap.deliver": 33,
-    "push.residual.kernel": 34,
+    "store.evict": 24,
+    "emit.decode": 25,
+    "store.compact": 26,
+    "table.grow": 27,
+    "device.step": 28,
+    "table.upsert": 29,
+    "exchange": 30,
+    "emit.dispatch": 31,
+    "sink.produce": 32,
+    "commit": 33,
+    "push.pipeline.step": 34,
+    "push.tap.deliver": 35,
+    "push.residual.kernel": 36,
     "poison.skip": 40,
     "checkpoint": 50,
     # cutover.* phases rank 45 (alpha within), below checkpoint
@@ -157,7 +169,7 @@ def _cutover_rank(name: str):
 def stage_sort_key(name: str):
     if name.startswith("stage:"):
         return (10, name)
-    return _cutover_rank(name) or (_STAGE_RANK.get(name, 35), name)
+    return _cutover_rank(name) or (_STAGE_RANK.get(name, 37), name)
 
 
 _TL = threading.local()

@@ -10,8 +10,10 @@ device, assignment is columnar arithmetic over the timestamp vector:
   sees a static (k·n)-row batch; out-of-range expansions are masked, never
   branched.
 
-SESSION windows are data-dependent merges and stay on the row oracle (their
-segment-scan device formulation is future work, noted in SURVEY §7).
+SESSION windows are data-dependent merges: their device formulation is the
+sort-free segment merge of ``ops/session_merge.py``, driven by the session
+step in runtime/lowering.py (per-key session slots, grown on overflow); the
+helpers here assign fixed windows only.
 
 Stream slicing (the Partial Partial Aggregates / Enthuse formulation): the
 k-fold hopping expansion is the *baseline*; decomposable aggregates instead

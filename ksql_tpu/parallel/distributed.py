@@ -510,12 +510,18 @@ class DistributedDeviceQuery:
                 np.asarray(emits["occupancy"]).reshape(nd).astype(np.int64)
             )
         if "probe_rounds" in emits and tracing.active() is not None:
-            # the step waits for its slowest shard: the longest probe loop
+            # the step waits for its slowest shard: the longest probe
+            # loop; the load scalars beside it are the fullest shard's
+            fullest = int(np.argmax(self.shard_store_occupancy))
             tracing.counter(
                 "device.step", sampled=1,
                 probe_rounds=int(np.asarray(emits["probe_rounds"]).max()),
                 probe_lane_rounds=int(
                     np.asarray(emits["probe_lane_rounds"]).max()
+                ),
+                occupancy=int(self.shard_store_occupancy[fullest]),
+                graves=int(
+                    np.asarray(emits["graves"]).reshape(nd)[fullest]
                 ),
             )
         if "find_rounds" in emits and tracing.active() is not None:
@@ -651,8 +657,8 @@ class DistributedDeviceQuery:
                 self.c.retention_ms is not None
                 and self._batches % self.c.EVICT_INTERVAL == 0
             ):
-                # a dispatch of its own: device.execute's self time
-                self.state = self._evict(self.state)
+                with tracing.span("store.evict"):
+                    self.state = self._evict(self.state)
         with tracing.span("emit.decode"):
             self._account(emits)
             if self.c.agg is not None:

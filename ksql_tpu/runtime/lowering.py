@@ -95,10 +95,27 @@ _HASHED = (
     SqlBaseType.ARRAY, SqlBaseType.MAP, SqlBaseType.STRUCT,
 )
 
-#: HBM budget for a store's aggregate state arrays; wide vector components
-#: (collect caps up to 4096 elements/key) trade initial slot count for width
+#: HBM budget for a store's aggregate state arrays at construction: wide
+#: vector components (collect caps up to 4096 elements/key, slice rings)
+#: trade initial slot count for width.  An eighth of one device's memory
+#: where the device reports it (2 GB of a v5e's 16), and never under 256 MiB
 _VEC_STATE_BUDGET_BYTES = 256 << 20
+_VEC_STATE_DEVICE_SHARE = 8
 _NESTED_BASES = (SqlBaseType.ARRAY, SqlBaseType.MAP, SqlBaseType.STRUCT)
+
+
+def _device_memory_bytes() -> int:
+    """One device's memory as its runtime reports it; 0 where it reports
+    none (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return int((stats or {}).get("bytes_limit", 0))
+
+
+def _vec_state_budget_bytes() -> int:
+    return max(
+        _VEC_STATE_BUDGET_BYTES,
+        _device_memory_bytes() // _VEC_STATE_DEVICE_SHARE,
+    )
 
 
 def _collect_struct_paths(exprs, schema):
@@ -592,11 +609,17 @@ class CompiledDeviceQuery:
             comps = self._agg_components()
             # wide vector state (collect caps / slice rings) shrinks the
             # initial slot count to a bounded HBM budget; the store still
-            # grows on demand
+            # grows on demand.  The bound is bytes alone: cut below what
+            # fits, a store sized to hold its keys doubles its way back,
+            # each time with a host rebuild and a recompile of every step
+            # (about a minute for the sliced hopping step at 32,768 lanes;
+            # PERF.md, PR 34)
             row_bytes = sum(
                 np.dtype(c.dtype).itemsize * c.width for c in comps
             )
-            budget_slots = max(1024, _VEC_STATE_BUDGET_BYTES // max(row_bytes, 1))
+            budget_slots = max(
+                1024, _vec_state_budget_bytes() // max(row_bytes, 1)
+            )
             while store_capacity > 1024 and store_capacity > budget_slots:
                 store_capacity //= 2
             self.store_capacity = store_capacity
@@ -4405,7 +4428,8 @@ class CompiledDeviceQuery:
                 self.retention_ms is not None
                 and self._batches % self.EVICT_INTERVAL == 0
             ):
-                self.state = self._evict(self.state)
+                with tracing.span("store.evict"):
+                    self.state = self._evict(self.state)
         if result is not None:
             self._react_to_load(host)
             return result
@@ -4571,11 +4595,15 @@ class CompiledDeviceQuery:
         if "probe_rounds" in emits and tracing.active() is not None:
             # the step's own account of its store work, read where the
             # host reads the load scalars anyway; ``sampled`` (not ``n``)
-            # is the denominator: pipelined ticks check every 4th batch
+            # is the denominator: pipelined ticks check every 4th batch.
+            # The load scalars ride along: slots taken, and how many of
+            # them are graves
             tracing.counter(
                 "device.step",
                 probe_rounds=int(emits["probe_rounds"]),
                 probe_lane_rounds=int(emits["probe_lane_rounds"]),
+                occupancy=occupancy,
+                graves=int(emits["graves"]),
                 sampled=1,
             )
         headroom = self.capacity * self.expansion
@@ -4586,8 +4614,11 @@ class CompiledDeviceQuery:
                 # evict expired windows now (off-cadence), then compact the
                 # tombstones away in place — the RocksDB compaction analog;
                 # grow only if the table is still dense with LIVE entries
-                self.state = self._evict(self.state)
-                live = self._grow(factor=1)
+                with tracing.span("store.evict"):
+                    self.state = self._evict(self.state)
+                tracing.counter("store.evict", off_cadence=1)
+                with tracing.span("store.compact"):
+                    live = self._grow(factor=1)
                 if (
                     live + headroom > 0.5 * self.store_capacity
                     and self._grow_allowed()
@@ -4732,6 +4763,11 @@ class CompiledDeviceQuery:
                 )
         mask = np.asarray(emits["emit_mask"])
         idx = np.nonzero(mask)[0]
+        # how much of what was read back is rows: the mask's static length
+        # against the emits it marks
+        tracing.counter(
+            "emit.decode", lanes=int(mask.shape[0]), rows=int(idx.size)
+        )
         if idx.size == 0:
             return []
         if "ord_a" in emits:

@@ -72,6 +72,19 @@ def _pageviews_count_feed(engine, poll):
             poll()
 
 
+def _pageviews_hopping_stats_feed(engine, poll):
+    """66 polls of a batch each, past the store's first retention pass
+    (every 64th batch): ``store_grave_pct.hop`` has graves to read."""
+    topic = engine.broker.topic("page_views")
+    for i in range(N_EVENTS + 60):
+        topic.produce(Record(
+            key=None, timestamp=TS0 + 1000 * i,
+            value='{"URL":"/catalog/products/item-%07d/view.html","USER_ID":%d,"LATENCY":%d.25}'
+            % (i * i % 97, 1 + i % 999, i % 1000)))
+        if (i + 1) % 10 == 0:
+            poll()
+
+
 def _clicks_users_join_feed(engine, poll):
     users = engine.broker.topic("users")
     for i in range(40):  # ten keys: inserts, then updates
@@ -148,6 +161,11 @@ def clicks_users_join():
 
 
 @pytest.fixture(scope="module")
+def pageviews_hopping_stats():
+    return Run("pageviews_hopping_stats", _pageviews_hopping_stats_feed)
+
+
+@pytest.fixture(scope="module")
 def pageviews_count_mesh4():
     """The same statements and feed on four of ``conftest.py``'s virtual
     devices (``ksql.runtime.backend=distributed``)."""
@@ -173,13 +191,14 @@ def test_layer_metric_reads_what_the_program_books(metric_name, request):
 
 
 def test_the_harness_finds_what_it_reaches_for(pageviews_count, clicks_users_join,
-                                               pageviews_count_mesh4):
+                                               pageviews_count_mesh4, pageviews_hopping_stats):
     """What ``benchmark/run.py`` takes hold of besides the stages:
     ``FlightRecorder.observer`` and each trace's ``_t0`` and spans
     (``TickLog``), ``KsqlServer.engine_lock`` (the fill, ``_quiescent``) and
     the executor's ``_native_fields``, held to the configuration's
     ``native_ingest``."""
-    for run in (pageviews_count, clicks_users_join, pageviews_count_mesh4):
+    for run in (pageviews_count, clicks_users_join, pageviews_count_mesh4,
+                pageviews_hopping_stats):
         kept = run.stage_stats["tick"]["n"]
         assert kept >= N_POLLS and len(run.traces) == kept
         for trace in run.traces:
