@@ -76,6 +76,9 @@ is never timed and reports no time):
 ``device.step``     counters the step program reports about its own work
                     (``probe_rounds`` and ``probe_lane_rounds``, the lanes
                     those rounds worked on, over ``sampled`` load checks;
+                    ``sliced_lanes``, the lanes of the batch a sliced
+                    hopping step's fold and emission visited: its chunk's
+                    lanes x the chunks that held a row;
                     the load scalars read there: ``occupancy``, slots taken,
                     and ``graves`` among them, the mesh's of its fullest
                     shard;

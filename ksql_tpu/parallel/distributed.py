@@ -31,7 +31,7 @@ from ksql_tpu.common.batch import HostBatch
 from ksql_tpu.compiler.jax_expr import DeviceUnsupported
 from ksql_tpu.parallel.mesh import SHARD_AXIS
 from ksql_tpu.parallel.repartition import all_to_all_exchange, shard_of
-from ksql_tpu.runtime.lowering import CompiledDeviceQuery
+from ksql_tpu.runtime.lowering import CompiledDeviceQuery, _sliced_lanes_of
 from ksql_tpu.runtime.oracle import SinkEmit
 
 
@@ -523,6 +523,7 @@ class DistributedDeviceQuery:
                 graves=int(
                     np.asarray(emits["graves"]).reshape(nd)[fullest]
                 ),
+                **_sliced_lanes_of(emits),
             )
         if "find_rounds" in emits and tracing.active() is not None:
             # a stream-table join's lookups: the longest loop among the

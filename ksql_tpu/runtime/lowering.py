@@ -245,6 +245,73 @@ class _AggSpec:
 #: slice-ring combines cover exactly the monoid component kinds
 _DECOMPOSABLE = ("add", "min", "max")
 
+#: lanes one visit of the sliced fold, claim and combine works on.  A TPU
+#: scatter costs by index and a gather by element, whether or not the lane
+#: holds a row, so a sliced step wider than this visits the chunks of
+#: consecutive lanes that hold an active row and skips the others, as
+#: ``hash_store.probe_insert`` does since PR 26; a step at or under it runs
+#: all its lanes at once.  Read on one v5e chip (my chip runs, PR 35: the
+#: benchmark's hopping step alone, 32,768 lanes, 2^21 slots, ring 8, nine
+#: components), ms a step at chunks of 1,024 / 2,048 / 4,096 lanes: 4,096
+#: rows leading the batch 110.0 / 103.4 / 108.5 (all lanes at once 295.2);
+#: 480 rows 80.7 / 85.0 / 109.0 (288.1); a full batch 383 / 365 / 369
+#: (349).  Some 75 ms of each are passes over the whole ring arrays that no
+#: width moves (PERF.md §5).
+_SLICED_CHUNK = 2048
+
+
+def _pad_lanes(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """``x`` with its leading axis padded to whole chunks of ``width``
+    (zeros: a padding lane is inactive)."""
+    pad = -x.shape[0] % width
+    return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)) if pad else x
+
+
+def _flat_cell(row: Any, col: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:
+    """Index of cell (``row``, ``col``) in a ``rows × cols`` array laid out
+    flat, row by row; int32 where every cell's index fits."""
+    dtype = jnp.int32 if rows * cols < 2 ** 31 else jnp.int64
+    return jnp.asarray(row, dtype) * cols + col.astype(dtype)
+
+
+def _sliced_lanes_of(emits: Dict[str, Any]) -> Dict[str, int]:
+    """A sliced step's ``sliced_lanes`` as a ``device.step`` counter field
+    (the mesh: its busiest shard's); nothing for a step that has none."""
+    if "sliced_lanes" not in emits:
+        return {}
+    return {"sliced_lanes": int(np.asarray(emits["sliced_lanes"]).max())}
+
+
+def _visit_occupied_chunks(
+    active: jnp.ndarray, width: int, carry: Any,
+    visit: Callable[[jnp.ndarray, Any], Any],
+) -> Tuple[Any, jnp.ndarray]:
+    """``visit(lo, carry) -> carry`` for every chunk of ``width``
+    consecutive lanes of ``active`` (whole chunks) that holds a true lane,
+    in lane order, ``lo`` the chunk's first lane; a chunk with none is not
+    visited.  Returns the last carry and the lanes visited (``width`` a
+    chunk, int32).  ``carry`` must derive from varying inputs, as
+    ``probe_insert``'s, so that the loop is well typed under shard_map."""
+    n_chunks = active.shape[0] // width
+    chunk_ids = jnp.arange(n_chunks, dtype=jnp.int32)
+    occupied = jnp.any(active.reshape(n_chunks, width), axis=1)
+
+    def next_chunk(after):
+        return jnp.min(
+            jnp.where(occupied & (chunk_ids > after), chunk_ids, n_chunks)
+        )
+
+    def body(loop):
+        chunk, lanes, carry = loop
+        return next_chunk(chunk), lanes + width, visit(chunk * width, carry)
+
+    zero = jnp.sum(active.astype(jnp.int32) * 0)
+    _, lanes, carry = jax.lax.while_loop(
+        lambda loop: loop[0] < n_chunks, body,
+        (next_chunk(zero - 1), zero, carry),
+    )
+    return carry, lanes
+
 
 class FamilyAttachRefused(DeviceUnsupported):
     """A shared-pipeline attach the runtime must refuse — classified and
@@ -2020,31 +2087,79 @@ class CompiledDeviceQuery:
         slots: jnp.ndarray,
         payload: Dict[str, jnp.ndarray],
         contribs: Sequence[jnp.ndarray],
+    ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
+        """Fold per-row contributions into each key slot's slice ring, the
+        occupied chunks of ``_SLICED_CHUNK`` lanes in lane order: (store,
+        lanes visited).  The ring arrays are carried from chunk to chunk
+        flat, ring position by ring position (a cell at ``ring_pos * slots
+        + slot``): the TPU scatters by index into one-dimensional arrays
+        in place, and what turns a (slots, ring) array into one and back
+        is a pass over the whole array, as the chip lays it out a ring
+        position at a time (0.9 + 0.9 ms a 32-bit half of a 2^21 x 8
+        array on a v5e, 1.8 + 0.9 slot by slot; inside the chunk loop XLA
+        makes that pass once a visit: PERF.md, PR 35) — so it is made
+        here, once a step."""
+        n = int(slots.shape[0])
+        width = min(n, _SLICED_CHUNK)
+        lanes = (slots, payload["active"], payload["wstart"], *contribs)
+        cells = (*(f"a{j}" for j in range(len(contribs))), "slice_id")
+        rings = {name: store[name].T.reshape(-1) for name in cells}
+        rings.update(slast=store["slast"], dirty=store["dirty"])
+        if n == width:
+            rings = self._fold_lanes(rings, *lanes)
+            visited = jnp.sum(slots * 0) + n
+        else:
+            lanes = [_pad_lanes(x, width) for x in lanes]
+
+            def visit(lo, rings):
+                return self._fold_lanes(rings, *(
+                    jax.lax.dynamic_slice_in_dim(x, lo, width) for x in lanes
+                ))
+
+            rings, visited = _visit_occupied_chunks(
+                lanes[1], width, rings, visit
+            )
+        rings.update(
+            (name, rings[name].reshape(store[name].shape[::-1]).T)
+            for name in cells
+        )
+        return {**store, **rings}, visited
+
+    def _fold_lanes(
+        self,
+        rings: Dict[str, jnp.ndarray],
+        slots: jnp.ndarray,
+        active: jnp.ndarray,
+        wstart: jnp.ndarray,
+        *contribs: jnp.ndarray,
     ) -> Dict[str, jnp.ndarray]:
-        """Fold per-row contributions into each key slot's slice ring at
-        ``ring_pos = (slice_index % slice_ring)``.  A targeted ring cell
-        whose stored slice_id differs is a recycled cell from an earlier
-        ring wrap: it resets to the component inits first (idempotent —
-        every batch row targeting one cell carries the SAME slice index,
-        guaranteed by the pre_exchange ring-wrap horizon cut)."""
-        store = dict(store)
-        active = payload["active"]
+        """Fold the lanes given into cell ``(slice_index % slice_ring) *
+        slots + slot`` of the flat ring arrays.  A targeted cell whose
+        stored slice_id differs is a recycled cell from an earlier ring
+        wrap: it resets to the component inits first (idempotent — every
+        batch row targeting one cell carries the SAME slice index,
+        guaranteed by the pre_exchange ring-wrap horizon cut).  The cell's
+        slice_id is read from, and written to, the rings handed in: of two
+        chunks of one batch that target one recycled cell the second finds
+        it current and leaves the first's contributions in it."""
+        rings = dict(rings)
         dump = jnp.int32(self.store_capacity)
         ring = self.slice_ring
-        sidx = payload["wstart"] // self.slice_width  # absolute slice index
-        pos = jnp.remainder(sidx, ring).astype(jnp.int32)
+        sidx = wstart // self.slice_width  # absolute slice index
         eff = jnp.where(active, slots, dump)
         live = active & (slots != dump)
-        cur = store["slice_id"][eff, pos]
-        stale = live & (cur != sidx)
-        tgt_stale = jnp.where(stale, eff, dump)
+        c1 = self.store_capacity + 1
+        dump_cell = _flat_cell(0, dump, ring, c1)
+        cell = _flat_cell(jnp.remainder(sidx, ring), eff, ring, c1)
+        stale = live & (rings["slice_id"][cell] != sidx)
+        tgt_stale = jnp.where(stale, cell, dump_cell)
         for j, comp in enumerate(self.store_layout.components):
-            col = store[f"a{j}"]
+            col = rings[f"a{j}"]
             init = jnp.asarray(comp.init, col.dtype)
-            # duplicate (slot, pos) writers all write the same init value,
-            # so the unordered scatter-set stays deterministic
-            col = col.at[tgt_stale, pos].set(init)
-            ref = col.at[eff, pos]
+            # duplicate cell writers all write the same init value, so
+            # the unordered scatter-set stays deterministic
+            col = col.at[tgt_stale].set(init)
+            ref = col.at[cell]
             contrib = contribs[j]
             if comp.combine == "add":
                 col = ref.add(contrib.astype(col.dtype))
@@ -2052,15 +2167,15 @@ class CompiledDeviceQuery:
                 col = ref.min(contrib.astype(col.dtype))
             else:  # 'max' — _slice_ineligibility admits only the monoids
                 col = ref.max(contrib.astype(col.dtype))
-            store[f"a{j}"] = col
-        tgt_live = jnp.where(live, eff, dump)
-        store["slice_id"] = store["slice_id"].at[tgt_live, pos].set(sidx)
-        store["slast"] = store["slast"].at[eff].max(
-            jnp.where(live, payload["wstart"], -(2 ** 62))
+            rings[f"a{j}"] = col
+        tgt_live = jnp.where(live, cell, dump_cell)
+        rings["slice_id"] = rings["slice_id"].at[tgt_live].set(sidx)
+        rings["slast"] = rings["slast"].at[eff].max(
+            jnp.where(live, wstart, -(2 ** 62))
         )
-        store["dirty"] = store["dirty"].at[eff].set(True)
-        store["dirty"] = store["dirty"].at[self.store_capacity].set(False)
-        return store
+        dirty = rings["dirty"].at[eff].set(True)
+        rings["dirty"] = dirty.at[self.store_capacity].set(False)
+        return rings
 
     def _combine_windows(
         self,
@@ -2155,51 +2270,141 @@ class CompiledDeviceQuery:
         member covering a touched slice emits one coalesced change (the
         expansion path's one-change-per-(key, window)-per-batch cadence,
         at O(touched · k) combine lanes instead of O(rows · k) state
-        lanes)."""
-        active = payload["active"] & (slots != jnp.int32(self.store_capacity))
-        n = int(active.shape[0])
+        lanes).  The emit columns are ``k × n`` lanes, lane ``hop * n +
+        row``; a step wider than ``_SLICED_CHUNK`` claims and then combines
+        the occupied chunks of the batch, ``k × _SLICED_CHUNK`` lanes a
+        visit, and leaves the lanes of the others masked out and zero."""
+        n = int(slots.shape[0])
+        S = W.slices_per_window(member.size_ms, self.slice_width)
+        k = W.hopping_expansion(member.size_ms, member.advance_ms)
+        nn = n * k
+        width = min(n, _SLICED_CHUNK)
+        rows = [
+            _pad_lanes(x, width)
+            for x in (slots, payload["active"], payload["wstart"])
+        ]
+
+        def chunk_lanes(lo):
+            return self._window_lanes(
+                *(jax.lax.dynamic_slice_in_dim(x, lo, width) for x in rows),
+                member, max_ts_pre, lo, n,
+            )
+
+        def claim_lanes(lo, claim):
+            _slot_lane, _w_lane, _mask, cell, lane_idx = chunk_lanes(lo)
+            return claim.at[cell].min(lane_idx)
+
+        # one lane per distinct (slot, window) — two touched slices of one
+        # key can cover the same window — and of those the lowest lane
+        # index of the whole batch, as a stable sort by (slot, window)
+        # would pick: every lane claims before any is combined.  Claimed
+        # by scatter-min into a cell per (slot, window), not sorted: XLA's
+        # TPU sort of these nn lanes (6 operand words) took 3 minutes to
+        # compile at the engine's default capacity.  The ring-wrap cut in
+        # pre_exchange keeps a batch's live slices within ring - 2 of each
+        # other and a window starts at most S - 1 slices before a slice it
+        # covers, so one slot's masked windows span fewer than ring + S
+        # slices and `window mod (ring + S)` names each of them apart.  The
+        # cells lie in one flat array, which the TPU scatters into as it is.
+        zero = jnp.sum(slots * 0)  # varying under shard_map, as the rows
+        claim = zero + jnp.full(
+            (self.store_capacity + 1) * (self.slice_ring + S), nn, jnp.int32
+        )
+        if n == width:
+            claim = claim_lanes(0, claim)
+        else:
+            claim, _ = _visit_occupied_chunks(
+                rows[1], width, claim, claim_lanes
+            )
+
+        def emit_lanes(lo):
+            slot_lane, w_lane, mask, cell, lane_idx = chunk_lanes(lo)
+            winner = mask & (claim[cell] == lane_idx)
+            env, row_ts, dec_exceeded = self._combine_windows(
+                store, slot_lane, w_lane, member
+            )
+            return self._member_emit(
+                env, row_ts, dec_exceeded, winner, member, k * width
+            )
+
+        if n == width:
+            return emit_lanes(0)
+        n_pad = int(rows[1].shape[0])
+
+        def emit_chunk(lo, emits):
+            out = {}
+            for name, lanes in emit_lanes(lo).items():
+                if name == "dec_envelope":
+                    out[name] = emits[name] + lanes
+                    continue
+                # the chunk's k * width lanes are a (k, width) block of
+                # the (k, n) column
+                at = (jnp.int32(0), lo) + (jnp.int32(0),) * (lanes.ndim - 1)
+                out[name] = jax.lax.dynamic_update_slice(
+                    emits[name],
+                    lanes.reshape((k, width) + lanes.shape[1:]), at,
+                )
+            return out
+
+        shapes = jax.eval_shape(emit_lanes, jax.ShapeDtypeStruct((), jnp.int32))
+        emits = {
+            name: jnp.broadcast_to(
+                zero,
+                shape.shape if name == "dec_envelope"
+                else (k, n_pad) + shape.shape[1:],
+            ).astype(shape.dtype)
+            for name, shape in shapes.items()
+        }
+        emits, _ = _visit_occupied_chunks(rows[1], width, emits, emit_chunk)
+        return {
+            name: lanes if name == "dec_envelope"
+            else lanes[:, :n].reshape((nn,) + lanes.shape[2:])
+            for name, lanes in emits.items()
+        }
+
+    def _window_lanes(
+        self,
+        slots: jnp.ndarray,
+        active: jnp.ndarray,
+        wstart: jnp.ndarray,
+        member: _MemberSpec,
+        max_ts_pre: jnp.ndarray,
+        lo: Any,
+        n: int,
+    ) -> Tuple[jnp.ndarray, ...]:
+        """The ``k`` window lanes of each row given, hop by hop: (slot,
+        window start in slice units, mask of the still-open windows that
+        cover the row's slice, the (slot, window)'s claim cell — the dump
+        slot's for a lane masked out —, the lane's index ``hop * n + lo +
+        row`` in a batch of ``n`` rows of which these start at ``lo``)."""
+        dump = jnp.int32(self.store_capacity)
+        rows = int(slots.shape[0])
         width = self.slice_width
         S = W.slices_per_window(member.size_ms, width)
         A = member.advance_ms // width
         k = W.hopping_expansion(member.size_ms, member.advance_ms)
-        nn = n * k
-        dump = jnp.int32(self.store_capacity)
-        sidx = payload["wstart"] // width
+        sidx = wstart // width
         newest = sidx - jnp.remainder(sidx, A)  # newest covering window
-        hops = jnp.repeat(jnp.arange(k, dtype=jnp.int64), n)
+        hops = jnp.repeat(jnp.arange(k, dtype=jnp.int64), rows)
         w_lane = jnp.tile(newest, k) - hops * A  # window start, slice units
         s_lane = jnp.tile(sidx, k)
         slot_lane = jnp.tile(slots, k)
-        act_lane = jnp.tile(active, k)
+        act_lane = jnp.tile(active & (slots != dump), k)
         covers = (w_lane + S > s_lane) & (w_lane >= 0)
         open_w = (
             w_lane * width + member.size_ms + member.grace_ms > max_ts_pre
         )
         mask = act_lane & covers & open_w
-        # one lane per distinct (slot, window) — two touched slices of one
-        # key can cover the same window — and of those the lowest lane
-        # index, as a stable sort by (slot, window) would pick.  Claimed by
-        # scatter-min into a cell per (slot, window), not sorted: XLA's TPU
-        # sort of these nn lanes (6 operand words) took 3 minutes to
-        # compile at the engine's default capacity.  The ring-wrap cut in
-        # pre_exchange keeps a batch's live slices within ring - 2 of each
-        # other and a window starts at most S - 1 slices before a slice it
-        # covers, so one slot's masked windows span fewer than ring + S
-        # slices and `window mod (ring + S)` names each of them apart.
         cells = self.slice_ring + S
-        eff_slot = jnp.where(mask, slot_lane, dump)
-        cell = jnp.remainder(w_lane, cells).astype(jnp.int32)
-        lane_idx = jnp.arange(nn, dtype=jnp.int32)
-        claim = jnp.full(
-            (self.store_capacity + 1, cells), nn, jnp.int32
-        ).at[eff_slot, cell].min(lane_idx)
-        winner = mask & (claim[eff_slot, cell] == lane_idx)
-        env, row_ts, dec_exceeded = self._combine_windows(
-            store, slot_lane, w_lane, member
+        cell = _flat_cell(
+            jnp.remainder(w_lane, cells), jnp.where(mask, slot_lane, dump),
+            cells, self.store_capacity + 1,
         )
-        return self._member_emit(
-            env, row_ts, dec_exceeded, winner, member, nn
+        lane_idx = (
+            hops.astype(jnp.int32) * n + lo
+            + jnp.tile(jnp.arange(rows, dtype=jnp.int32), k)
         )
+        return slot_lane, w_lane, mask, cell, lane_idx
 
     # ----------------------------------------------------------- state mgmt
     def changelog_dirty_state(self) -> Dict[str, Any]:
@@ -4048,7 +4253,9 @@ class CompiledDeviceQuery:
             # START (the expansion path's documented EMIT CHANGES clock) —
             # capture it before the fold advances max_ts
             max_ts_pre = state["max_ts"]
-            store = self._sliced_scatter(store, slot_or_dump, payload, contribs)
+            store, sliced_lanes = self._sliced_scatter(
+                store, slot_or_dump, payload, contribs
+            )
         else:
             store = scatter_combine(
                 store, self.store_layout, slot_or_dump, contribs
@@ -4133,6 +4340,8 @@ class CompiledDeviceQuery:
         emits["probe_rounds"] = probe_rounds.astype(jnp.int32)
         emits["probe_lane_rounds"] = probe_lane_rounds.astype(jnp.int32)
         if self.sliced:
+            # the lanes of the batch the sliced fold and emission visited
+            emits["sliced_lanes"] = sliced_lanes.astype(jnp.int32)
             # host mirror of the stream clock (rides the existing per-batch
             # load readback): lower-bounds the admission floor ensure_ring_for
             # sizes the ring against
@@ -4605,6 +4814,7 @@ class CompiledDeviceQuery:
                 occupancy=occupancy,
                 graves=int(emits["graves"]),
                 sampled=1,
+                **_sliced_lanes_of(emits),
             )
         headroom = self.capacity * self.expansion
         if self.pipeline:

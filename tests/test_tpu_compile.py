@@ -32,9 +32,13 @@ from ksql_tpu.engine.engine import KsqlEngine
 from ksql_tpu.runtime.lowering import CompiledDeviceQuery
 
 HBM_BYTES = 16 * 10**9  # one v5e chip
-#: ceiling on any one compile here.  Measured in this sandbox (PR 21): the
-#: longest, config #2's sliced hopping step, takes ~20 s; a program back in
-#: the minutes fails the test long before it fails a tick deadline
+#: ceiling on any one compile here.  Measured in this sandbox: the longest,
+#: config #2's sliced hopping step, takes ~25 s since PR 35 (its fold, claim
+#: and combine are loops over 2,048-lane chunks: three more loop bodies at
+#: the engine's default 8,192 lanes, where one pass took ~19 s; at the
+#: benchmark cell's 32,768 lanes the same step compiles in ~18 s where the
+#: one pass over 131,072 emit lanes took ~56 s); a program back in the
+#: minutes fails the test long before it fails a tick deadline
 COMPILE_CEILING_S = 90.0
 
 PV_DDL = (
@@ -159,6 +163,35 @@ def test_config2_hopping_multi_udaf_double(one_chip):
     assert dev.sliced, dev.windowing_fallback
     _compile(dev._step, _state(dev, one_chip),
              _on(one_chip, dev.layout.array_structs()))
+
+
+def test_hopping_cell_step_at_its_shapes(one_chip, monkeypatch):
+    """The benchmark cell ``pv_hopstats.backlog``'s step at its shapes
+    (32,768 lanes, 2^21 slots, the ring at the 8 cells the fill leaves it
+    with), whose fold, claim and combine are loops over the occupied
+    2,048-lane chunks: it compiles under the ceiling, no ring array is
+    copied on its way round a loop, the emit columns keep their 131,072
+    lanes and the step counts the lanes it visited."""
+    from ksql_tpu.runtime import lowering
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark/configs/pageviews_hopping_stats.json")) as f:
+        config = json.load(f)
+    # the store is sized from the device's memory, and there is none here
+    monkeypatch.setattr(lowering, "_device_memory_bytes", lambda: HBM_BYTES)
+    dev = _lowered(config["statements"],
+                   capacity=config["engine_props"]["ksql.batch.capacity"],
+                   store_capacity=config["engine_props"]["ksql.state.slots"])
+    dev._resize_ring(dev.slice_width, 8)
+    assert dev.sliced and (dev.capacity, dev.store_capacity) == (32_768, 1 << 21)
+    state = _state(dev, one_chip)
+    arrays = _on(one_chip, dev.layout.array_structs())
+    text = _compile(dev._step, state, arrays).as_text()
+    # nothing the size of a ring array (2,097,153 x 8) is copied
+    assert not re.findall(r"(?:2097153,8|8,2097153|16777224)\]\S* copy\(", text)
+    emits = jax.eval_shape(dev._trace_step, state, arrays)[1]
+    assert emits["emit_mask"].shape == (4 * 32_768,)
+    assert emits["sliced_lanes"].shape == () and emits["sliced_lanes"].dtype == np.int32
 
 
 @pytest.mark.parametrize("table_slots", [1 << 16, 1 << 21])
