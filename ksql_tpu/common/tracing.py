@@ -26,6 +26,24 @@ Design constraints:
   child time) on its stage; a timed ``stage()`` call counts as a child of
   the span it was made under.  ``tick``'s ``self_ms`` is the part of a
   tick under no span at all.
+* **By cause, not only by place**: beside ``self_ms`` a span books
+  ``gc_ms``, the collector's pauses that fell inside it (one
+  ``gc.callbacks`` hook for the process, held by the engines that trace;
+  a pause is a timed stage ``gc.pause`` under the innermost open span,
+  so no ``self_ms`` holds it; booked at every span exit and tick finish,
+  0.0 where none fell; like ``ms`` and unlike ``self_ms`` it holds what
+  fell inside a span's children).  ``off_cpu_ms`` is wall time less the
+  thread's CPU time (``time.thread_time`` beside ``perf_counter``): the
+  thread was not running.  That clock is a system call, 0.3 us here and
+  5.7 us on the sealed machine that holds the chip, so it is read where
+  the answer is worth it and not at every span: by the tick, by a span
+  that waits for the device by design (``wait=True``: it keeps its
+  off-CPU time to itself) and by a span that asks (``cpu=True``: it hands
+  it up like every other span).  ``tick``'s ``off_cpu_ms`` is then the
+  time the poll thread should have been running and was not (the GIL in
+  another thread's hands, another thread's collection, the scheduler, a
+  blocking transfer, a page fault), and a kept tick's span durations say
+  where.
 * **One clock with the device trace**: every span, and the tick itself
   (``ksql.tick#<query>#<seq>``), is also entered as a
   ``jax.profiler.TraceAnnotation`` once jax is loaded, so a profile of a
@@ -43,7 +61,9 @@ is never timed and reports no time):
 
 ==================  ========================================================
 ``tick``            the whole poll tick of one query (total, booked when
-                    the tick ends; ``self_ms`` = time under no span)
+                    the tick ends; ``self_ms`` = time under no span,
+                    ``gc_ms`` = every pause inside it, ``off_cpu_ms`` =
+                    the thread not running outside the declared waits)
 ``poll``            span: Consumer.poll for the tick (``rows``)
 ``process``         span: the hand-over of a poll's records to the executor
                     (a block for the records it only buffers, the
@@ -51,7 +71,8 @@ is never timed and reports no time):
                     ``block_rows`` of them in a block; a full micro-batch
                     runs the stages below inside it)
 ``deserialize``     decode_source_record (total, all backends); a span per
-                    chunk in the native C++ tier
+                    chunk in the native C++ tier (``off_cpu_ms``: the parse
+                    runs with the GIL released and takes it back)
 ``stage:<ctx>``     total: one oracle ExecutionStep node (Filter/Join/...)
 ``drain``           span: the executor's end-of-tick flush
 ``batch.assemble``  span: key decode, column encode, dictionary learn and
@@ -60,15 +81,22 @@ is never timed and reports no time):
 ``device.execute``  span: a device step served from the jit cache (hit)
 ``step.dispatch``     span: h2d of the batch + enqueue of the step and of
                       its emits' host copies (``h2d_bytes``)
-``step.wait``         span: the host blocked on the step's outputs (a
-                      join-table step reads its four load scalars there)
+``step.wait``         span, a declared wait: the host blocked on the
+                      step's outputs (a join-table step reads its four
+                      load scalars there)
 ``store.evict``       span: a retention pass of the window store (every
                       64th batch, and off cadence when the load check finds
                       the store at 0.75: ``off_cadence``); the span holds
                       the enqueue, the device's part lands in the next wait
-``emit.decode``       span: the step's emits read back in one transfer,
-                      load check, row building (``d2h_bytes``; ``lanes``,
-                      the emit mask's length, and ``rows`` decoded from it)
+``emit.decode``       span: the step's emits read back, load check, row
+                      building (``d2h_bytes``; ``lanes``, the emit mask's
+                      length, and ``rows`` decoded from it); its self time
+                      is the load check, the join's counts, the members
+``emit.read``           span, a declared wait: the emits cross to the host
+                        (one ``jax.device_get``; on the mesh every shard's
+                        columns, a blocking read a leaf)
+``emit.rows``           span: ``_decode_emits``, a ``SinkEmit`` and a row
+                        dict a record from the host copy
 ``store.compact``       span: the in-place compaction after an off-cadence
                         pass (host rebuild of the store without its graves)
 ``table.grow``        span: a join table doubled (host rebuild; the steps
@@ -97,12 +125,20 @@ is never timed and reports no time):
 ``emit.dispatch``   span: block encode, then the emit callbacks and the sink
                     produce, for the block at once or emit by emit (``rows``
                     dispatched, ``block_rows`` of them as a block)
-``sink.produce``    total: SinkWriter's block encode, and its produce: one
-                    stage a block (``n`` = its records) on the block path,
-                    one an emit in the per-emit loop (all backends)
+``emit.callbacks``    total: the block callback's one pass over an emission
+                      block (the per-emit loop's callbacks stay the span's
+                      self time)
+``sink.produce``      total: SinkWriter's block encode, and its produce: one
+                      stage a block (``n`` = its records) on the block path,
+                      one an emit in the per-emit loop (all backends);
+                      ``encode_ms`` is the block encode's part of its time
+                      (``encode_batch``), the append is the rest
 ``commit``          span: the tick's commit point (commit cursor, state
                     epoch, changelog append, query metrics)
-``poison.skip``     USER-classified records skipped by the poll loop
+``gc.pause``        total: a collection of CPython's collector that this
+                    thread ran inside a tick, under whichever span was open
+                    (``gen2`` full collections, ``gen2_ms`` their time); no
+                    entry among a tick's spans
 ``checkpoint``      engine state snapshot (recorded under ``__engine__``)
 ``push.pipeline.step``  one shared push-registry pipeline pump (poll →
                     process → drain; ``rows`` counts ring appends, from the
@@ -122,13 +158,17 @@ is never timed and reports no time):
 
 from __future__ import annotations
 
+import gc
 import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 _perf = time.perf_counter
+#: the calling thread's CPU time: wall time it does not cover, the thread
+#: was not running
+_cpu = time.thread_time
 
 #: recorder key for engine-level (not per-query) work, e.g. checkpoints
 ENGINE_RECORDER = "__engine__"
@@ -147,20 +187,24 @@ _STAGE_RANK = {
     "step.wait": 23,
     "store.evict": 24,
     "emit.decode": 25,
-    "store.compact": 26,
-    "table.grow": 27,
-    "device.step": 28,
-    "table.upsert": 29,
-    "exchange": 30,
-    "emit.dispatch": 31,
-    "sink.produce": 32,
-    "commit": 33,
-    "push.pipeline.step": 34,
-    "push.tap.deliver": 35,
-    "push.residual.kernel": 36,
-    "poison.skip": 40,
-    "checkpoint": 50,
+    "emit.read": 26,
+    "emit.rows": 27,
+    "store.compact": 28,
+    "table.grow": 29,
+    "device.step": 30,
+    "table.upsert": 31,
+    "exchange": 32,
+    "emit.dispatch": 33,
+    "emit.callbacks": 34,
+    "sink.produce": 35,
+    "commit": 36,
+    "push.pipeline.step": 37,
+    "push.tap.deliver": 38,
+    "push.residual.kernel": 39,
+    # unlisted stages rank 40
     # cutover.* phases rank 45 (alpha within), below checkpoint
+    "checkpoint": 50,
+    "gc.pause": 55,
     "tick": 60,  # the whole tick: the table's total row
 }
 
@@ -172,7 +216,7 @@ def _cutover_rank(name: str):
 def stage_sort_key(name: str):
     if name.startswith("stage:"):
         return (10, name)
-    return _cutover_rank(name) or (_STAGE_RANK.get(name, 37), name)
+    return _cutover_rank(name) or (_STAGE_RANK.get(name, 40), name)
 
 
 _TL = threading.local()
@@ -197,11 +241,13 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
-def span(name: str):
+def span(name: str, wait: bool = False, cpu: bool = False):
     """Context manager recording a span on the active trace (no-op when
-    tracing is off)."""
+    tracing is off).  ``wait`` declares a span that waits for the device
+    by design: it books ``off_cpu_ms`` and keeps that time to itself;
+    ``cpu`` books it and hands it up."""
     tr = active()
-    return tr.span(name) if tr is not None else _NULL
+    return tr.span(name, wait, cpu) if tr is not None else _NULL
 
 
 def stage(name: str, dur_s: float = 0.0, **counters) -> None:
@@ -254,25 +300,44 @@ class _Span:
     afterwards whether it compiled, the native decode how many rows of
     its chunk were good."""
 
-    __slots__ = ("trace", "name", "n", "t0", "depth", "child_s",
-                 "_annotation")
+    __slots__ = ("trace", "name", "n", "t0", "depth", "child_s", "gc_s",
+                 "wait", "cpu", "wait_s", "_cpu0", "_annotation")
 
-    def __init__(self, trace: "TickTrace", name: str):
+    def __init__(self, trace: "TickTrace", name: str, wait: bool = False,
+                 cpu: bool = False):
         self.trace = trace
         self.name = name
         self.n = 1
         self.child_s = 0.0
+        #: seconds of the collector's pauses inside the span
+        self.gc_s = 0.0
+        self.wait = wait
+        self.cpu = cpu or wait
+        #: off-CPU seconds the declared waits under the span kept
+        self.wait_s = 0.0
 
     def __enter__(self):
         tr = self.trace
         self.depth = len(tr._open)
         tr._open.append(self)
         self._annotation = _annotate(self.name)
+        if self.cpu:
+            # the CPU clock is read outside the wall clock's interval on
+            # both sides: a span that only computes reads 0, not noise
+            self._cpu0 = _cpu()
         self.t0 = _perf()
         return self
 
     def __exit__(self, *exc):
         dur = _perf() - self.t0
+        counters = {"self_ms": (dur - self.child_s) * 1000.0,
+                    "gc_ms": self.gc_s * 1000.0}
+        kept_s = self.wait_s
+        if self.cpu:
+            off = max(dur - (_cpu() - self._cpu0) - kept_s, 0.0)
+            counters["off_cpu_ms"] = off * 1000.0
+            if self.wait:
+                kept_s += off
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
         tr = self.trace
@@ -282,9 +347,9 @@ class _Span:
             pass
         parent = tr._open[-1] if tr._open else tr
         parent.child_s += dur
+        parent.wait_s += kept_s
         tr.add_span(self.name, self.t0, dur, self.depth)
-        tr._book(self.name, dur, self.n,
-                 {"self_ms": (dur - self.child_s) * 1000.0})
+        tr._book(self.name, dur, self.n, counters)
         return False
 
 
@@ -293,7 +358,8 @@ class TickTrace:
 
     __slots__ = (
         "query_id", "seq", "started_at_ms", "dur_ms", "spans", "stages",
-        "status", "error", "keep", "child_s", "_t0", "_open", "_dumped",
+        "status", "error", "keep", "child_s", "gc_s", "wait_s", "_t0",
+        "_cpu0", "_open", "_pauses", "_dumped",
     )
 
     def __init__(self, query_id: str, seq: int):
@@ -310,13 +376,20 @@ class TickTrace:
         self.keep = True  # engine clears for empty ticks (ring hygiene)
         #: seconds under depth-0 spans and timed stages outside any span
         self.child_s = 0.0
+        #: seconds of the collector's pauses inside the tick
+        self.gc_s = 0.0
+        #: off-CPU seconds the tick's declared waits kept
+        self.wait_s = 0.0
+        self._cpu0 = _cpu()
         self._t0 = _perf()
         self._open: List[_Span] = []  # spans entered but not yet exited
+        #: (perf_counter instant it ended, seconds) of each pause in the tick
+        self._pauses: List[Tuple[float, float]] = []
         self._dumped = False
 
     # ------------------------------------------------------------ recording
-    def span(self, name: str) -> _Span:
-        return _Span(self, name)
+    def span(self, name: str, wait: bool = False, cpu: bool = False) -> _Span:
+        return _Span(self, name, wait, cpu)
 
     def add_span(self, name: str, t0: float, dur_s: float, depth: int) -> None:
         self.spans.append({
@@ -329,10 +402,34 @@ class TickTrace:
     def stage(self, name: str, dur_s: float = 0.0, n: int = 1,
               **counters) -> None:
         """Accumulate one timed stage invocation; its time counts as a
-        child of the span it was made under (of the tick under none)."""
+        child of the span it was made under (of the tick under none).  A
+        pause of the collector inside it stays in the stage's own time (two
+        clock reads are all its site takes) and is a child of that span
+        already, as ``gc.pause``: it is not counted there twice."""
         if dur_s:
-            (self._open[-1] if self._open else self).child_s += dur_s
+            child_s = dur_s
+            if self._pauses:
+                t0 = _perf() - dur_s
+                for end, pause_s in reversed(self._pauses):
+                    if end <= t0:
+                        break
+                    child_s -= pause_s
+            (self._open[-1] if self._open else self).child_s += child_s
         self._book(name, dur_s, n, counters)
+
+    def pause(self, end: float, dur_s: float, generation: int) -> None:
+        """Book a collection this thread ran until ``end`` (on
+        ``perf_counter``'s clock): ``gc.pause`` under the innermost open
+        span, ``gc_ms`` on every open span and on the tick."""
+        self._pauses.append((end, dur_s))
+        for sp in self._open:
+            sp.gc_s += dur_s
+        self.gc_s += dur_s
+        (self._open[-1] if self._open else self).child_s += dur_s
+        full = generation == 2
+        self._book("gc.pause", dur_s, 1, {
+            "gen2": int(full), "gen2_ms": dur_s * 1000.0 if full else 0.0,
+        })
 
     def counter(self, name: str, **counters) -> None:
         self._book(name, 0.0, 0, counters)
@@ -351,8 +448,13 @@ class TickTrace:
         """Close the tick: its duration, and the ``tick`` stage whose
         ``self_ms`` is the time no span or timed stage accounts for."""
         dur = _perf() - self._t0
+        off = max(dur - (_cpu() - self._cpu0) - self.wait_s, 0.0)
         self.dur_ms = round(dur * 1000.0, 3)
-        self._book("tick", dur, 1, {"self_ms": (dur - self.child_s) * 1000.0})
+        self._book("tick", dur, 1, {
+            "self_ms": (dur - self.child_s) * 1000.0,
+            "gc_ms": self.gc_s * 1000.0,
+            "off_cpu_ms": off * 1000.0,
+        })
 
     def to_dict(self) -> Dict[str, Any]:
         # a crash dump serializes mid-tick, before finish()/span exits run:
@@ -384,6 +486,51 @@ class TickTrace:
                 for name, st in self.stages.items()
             },
         }
+
+
+# ------------------------------------------------- the collector's pauses
+#: re-entrant: a release may run as a dead engine's finalizer, inside a
+#: collection that an allocation under the lock set off
+_gc_lock = threading.RLock()
+_gc_holders = 0
+_gc_t0: Optional[float] = None
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    """The process's one ``gc.callbacks`` entry.  A collection runs on the
+    thread whose allocation set it off, start to stop, and no second one
+    starts meanwhile: one stamp serves.  A thread with no open tick books
+    nothing; its collection is time off the CPU to a thread that has."""
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = _perf() if getattr(_TL, "trace", None) is not None else None
+        return
+    t0, _gc_t0 = _gc_t0, None
+    if t0 is not None:
+        tr = getattr(_TL, "trace", None)
+        if tr is not None:
+            end = _perf()
+            tr.pause(end, end - t0, info["generation"])
+
+
+def hold_gc_hook() -> None:
+    """Install the hook for one more holder (an engine that traces)."""
+    global _gc_holders
+    with _gc_lock:
+        _gc_holders += 1
+        if _gc_holders == 1:
+            gc.callbacks.append(_on_gc)
+
+
+def release_gc_hook() -> None:
+    """One holder fewer; the last one takes the hook out."""
+    global _gc_holders
+    with _gc_lock:
+        if _gc_holders == 0:
+            return
+        _gc_holders -= 1
+        if _gc_holders == 0 and _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
 
 
 class tick:

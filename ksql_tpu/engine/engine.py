@@ -15,6 +15,7 @@ import dataclasses
 import itertools
 import os
 import threading
+import weakref
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -498,6 +499,15 @@ class KsqlEngine:
         self.trace_enabled = cfg._bool(self.config.get(cfg.TRACE_ENABLE, True))
         self.trace_ring = int(self.config.get(cfg.TRACE_RING_SIZE, 64))
         self.trace_recorders: Dict[str, tracing.FlightRecorder] = {}
+        # the process's one gc.callbacks hook books the collector's pauses
+        # on the open tick: held from here to shutdown() (or to the
+        # engine's own collection, where nobody calls that)
+        self._gc_hook_release = None
+        if self.trace_enabled:
+            tracing.hold_gc_hook()
+            self._gc_hook_release = weakref.finalize(
+                self, tracing.release_gc_hook
+            )
         # entries trimmed off the processing-log ring so far (the ring is
         # bounded by ksql.processing.log.buffer.size, cached here — the
         # append sits on the per-record error path); /metrics surfaces it
@@ -3081,6 +3091,8 @@ class KsqlEngine:
         # stop the overload monitor thread (server mode) before the
         # queries it samples go away
         self.overload.stop()
+        if self._gc_hook_release is not None:
+            self._gc_hook_release()  # at most once: a finalizer
         if self.push_registry is not None:
             # shared push pipelines hold broker consumers and (listener
             # mode) handle callbacks: tear them down before the queries go
@@ -3383,8 +3395,6 @@ class KsqlEngine:
                                     f"@{rec_.offset}"
                                 ),
                             )
-                            if tick is not None:
-                                tick.stage("poison.skip", 0.0)
                             consumed.append(None)
                             n += 1
                             note_durable()
@@ -3424,8 +3434,6 @@ class KsqlEngine:
                                     self.metrics.for_query(
                                         handle.query_id
                                     ).errors.mark(1)
-                                    if tick is not None:
-                                        tick.stage("poison.skip", 0.0)
                                     handed += 1
                                     consumed.append(handed - 1)
                                     n += 1  # offset advanced: skipping IS
@@ -3442,8 +3450,6 @@ class KsqlEngine:
                                     self.metrics.for_query(
                                         handle.query_id
                                     ).errors.mark(1)
-                                    if tick is not None:
-                                        tick.stage("poison.skip", 0.0)
                                     handed += 1
                                     consumed.append(handed - 1)
                                     n += 1

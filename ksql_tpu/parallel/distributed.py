@@ -415,7 +415,7 @@ class DistributedDeviceQuery:
                     h2d_bytes=int(sum(v.nbytes for v in arrays.values())),
                 )
                 self.state, metrics = self._table_step(self.state, arrays)
-            with tracing.span("step.wait"):
+            with tracing.span("step.wait", wait=True):
                 # one blocking read of the load scalars; every replica folds
                 # the same batch, so the slowest sets the pace
                 load = {
@@ -638,7 +638,7 @@ class DistributedDeviceQuery:
         with tracing.span("step.dispatch"):
             new_state, emits = self._step(self.state, arrays)
         while self.c.session:
-            with tracing.span("step.wait"):
+            with tracing.span("step.wait", wait=True):
                 overflowed = int(np.asarray(emits["sess_ovf"]).sum()) > 0
             if not overflowed:
                 break
@@ -650,7 +650,7 @@ class DistributedDeviceQuery:
                 new_state, emits = self._step(self.state, arrays)
         self.state = new_state
         if not self.c.session:  # the session step's overflow read has waited
-            with tracing.span("step.wait"):
+            with tracing.span("step.wait", wait=True):
                 jax.block_until_ready(emits)
         if self.c.agg is not None:
             self._batches += 1
@@ -661,6 +661,11 @@ class DistributedDeviceQuery:
                 with tracing.span("store.evict"):
                     self.state = self._evict(self.state)
         with tracing.span("emit.decode"):
+            with tracing.span("emit.read", wait=True):
+                # every shard's columns cross here, a blocking read a leaf;
+                # the accounting, the checks and the decode below read
+                # these host copies
+                emits = self._flatten(emits)
             self._account(emits)
             if self.c.agg is not None:
                 overflow = int(np.asarray(emits["overflow"]).sum())
@@ -681,7 +686,8 @@ class DistributedDeviceQuery:
                         "shard); restart the query with a larger "
                         "store_capacity"
                     )
-            return self.c._decode_emits(self._flatten(emits))
+            with tracing.span("emit.rows"):
+                return self.c._decode_emits(emits)
 
     # -------------------------------------------------- executor-facing API
     def flush_pipeline(self) -> List[SinkEmit]:

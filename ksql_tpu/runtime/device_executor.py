@@ -22,6 +22,7 @@ executor runs with batch size 1.
 
 from __future__ import annotations
 
+import time
 from typing import Callable, Dict, List, Optional
 
 from ksql_tpu.common import faults, tracing
@@ -483,7 +484,7 @@ class DeviceExecutor:
             # -> columnar arrays in C++, one span a chunk whose ``n`` counts
             # the rows (the per-record path accumulates the same stage
             # inside decode_source_record)
-            with tracing.span("deserialize") as sp:
+            with tracing.span("deserialize", cpu=True) as sp:
                 try:
                     data, valid, row_ok, learned = native.parse_batch(
                         [r.value for r in chunk], self._native_fields
@@ -954,7 +955,8 @@ class DeviceExecutor:
         block_rows = 0
         if writer.block_ready(precoded) and (
             callback is None
-            or (block_callback is not None and block_callback(emits))
+            or (block_callback is not None
+                and _timed_block_callback(block_callback, emits))
         ):
             # block dispatch: nothing observable asks for per-emit
             # treatment, so the callbacks ran in one pass and the sink
@@ -979,6 +981,20 @@ class DeviceExecutor:
                     callback(e)
                 writer.produce(e, precoded=v)
         tracing.counter("emit.dispatch", rows=len(emits), block_rows=block_rows)
+
+
+def _timed_block_callback(block_callback, emits: List[SinkEmit]) -> bool:
+    """The block callback's one pass over ``emits`` as the timed stage
+    ``emit.callbacks``; where it declines, it has run nothing and books
+    nothing."""
+    tr = tracing.active()
+    if tr is None:
+        return block_callback(emits)
+    t0 = time.perf_counter()
+    took = block_callback(emits)
+    if took:
+        tr.stage("emit.callbacks", time.perf_counter() - t0)
+    return took
 
 
 class DistributedDeviceExecutor(DeviceExecutor):

@@ -3168,7 +3168,7 @@ class CompiledDeviceQuery:
         with tracing.span("step.dispatch"):
             _note_transfer("h2d_bytes", arrays)
             self.state, metrics = self._table_steps[idx](self.state, arrays)
-        with tracing.span("step.wait"):
+        with tracing.span("step.wait", wait=True):
             # one blocking read of the step's four load scalars: the host
             # waits for the step here, and for nothing else
             load = {k: int(v) for k, v in jax.device_get(metrics).items()}
@@ -4608,7 +4608,7 @@ class CompiledDeviceQuery:
                 for leaf in emits.values():
                     leaf.copy_to_host_async()
         while self.session:
-            with tracing.span("step.wait"):
+            with tracing.span("step.wait", wait=True):
                 overflowed = int(emits["sess_ovf"]) > 0
             if not overflowed:
                 break
@@ -4662,10 +4662,11 @@ class CompiledDeviceQuery:
         is explicit so that its span holds the host blocked on the device
         and nothing else."""
         if not self.session:  # the session step's overflow read has waited
-            with tracing.span("step.wait"):
+            with tracing.span("step.wait", wait=True):
                 jax.block_until_ready(emits)
         with tracing.span("emit.decode"):
-            host = jax.device_get(emits)
+            with tracing.span("emit.read", wait=True):
+                host = jax.device_get(emits)
             if react:
                 self._react_to_load(host)
             self._note_join_stats(host)
@@ -4674,7 +4675,8 @@ class CompiledDeviceQuery:
                 # the raw block gathers on the device: decode from the
                 # step's own arrays, whose host copies the read has cached
                 host = {k: emits[k] for k in host}
-            return self._decode_emits(host)
+            with tracing.span("emit.rows"):
+                return self._decode_emits(host)
 
     _JOIN_STATS = ("find_rounds", "join_rows", "join_matched")
 

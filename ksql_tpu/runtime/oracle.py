@@ -1263,7 +1263,8 @@ class SinkWriter:
         if tr is not None:
             # the block encode IS these emits' serialize time; produce()
             # still records its (now serialization-free) per-emit stage
-            tr.stage("sink.produce", _time.perf_counter() - t0, n=encoded)
+            dur_s = _time.perf_counter() - t0
+            tr.stage("sink.produce", dur_s, n=encoded, encode_ms=dur_s * 1e3)
         return out
 
     def produce(self, e: SinkEmit, precoded=_UNSET) -> None:

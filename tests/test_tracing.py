@@ -5,8 +5,11 @@ ANALYZE output shape, the Prometheus exposition of /metrics, and the new
 observability fault points (schema registry lookups, HTTP peer
 forwarding)."""
 
+import gc
 import json
 import re
+import threading
+import time
 import urllib.request
 
 import pytest
@@ -437,7 +440,14 @@ def test_tick_accounts_for_itself():
     time is its spans' duration minus their direct children and the timed
     stages accumulated under them."""
     e, handle = _device_count_engine()
-    _drive_ticks(e, 21)
+    # a pause of the collector is a child of whichever span it falls in
+    # and has no entry among the spans: this test's arithmetic over the
+    # spans runs without one (the pauses have their own test below)
+    gc.disable()
+    try:
+        _drive_ticks(e, 21)
+    finally:
+        gc.enable()
     rec = e.trace_recorder(handle.query_id)
     ticks = rec.recent()[1:]  # without the tick that compiled
     assert len(ticks) == 20
@@ -445,7 +455,8 @@ def test_tick_accounts_for_itself():
     unattributed = sum(t["stages"]["tick"]["self_ms"] for t in ticks)
     assert 0 <= unattributed <= 0.10 * total
     #: timed accumulators (no span of their own) and the span they run under
-    accumulated_under = {"sink.produce": "emit.dispatch"}
+    accumulated_under = {"sink.produce": "emit.dispatch",
+                         "emit.callbacks": "emit.dispatch"}
     for t in ticks:
         spans, stages = t["spans"], t["stages"]
         assert {"poll", "process", "drain", "commit"} == {
@@ -465,6 +476,15 @@ def test_tick_accounts_for_itself():
             assert stages[name]["self_ms"] == pytest.approx(
                 want, abs=0.001 * (len(spans) + n_spans)), name
             assert stages[name]["self_ms"] >= -0.001
+            # booked at every span exit, 0.0 where no collection fell
+            assert stages[name]["gc_ms"] == 0.0
+        # the CPU clock is a system call: the tick, the declared waits and
+        # the native parse read it, no other span
+        assert {n for n, st in stages.items() if "off_cpu_ms" in st} == {
+            "tick", "step.wait", "emit.read", "deserialize"}
+        for name in ("tick", "step.wait", "emit.read", "deserialize"):
+            assert 0.0 <= stages[name]["off_cpu_ms"] <= stages[name]["ms"]
+        assert "gc.pause" not in stages and stages["tick"]["gc_ms"] == 0.0
     e.shutdown()
 
 
@@ -728,6 +748,7 @@ def _shape_of(rec):
         name: {k: v for k, v in st.items()
                if not k.endswith("_ms") and k != "d2h_bytes"}
         for name, st in rec.stage_stats().items()
+        if name != "gc.pause"  # the collector keeps its own schedule
     }
     return ticks, stats
 
@@ -761,7 +782,254 @@ def test_trace_annotations_leave_the_recorder_unchanged(tmp_path):
             names.update(ev.name for ev in line.events)
     assert {f"ksql.tick#{qid}#{seq}" for seq in (1, 2, 3)} <= names
     assert {"poll", "process", "drain", "device.execute", "step.wait",
-            "emit.decode", "emit.dispatch", "commit"} <= names
+            "emit.decode", "emit.read", "emit.rows", "emit.dispatch",
+            "commit"} <= names
+
+
+# ------------------------------- the host's serial part, by cause (ISSUE 36)
+@pytest.fixture
+def gc_hook_from_zero(monkeypatch):
+    """The hook's holders counted from none, whatever engines earlier tests
+    of this process left alive; their hold is given back afterwards."""
+    gc.collect()  # dead engines give their hold back now, not mid-test
+    installed = tracing._on_gc in gc.callbacks
+    if installed:
+        gc.callbacks.remove(tracing._on_gc)
+    monkeypatch.setattr(tracing, "_gc_holders", 0)
+    yield
+    while tracing._on_gc in gc.callbacks:
+        gc.callbacks.remove(tracing._on_gc)
+    if installed:
+        gc.callbacks.append(tracing._on_gc)
+
+
+def _traced_tick(body):
+    """One tick of a recorder of its own around ``body(trace)``; returns
+    the tick's stages."""
+    rec = tracing.FlightRecorder("q", 4)
+    with tracing.tick(rec) as tr:
+        body(tr)
+    return rec.last().stages
+
+
+def test_a_collection_is_booked_where_it_falls(gc_hook_from_zero):
+    """A collection forced inside a span: one ``gc.pause`` under the
+    innermost open span, ``gc_ms`` on every open span and on the tick, no
+    ``self_ms`` holds it, and each parent counts its child's time once."""
+    tracing.hold_gc_hook()
+
+    def body(tr):
+        with tracing.span("outer"):
+            with tracing.span("inner"):
+                gc.collect()
+            with tracing.span("quiet"):
+                pass
+
+    try:
+        gc.disable()  # only the forced one
+        stages = _traced_tick(body)
+    finally:
+        gc.enable()
+        tracing.release_gc_hook()
+    pause = stages["gc.pause"]
+    assert pause["n"] == 1 and pause["gen2"] == 1
+    assert pause["gen2_ms"] == pause["ms"] > 0.0
+    for name in ("inner", "outer", "tick"):
+        assert stages[name]["gc_ms"] == pytest.approx(pause["ms"])
+    assert stages["quiet"]["gc_ms"] == 0.0  # the field is there all the same
+    inner, outer, tick = stages["inner"], stages["outer"], stages["tick"]
+    assert inner["self_ms"] == pytest.approx(inner["ms"] - pause["ms"])
+    assert 0.0 <= inner["self_ms"] < 0.5 * pause["ms"] + 0.05
+    assert outer["self_ms"] == pytest.approx(
+        outer["ms"] - inner["ms"] - stages["quiet"]["ms"])
+    assert tick["self_ms"] == pytest.approx(tick["ms"] - outer["ms"])
+    assert tracing._on_gc not in gc.callbacks
+
+
+def test_a_pause_inside_a_timed_stage_is_its_spans_child_once(gc_hook_from_zero):
+    """A timed stage's two clock reads hold a pause that fell between them,
+    and the pause is ``gc.pause`` under the span already: the span's self
+    time loses it once, not twice."""
+    tracing.hold_gc_hook()
+
+    def body(tr):
+        with tracing.span("outer"):
+            gc.collect()  # before the stage: not the stage's
+            t0 = time.perf_counter()
+            gc.collect()
+            tr.stage("leaf", time.perf_counter() - t0)
+
+    try:
+        gc.disable()
+        stages = _traced_tick(body)
+    finally:
+        gc.enable()
+        tracing.release_gc_hook()
+    outer, leaf, pause = stages["outer"], stages["leaf"], stages["gc.pause"]
+    assert pause["n"] == 2 and outer["gc_ms"] == pytest.approx(pause["ms"])
+    assert leaf["ms"] > 0.4 * pause["ms"]  # the stage's total keeps its pause
+    second = outer["ms"] - outer["self_ms"] - leaf["ms"]  # = the first pause
+    assert 0.0 < second < pause["ms"]
+    assert outer["self_ms"] >= 0.0
+
+
+def test_a_thread_with_no_open_tick_books_nothing(gc_hook_from_zero):
+    tracing.hold_gc_hook()
+    try:
+        gc.collect()  # no tick on this thread: the hook is a no-op
+        stages = _traced_tick(lambda tr: None)
+    finally:
+        tracing.release_gc_hook()
+    assert "gc.pause" not in stages and stages["tick"]["gc_ms"] == 0.0
+
+
+def test_one_gc_hook_however_many_engines(gc_hook_from_zero):
+    first = _engine({cfg.RUNTIME_BACKEND: "oracle"})
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    second = _engine({cfg.RUNTIME_BACKEND: "oracle"})
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    first.shutdown()
+    first.shutdown()  # gives its hold back once
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    second.shutdown()
+    assert tracing._on_gc not in gc.callbacks and tracing._gc_holders == 0
+    # an engine nobody shuts down gives it back when it is collected
+    third = _engine({cfg.RUNTIME_BACKEND: "oracle"})
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    del third
+    gc.collect()
+    assert tracing._on_gc not in gc.callbacks
+
+
+def test_trace_disabled_installs_no_hook_and_reads_no_clock(
+        gc_hook_from_zero, monkeypatch):
+    def no_clock():
+        raise AssertionError("a span read a clock with tracing disabled")
+
+    monkeypatch.setattr(tracing, "_perf", no_clock)
+    monkeypatch.setattr(tracing, "_cpu", no_clock)
+    e, handle = _device_count_engine({cfg.TRACE_ENABLE: "false"})
+    assert tracing._on_gc not in gc.callbacks and tracing._gc_holders == 0
+    _drive_ticks(e, 2)
+    assert handle.state == "RUNNING" and e.trace_recorders == {}
+    sink = e.broker.topic(handle.plan.physical_plan.topic)
+    assert sum(sink.end_offsets()) > 0
+    e.shutdown()
+    assert tracing._on_gc not in gc.callbacks
+
+
+def _spin(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def test_off_cpu_is_the_time_the_thread_did_not_run():
+    """A sleep is off the CPU, a busy loop is not; a declared wait keeps
+    its time to itself (through a span that reads no CPU clock too), any
+    other span hands it up to the tick."""
+    nap = 0.05
+
+    def body(tr):
+        with tracing.span("sleeps", cpu=True):
+            time.sleep(nap)
+        with tracing.span("spins", cpu=True):
+            _spin(nap)
+        with tracing.span("parent", cpu=True):
+            with tracing.span("between"):  # reads no CPU clock, books none
+                with tracing.span("waits", wait=True):
+                    time.sleep(nap)
+
+    stages = _traced_tick(body)
+    assert "off_cpu_ms" not in stages["between"]
+    nap_ms = nap * 1e3
+    assert stages["sleeps"]["off_cpu_ms"] >= 0.9 * nap_ms
+    assert stages["spins"]["off_cpu_ms"] <= 0.5 * nap_ms
+    assert stages["waits"]["off_cpu_ms"] >= 0.9 * nap_ms
+    # the wait handed nothing up: its parent was running the whole time it
+    # was not inside the wait, and the tick holds the one undeclared sleep
+    assert stages["parent"]["off_cpu_ms"] <= 0.5 * nap_ms
+    tick = stages["tick"]
+    assert 0.9 * nap_ms <= tick["off_cpu_ms"] <= tick["ms"] - 1.9 * nap_ms
+
+
+def test_a_spinning_second_thread_shows_as_time_off_the_cpu():
+    """The same CPU-bound work beside a thread that spins in Python: the
+    GIL is in the other thread's hands about half the time, and the span
+    says so."""
+    def work(tr):
+        with tracing.span("work", cpu=True):
+            x = 0
+            for i in range(1_500_000):
+                x += i
+
+    alone = _traced_tick(work)["work"]
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            pass
+
+    other = threading.Thread(target=spin, daemon=True)
+    other.start()
+    try:
+        beside = _traced_tick(work)["work"]
+    finally:
+        stop.set()
+        other.join(10.0)
+    assert not other.is_alive()
+    assert beside["off_cpu_ms"] > alone["off_cpu_ms"] + 0.2 * alone["ms"]
+
+
+def _emit_children_add_up(stats):
+    decode, read, rows = (stats[n] for n in ("emit.decode", "emit.read", "emit.rows"))
+    assert read["n"] == rows["n"] == decode["n"] > 0
+    assert decode["total_ms"] == pytest.approx(
+        read["total_ms"] + rows["total_ms"] + decode["self_ms"], abs=0.01)
+    assert decode["d2h_bytes"] > 0
+    assert "d2h_bytes" not in read and "d2h_bytes" not in rows
+    assert decode["rows"] > 0 and "rows" not in rows
+    for st in (decode, read, rows):
+        assert st["gc_ms"] >= 0.0
+    # the read is a declared wait; its parent reads no CPU clock
+    assert 0.0 <= read["off_cpu_ms"] <= read["total_ms"]
+    assert "off_cpu_ms" not in decode and "off_cpu_ms" not in rows
+
+
+def test_emit_decode_and_emit_dispatch_have_children():
+    e, handle = _device_count_engine()
+    _drive_ticks(e, 4)
+    stats = e.trace_recorder(handle.query_id).stage_stats()
+    _emit_children_add_up(stats)
+    dispatch, callbacks, sink = (
+        stats[n] for n in ("emit.dispatch", "emit.callbacks", "sink.produce"))
+    # the block path: one pass of the callbacks a block, and the sink's
+    # two stages a block (the encode, the append)
+    assert dispatch["block_rows"] == dispatch["rows"] > 0
+    assert callbacks["n"] == dispatch["n"]
+    assert 0.0 < sink["encode_ms"] < sink["total_ms"]
+    assert callbacks["total_ms"] + sink["total_ms"] <= dispatch["total_ms"] + 0.01
+    assert dispatch["total_ms"] == pytest.approx(
+        callbacks["total_ms"] + sink["total_ms"] + dispatch["self_ms"], abs=0.01)
+    # a subscriber keeps the per-emit loop: its callbacks are self time
+    handle.push_listeners.append(lambda emit: None)
+    _drive_ticks(e, 2)
+    after = e.trace_recorder(handle.query_id).stage_stats()
+    assert after["emit.dispatch"]["n"] > dispatch["n"]
+    assert after["emit.callbacks"]["n"] == callbacks["n"]
+    e.shutdown()
+
+
+def test_emit_decode_has_children_on_four_virtual_devices():
+    e = _engine({cfg.RUNTIME_BACKEND: "distributed", "ksql.device.shards": 4})
+    e.execute_sql(PV_DDL)
+    e.execute_sql(COUNT_CTAS)
+    (handle,) = e.queries.values()
+    assert handle.backend == "distributed", e.fallback_reasons
+    assert handle.executor.device.n_shards == 4
+    _drive_ticks(e, 3, rows=64)
+    _emit_children_add_up(e.trace_recorder(handle.query_id).stage_stats())
+    e.shutdown()
 
 
 # ------------------------------------------- tracing: push-registry spans
