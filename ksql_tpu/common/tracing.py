@@ -88,9 +88,14 @@ is never timed and reports no time):
                       64th batch, and off cadence when the load check finds
                       the store at 0.75: ``off_cadence``); the span holds
                       the enqueue, the device's part lands in the next wait
+``session.grow``      span: a SESSION step found a key with more live
+                      sessions than it gathers, doubled them and re-ran the
+                      batch (holds that re-run's dispatch and recompile,
+                      also booked as a ``device.compile`` ``jit_miss``)
 ``emit.decode``       span: the step's emits read back, load check, row
                       building (``d2h_bytes``; ``lanes``, the emit mask's
-                      length, and ``rows`` decoded from it); its self time
+                      length, ``rows`` decoded from it and ``tombstones``
+                      among them where the step retracts); its self time
                       is the load check, the join's counts, the members
 ``emit.read``           span, a declared wait: the emits cross to the host
                         (one ``jax.device_get``; on the mesh every shard's
@@ -107,6 +112,10 @@ is never timed and reports no time):
                     ``sliced_lanes``, the lanes of the batch a sliced
                     hopping step's fold and emission visited: its chunk's
                     lanes x the chunks that held a row;
+                    ``session_items``, the live items a SESSION step's
+                    merge worked (rows and the stored sessions gathered
+                    beside them), and ``session_lanes``, its static width
+                    n x (slots + 1);
                     the load scalars read there: ``occupancy``, slots taken,
                     and ``graves`` among them, the mesh's of its fullest
                     shard;
@@ -188,6 +197,7 @@ _STAGE_RANK = {
     "step.dispatch": 22,
     "step.wait": 23,
     "store.evict": 24,
+    "session.grow": 24,
     "emit.decode": 25,
     "emit.read": 26,
     "emit.rows": 27,

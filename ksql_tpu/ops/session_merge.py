@@ -18,17 +18,33 @@ before it; a stored session sits after the rows at or before its start
 and the stored sessions that start before it.  Dead items (padding rows,
 absent or expired sessions) never merge with anything — the caller gives
 them unique keys — so they take the positions past the live ones.
+
+The rows' own sort is the same network's problem at the served batch: one
+sort of 32,768 rows compiles in ~83 s (v5e compiler), and a batched sort
+costs what one of its total length does.  So past ``SORT_RUN`` rows the
+rows are sorted in runs of ``SORT_RUN`` by one sort inside a loop, compiled
+once, and the runs merged pairwise by binary search (``_sort_keys``): the
+step compiles in 28 s cold on the chip at 32,768 rows, 1 s from the cache.
+At that size, behind the served path (the benchmark's NEXmark q11 cell:
+~120k stored sessions at 2^21 slots), a step takes 210-240 ms of device time
+whatever the batch holds: the segment reductions and the store scatters run
+over all ``m`` items and lanes, live or not (``PERF.md``).
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 _I64_MAX = np.iinfo(np.int64).max
+#: most rows the step sorts in one piece (``_sort_keys``): the sort's
+#: compile time grows faster than its length — measured with the v5e
+#: compiler for these five operand words: 20 s at 16,384 rows, 83 s at
+#: 32,768, and a batched sort costs what one of its total length does
+SORT_RUN = 8192
 
 
 class RowOrder(NamedTuple):
@@ -42,19 +58,69 @@ class RowOrder(NamedTuple):
     first_occ: jnp.ndarray  # bool[n], ROW order: first live row of its key
 
 
+def _lex_less(a, b) -> jnp.ndarray:
+    """(key, ts, row) ``a`` before ``b``, element-wise."""
+    (ka, ta, ia), (kb, tb, ib) = a, b
+    return (ka < kb) | ((ka == kb) & ((ta < tb) | ((ta == tb) & (ia < ib))))
+
+
+def _sort_keys(keys: Tuple[jnp.ndarray, ...]) -> Tuple[jnp.ndarray, ...]:
+    """``keys`` = (key, ts, row index) sorted by all three (the index makes
+    every triple distinct).  Up to ``SORT_RUN`` items one sort does it;
+    beyond, the items are sorted in runs of ``SORT_RUN`` by one sort inside
+    a loop (compiled once), and the runs merged pairwise: an item's place
+    in a merged pair is its place in its own run plus the items of the
+    other run that precede it, found by binary search."""
+    n = keys[0].shape[0]
+    if n <= SORT_RUN:
+        return tuple(jax.lax.sort(keys, num_keys=3))
+    runs = 1 << (-(-n // SORT_RUN) - 1).bit_length()
+    size = runs * SORT_RUN
+    pad = size - n
+    if pad:  # padding sorts past every item: (MAX, MAX, index >= n)
+        keys = (
+            jnp.concatenate([keys[0], jnp.full(pad, _I64_MAX, keys[0].dtype)]),
+            jnp.concatenate([keys[1], jnp.full(pad, _I64_MAX, keys[1].dtype)]),
+            jnp.arange(size, dtype=jnp.int32).at[:n].set(keys[2]),
+        )
+    keys = jax.lax.map(
+        lambda run: jax.lax.sort(run, num_keys=3),
+        tuple(k.reshape(runs, SORT_RUN) for k in keys),
+    )
+    keys = tuple(k.reshape(-1) for k in keys)
+    pos = jnp.arange(size, dtype=jnp.int32)
+    length = SORT_RUN
+    while length < size:
+        own = pos % length
+        other = (pos // length ^ 1) * length  # the partner run's first item
+
+        def halve(_, bounds):
+            lo, hi = bounds
+            mid = jnp.minimum((lo + hi) // 2, length - 1)
+            before = _lex_less(tuple(k[other + mid] for k in keys), keys) & (lo < hi)
+            return jnp.where(before, mid + 1, lo), jnp.where(before | (lo >= hi), hi, mid)
+
+        count, _ = jax.lax.fori_loop(
+            0, length.bit_length(), halve,
+            (jnp.zeros(size, jnp.int32), jnp.full(size, length, jnp.int32)),
+        )
+        at = pos // (2 * length) * (2 * length) + own + count
+        keys = tuple(
+            jnp.zeros_like(k).at[at].set(k, unique_indices=True) for k in keys
+        )
+        length *= 2
+    return tuple(k[:n] for k in keys)
+
+
 def sort_rows(khash: jnp.ndarray, ts: jnp.ndarray, active: jnp.ndarray) -> RowOrder:
     n = khash.shape[0]
     # dead rows sort last: (MAX, MAX) can only tie with a live row whose
     # hash AND timestamp are both int64 max
-    ks, tss, rows = jax.lax.sort(
-        (
-            jnp.where(active, khash, _I64_MAX),
-            jnp.where(active, ts, _I64_MAX),
-            jnp.arange(n, dtype=jnp.int32),
-        ),
-        num_keys=2,
-        is_stable=True,
-    )
+    ks, tss, rows = _sort_keys((
+        jnp.where(active, khash, _I64_MAX),
+        jnp.where(active, ts, _I64_MAX),
+        jnp.arange(n, dtype=jnp.int32),
+    ))
     act = active[rows]
     new_key = jnp.concatenate([jnp.ones(1, bool), ks[1:] != ks[:-1]])
     group = jnp.cumsum(new_key.astype(jnp.int32)) - 1

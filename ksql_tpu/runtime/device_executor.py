@@ -475,6 +475,7 @@ class DeviceExecutor:
         records, self._raw = self._raw, []
         dev = self.device
         cap = dev.capacity
+        ts_field = self._native_fields["ts_field"]
         out: List[SinkEmit] = []
         tr = tracing.active()
         for s in range(0, len(records), cap):
@@ -495,6 +496,10 @@ class DeviceExecutor:
                     # offending records
                     data, valid, learned = {}, {}, []
                     row_ok = np.zeros(n, bool)
+                if ts_field in data:
+                    # a null or negative event time: the per-record
+                    # decoder skips the record (and logs a null one)
+                    row_ok = row_ok & valid[ts_field] & (data[ts_field] >= 0)
                 dev.dictionary.learn_pairs(learned)
                 if tr is not None:
                     sp.n = int(row_ok.sum())
@@ -509,7 +514,10 @@ class DeviceExecutor:
                         name: (d[i:j], valid[name][i:j])
                         for name, d in data.items()
                     }
-                    out.extend(self._native_segment(chunk[i:j], columns))
+                    out.extend(self._native_segment(
+                        chunk[i:j], columns,
+                        None if ts_field is None else data[ts_field][i:j],
+                    ))
                 else:
                     for r in chunk[i:j]:
                         ev = decode_source_record(
@@ -529,20 +537,25 @@ class DeviceExecutor:
                 i = j
         return out
 
-    def _native_segment(self, chunk, columns) -> List[SinkEmit]:
+    def _native_segment(self, chunk, columns, event_ts=None) -> List[SinkEmit]:
         """Device-step one contiguous run of natively decoded records.
         ``columns`` holds the segment's (data, valid) slices per parsed
         value field; key columns are decoded (vectorized when the key
-        shape allows) and merged here."""
+        shape allows) and merged here.  ``event_ts`` is the segment's
+        event times where the source takes them from a column (TIMESTAMP=),
+        else the records' own timestamps are."""
         from ksql_tpu.common.batch import encode_column
 
         dev = self.device
         n = len(chunk)
         with tracing.span("batch.assemble"):
             key_cols = list(self.source_step.schema.key_columns)
-            self.stream_time = max(
-                self.stream_time, max(r.timestamp for r in chunk)
-            )
+            if event_ts is None:
+                timestamps = [r.timestamp for r in chunk]
+                latest = max(timestamps)
+            else:
+                timestamps, latest = event_ts, int(event_ts.max())
+            self.stream_time = max(self.stream_time, latest)
             label = self._native_fields["format"]
             self.native_ingest_rows[label] = (
                 self.native_ingest_rows.get(label, 0) + n
@@ -568,7 +581,7 @@ class DeviceExecutor:
                     columns[c.name] = (kd, kok)
             arrays = self._native_arrays(
                 n, columns,
-                [r.timestamp for r in chunk],
+                timestamps,
                 [r.offset for r in chunk],
                 [r.partition for r in chunk],
             )
@@ -1201,8 +1214,14 @@ def native_ingest_fields(dev):
     vf = str(step.formats.value_format).upper()
     if vf not in ("JSON", "DELIMITED"):
         return None
-    if step.timestamp_column or getattr(step, "header_columns", ()):
+    if getattr(step, "header_columns", ()):
         return None
+    ts_field = step.timestamp_column
+    if ts_field is not None:
+        ts_col = step.schema.find_value_column(ts_field)
+        if step.timestamp_format or ts_col is None or ts_col.type.base != B.BIGINT:
+            # a formatted string timestamp, or one in the key: per record
+            return None
     try:
         from ksql_tpu import native
     except Exception:  # noqa: BLE001
@@ -1265,6 +1284,8 @@ def native_ingest_fields(dev):
         "fields": fields,
         "delimiter": delimiter,
         "format": vf,
+        # the BIGINT value column that is the record's event time (TIMESTAMP=)
+        "ts_field": ts_field,
     }
 
 

@@ -274,6 +274,14 @@ def _flat_cell(row: Any, col: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:
     return jnp.asarray(row, dtype) * cols + col.astype(dtype)
 
 
+def _session_work_of(emits: Dict[str, Any]) -> Dict[str, int]:
+    """A session step's ``session_items`` / ``session_lanes`` as
+    ``device.step`` counter fields; nothing for a step that has none."""
+    if "session_items" not in emits:
+        return {}
+    return {k: int(emits[k]) for k in ("session_items", "session_lanes")}
+
+
 def _sliced_lanes_of(emits: Dict[str, Any]) -> Dict[str, int]:
     """A sliced step's ``sliced_lanes`` as a ``device.step`` counter field
     (the mesh: its busiest shard's); nothing for a step that has none."""
@@ -3977,7 +3985,7 @@ class CompiledDeviceQuery:
         winner = boundary & seg_alive[seg]
         sess_ovf = jnp.sum(winner & (rank >= S))
         ins_act = winner & (rank < S)
-        state, ins_slots, _, _ = probe_insert(
+        state, ins_slots, probe_rounds, probe_lane_rounds = probe_insert(
             state, cap, kh, rank.astype(jnp.int64),
             [r[seg] for r in seg_reprs],
             jnp.zeros(m, jnp.int32), ins_act,
@@ -4062,6 +4070,12 @@ class CompiledDeviceQuery:
         emits["ord_a"] = jnp.concatenate([ord_row, ord_row])
         emits["ord_b"] = jnp.concatenate([start, jnp.full(m, big, jnp.int64)])
         emits["sess_ovf"] = sess_ovf
+        # the merge's own account of its work: live items (rows and the
+        # stored sessions gathered beside them) against its static width
+        emits["session_items"] = jnp.sum(alive, dtype=jnp.int32)
+        emits["session_lanes"] = jnp.int32(m)
+        emits["probe_rounds"] = probe_rounds
+        emits["probe_lane_rounds"] = probe_lane_rounds
         emits["occupancy"] = jnp.sum(state["occ"] | state["grave"])
         emits["graves"] = jnp.sum(state["grave"])
         emits["overflow"] = state["overflow"]
@@ -4602,7 +4616,7 @@ class CompiledDeviceQuery:
             if self.sliced:
                 self.ensure_ring_for(arrays["ts"], arrays["row_valid"])
             new_state, emits = self._step(self.state, arrays)
-            if not self.session and not self.suppress:
+            if not self.suppress:
                 # the read-back of _finish_step, queued behind the step: the
                 # copies run as soon as it ends, not when the host asks
                 for leaf in emits.values():
@@ -4613,10 +4627,12 @@ class CompiledDeviceQuery:
             if not overflowed:
                 break
             # more concurrent sessions per key than tracked slots: grow
-            # and re-run the batch (steps are undonated)
-            self._grow_sessions()
-            with tracing.span("step.dispatch"):
-                new_state, emits = self._step(self.state, arrays)
+            # and re-run the batch (steps are undonated); the span holds
+            # the recompile the re-run pays
+            with tracing.span("session.grow"):
+                self._grow_sessions()
+                with tracing.span("step.dispatch"):
+                    new_state, emits = self._step(self.state, arrays)
         self.state = new_state
         result: Optional[List[SinkEmit]] = None
         if self.suppress:
@@ -4817,6 +4833,7 @@ class CompiledDeviceQuery:
                 graves=int(emits["graves"]),
                 sampled=1,
                 **_sliced_lanes_of(emits),
+                **_session_work_of(emits),
             )
         headroom = self.capacity * self.expansion
         if self.pipeline:
@@ -4844,6 +4861,9 @@ class CompiledDeviceQuery:
         stay valid, only the gather loop bound changes — recompile."""
         self.session_slots *= factor
         self._step = jax.jit(self._trace_step)
+        # a fresh jit object holds as many entries as the one it replaces,
+        # so the executor's cache count cannot see this compile: book it
+        tracing.counter("device.compile", jit_miss=1)
 
     #: HBM admission budget enforced at store-growth time (bytes; 0 = no
     #: gate).  Wired by the engine from ksql.analysis.memory.budget.bytes,
@@ -4978,7 +4998,10 @@ class CompiledDeviceQuery:
         # how much of what was read back is rows: the mask's static length
         # against the emits it marks
         tracing.counter(
-            "emit.decode", lanes=int(mask.shape[0]), rows=int(idx.size)
+            "emit.decode", lanes=int(mask.shape[0]), rows=int(idx.size),
+            **({"tombstones": int(np.count_nonzero(
+                np.asarray(emits["tombstone"])[idx]))}
+               if "tombstone" in emits else {}),
         )
         if idx.size == 0:
             return []

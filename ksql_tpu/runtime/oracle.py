@@ -970,24 +970,29 @@ def _decode_source_record(
         tv = value_row.get(source_step.timestamp_column)
         if tv is None and source_step.timestamp_column in key_row:
             tv = key_row[source_step.timestamp_column]
-        if tv is not None:
-            if isinstance(tv, str) and source_step.timestamp_format:
-                from ksql_tpu.functions.udfs import _string_to_ts
+        if tv is None:
+            # no event time: skipped and logged (ksqlDB's
+            # LoggingTimestampExtractor hands Kafka Streams -1, which skips)
+            on_error("timestamp-extract", ValueError(
+                f"null timestamp column {source_step.timestamp_column}"))
+            return None
+        if isinstance(tv, str) and source_step.timestamp_format:
+            from ksql_tpu.functions.udfs import _string_to_ts
 
-                try:
-                    tv = _string_to_ts(tv, source_step.timestamp_format)
-                except Exception as e:
-                    on_error("timestamp-extract", e)
-                    return None
             try:
-                ts = int(tv)
-            except (TypeError, ValueError) as e:
+                tv = _string_to_ts(tv, source_step.timestamp_format)
+            except Exception as e:
                 on_error("timestamp-extract", e)
                 return None
-            if ts < 0:
-                # negative extracted timestamps drop the record
-                # (reference MetadataTimestampExtractor semantics)
-                return None
+        try:
+            ts = int(tv)
+        except (TypeError, ValueError) as e:
+            on_error("timestamp-extract", e)
+            return None
+        if ts < 0:
+            # negative extracted timestamps drop the record
+            # (reference MetadataTimestampExtractor semantics)
+            return None
     is_table = isinstance(source_step, (st.TableSource, st.WindowedTableSource))
     if record.key is None and schema.key_columns:
         if is_table:

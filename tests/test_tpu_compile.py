@@ -239,6 +239,31 @@ def test_config5_session_at_engine_defaults(one_chip):
              _on(one_chip, dev.layout.array_structs()))
 
 
+def test_q11_session_cell_step_at_its_shapes(one_chip, monkeypatch):
+    """The benchmark cell ``q11_sessions.backlog``'s step at its shapes
+    (32,768 lanes, 2^21 slots): its row sort runs in ``SORT_RUN``-row runs
+    inside a loop (one sort of 32,768 rows took 83 s here), so the step
+    compiles under the ceiling, and it counts the work its merge did."""
+    from ksql_tpu.runtime import lowering
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark/configs/nexmark_q11_sessions.json")) as f:
+        config = json.load(f)
+    monkeypatch.setattr(lowering, "_device_memory_bytes", lambda: HBM_BYTES)
+    dev = _lowered(config["statements"],
+                   capacity=config["engine_props"]["ksql.batch.capacity"],
+                   store_capacity=config["engine_props"]["ksql.state.slots"])
+    assert dev.session and (dev.capacity, dev.store_capacity) == (32_768, 1 << 21)
+    state = _state(dev, one_chip)
+    arrays = _on(one_chip, dev.layout.array_structs())
+    _compile(dev._step, state, arrays)
+    emits = jax.eval_shape(dev._trace_step, state, arrays)[1]
+    assert emits["emit_mask"].shape == (2 * 5 * 32_768,)
+    for name in ("session_items", "session_lanes"):
+        assert emits[name].shape == () and emits[name].dtype == np.int32, name
+    assert emits["probe_rounds"].shape == emits["probe_lane_rounds"].shape == ()
+
+
 def test_fused_tap_kernel_sixteen_lanes(one_chip):
     from ksql_tpu.server import tap_kernel
     from ksql_tpu.server.rest import PushQuerySession

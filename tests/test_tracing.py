@@ -924,10 +924,26 @@ def _spin(seconds):
         pass
 
 
+def _on_a_quiet_turn(check, tries=3):
+    """``check()`` measures and asserts; a machine shared with other work
+    may take the CPU from a busy loop at any moment, so the property has to
+    show on one of ``tries`` measurements, not on the first."""
+    for attempt in range(tries):
+        try:
+            return check()
+        except AssertionError:
+            if attempt == tries - 1:
+                raise
+
+
 def test_off_cpu_is_the_time_the_thread_did_not_run():
     """A sleep is off the CPU, a busy loop is not; a declared wait keeps
     its time to itself (through a span that reads no CPU clock too), any
     other span hands it up to the tick."""
+    _on_a_quiet_turn(_off_cpu_is_the_time_the_thread_did_not_run)
+
+
+def _off_cpu_is_the_time_the_thread_did_not_run():
     nap = 0.05
 
     def body(tr):
@@ -957,6 +973,10 @@ def test_a_spinning_second_thread_shows_as_time_off_the_cpu():
     """The same CPU-bound work beside a thread that spins in Python: the
     GIL is in the other thread's hands about half the time, and the span
     says so."""
+    _on_a_quiet_turn(_a_spinning_second_thread_shows_as_time_off_the_cpu)
+
+
+def _a_spinning_second_thread_shows_as_time_off_the_cpu():
     def work(tr):
         with tracing.span("work", cpu=True):
             x = 0
