@@ -114,8 +114,11 @@ is never timed and reports no time):
                     lanes x the chunks that held a row;
                     ``session_items``, the live items a SESSION step's
                     merge worked (rows and the stored sessions gathered
-                    beside them), and ``session_lanes``, its static width
-                    n x (slots + 1);
+                    beside them), ``session_lanes``, its static width
+                    n x (slots + 1), and ``session_work_lanes``, the width
+                    its per-item work ran at: ``WORK_LANES`` where the live
+                    items fitted it, n x (slots + 1) where they did not
+                    (the three summed over the mesh's shards);
                     the load scalars read there: ``occupancy``, slots taken,
                     and ``graves`` among them, the mesh's of its fullest
                     shard;

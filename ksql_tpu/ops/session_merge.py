@@ -25,10 +25,18 @@ costs what one of its total length does.  So past ``SORT_RUN`` rows the
 rows are sorted in runs of ``SORT_RUN`` by one sort inside a loop, compiled
 once, and the runs merged pairwise by binary search (``_sort_keys``): the
 step compiles in 28 s cold on the chip at 32,768 rows, 1 s from the cache.
-At that size, behind the served path (the benchmark's NEXmark q11 cell:
-~120k stored sessions at 2^21 slots), a step takes 210-240 ms of device time
-whatever the batch holds: the segment reductions and the store scatters run
-over all ``m`` items and lanes, live or not (``PERF.md``).
+
+Because the order puts the live items first, everything after it — the
+item gathers, the segmented merge, the store rewrite and the emission — can
+run over a prefix of the merged order.  A gather, a scatter or a segment
+reduction costs per index on the chip, and at 32,768 rows the merge's
+``m`` = 163,840 items took 210-240 ms of device time whatever the batch held
+(the benchmark's NEXmark q11 cell: ~4,700 live items in a served tick of
+4,096 bids; ``PERF.md``).  So the step runs that work over the first
+``WORK_LANES`` merged positions when its live items fit them, and over all
+``m`` when they do not: the same result either way, since a dead item never
+merges, writes the store or emits.  What stays ``n`` wide or wider is the
+row sort, the lookups of the stored sessions and this module's order.
 """
 
 from __future__ import annotations
@@ -45,6 +53,11 @@ _I64_MAX = np.iinfo(np.int64).max
 #: compiler for these five operand words: 20 s at 16,384 rows, 83 s at
 #: 32,768, and a batched sort costs what one of its total length does
 SORT_RUN = 8192
+#: merged positions the session step's per-item work runs over when the
+#: live items fit them (the whole ``m`` items otherwise; a step whose ``m``
+#: is no wider runs at ``m``): a served tick of 4,096 bids holds ~4,700
+#: live items, a fill batch of 32,768 rows ~33-37k
+WORK_LANES = 8192
 
 
 class RowOrder(NamedTuple):

@@ -29,6 +29,7 @@ verdict column (the oracle's retraction semantics).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -275,11 +276,15 @@ def _flat_cell(row: Any, col: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:
 
 
 def _session_work_of(emits: Dict[str, Any]) -> Dict[str, int]:
-    """A session step's ``session_items`` / ``session_lanes`` as
-    ``device.step`` counter fields; nothing for a step that has none."""
+    """A session step's ``session_items`` / ``session_lanes`` /
+    ``session_work_lanes`` as ``device.step`` counter fields (the mesh:
+    summed over its shards); nothing for a step that has none."""
     if "session_items" not in emits:
         return {}
-    return {k: int(emits[k]) for k in ("session_items", "session_lanes")}
+    return {
+        k: int(np.asarray(emits[k]).sum())
+        for k in ("session_items", "session_lanes", "session_work_lanes")
+    }
 
 
 def _sliced_lanes_of(emits: Dict[str, Any]) -> Dict[str, int]:
@@ -3892,190 +3897,231 @@ class CompiledDeviceQuery:
             row_order, active,
             jnp.stack(it_alive[1:]), jnp.stack(it_start[1:]),
         )
-        kh, start, end = kh[orderm], start[orderm], end[orderm]
-        alive, isrow, slot = alive[orderm], isrow[orderm], slot[orderm]
-        rowidx = rowidx[orderm]
-        reprs_m = [r[orderm] for r in reprs_m]
-        comps_m = [cm[orderm] for cm in comps_m]
+        n_alive = jnp.sum(alive, dtype=jnp.int32)
 
-        def seg_combine(a, b):
-            ka, ea = a
-            kb, eb = b
-            return kb, jnp.where(ka == kb, jnp.maximum(ea, eb), eb)
+        @jax.named_scope("merge_items")
+        def merge(width: int, state: Dict[str, jnp.ndarray]):
+            """The merge, the store rewrite and the emission over the first
+            ``width`` merged positions: every live item when ``n_alive <=
+            width`` (the order puts them first; a dead item never merges,
+            writes or emits).  The emits keep their ``2m`` lanes either way:
+            part A at ``[0, width)``, part B at ``[m, m + width)``."""
+            o = orderm[:width]
+            kh_w, start_w, end_w = kh[o], start[o], end[o]
+            alive_w, isrow_w, slot_w = alive[o], isrow[o], slot[o]
+            rowidx_w = rowidx[o]
+            reprs_w = [r[o] for r in reprs_m]
+            comps_w = [c[o] for c in comps_m]
 
-        _, segend = jax.lax.associative_scan(seg_combine, (kh, end))
-        prev_kh = jnp.concatenate([jnp.full(1, -1, jnp.int64), kh[:-1]])
-        prev_segend = jnp.concatenate([jnp.full(1, neg, jnp.int64), segend[:-1]])
-        boundary = (kh != prev_kh) | (start > prev_segend + gap)
-        seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+            def seg_combine(a, b):
+                ka, ea = a
+                kb, eb = b
+                return kb, jnp.where(ka == kb, jnp.maximum(ea, eb), eb)
 
-        seg_start = jax.ops.segment_min(start, seg, num_segments=m)
-        seg_end = jax.ops.segment_max(end, seg, num_segments=m)
-        seg_alive = (
-            jax.ops.segment_max(alive.astype(jnp.int32), seg, num_segments=m) > 0
-        )
-        seg_has_row = (
-            jax.ops.segment_max(
-                (isrow & alive).astype(jnp.int32), seg, num_segments=m
+            _, segend = jax.lax.associative_scan(seg_combine, (kh_w, end_w))
+            prev_kh = jnp.concatenate([jnp.full(1, -1, jnp.int64), kh_w[:-1]])
+            prev_segend = jnp.concatenate(
+                [jnp.full(1, neg, jnp.int64), segend[:-1]]
+            )
+            boundary = (kh_w != prev_kh) | (start_w > prev_segend + gap)
+            seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+
+            def seg_reduce(reduce, v):
+                return reduce(v, seg, num_segments=width)
+
+            seg_start = seg_reduce(jax.ops.segment_min, start_w)
+            seg_end = seg_reduce(jax.ops.segment_max, end_w)
+            seg_alive = seg_reduce(
+                jax.ops.segment_max, alive_w.astype(jnp.int32)
             ) > 0
-        )
-        seg_kh = jax.ops.segment_max(jnp.where(alive, kh, neg), seg, num_segments=m)
-        big = np.iinfo(np.int64).max
-        seg_minrow = jax.ops.segment_min(
-            jnp.where(isrow & alive, rowidx, big), seg, num_segments=m
-        )
-        seg_reprs = [
-            jax.ops.segment_max(jnp.where(alive, r, neg), seg, num_segments=m)
-            for r in reprs_m
-        ]
-        seg_comps = []
-        comp_list = list(self.store_layout.components)
-        last_order_j = 0
-        for j, comp in enumerate(comp_list):
-            v = comps_m[j]
-            fill = jnp.asarray(comp.init, v.dtype)
-            v = jnp.where(alive, v, fill)
-            if comp.combine == "add":
-                seg_comps.append(jax.ops.segment_sum(v, seg, num_segments=m))
-                last_order_j = j
-            elif comp.combine == "min":
-                seg_comps.append(jax.ops.segment_min(v, seg, num_segments=m))
-                last_order_j = j
-            elif comp.combine == "max":
-                seg_comps.append(jax.ops.segment_max(v, seg, num_segments=m))
-                last_order_j = j
-            else:  # argset: payload of the preceding order component's winner
-                order_vals = jnp.where(
-                    alive,
-                    comps_m[last_order_j],
-                    jnp.asarray(
-                        comp_list[last_order_j].init,
-                        comps_m[last_order_j].dtype,
-                    ),
-                )
-                winner = alive & (
-                    order_vals == seg_comps[last_order_j][seg]
-                ) & (
-                    order_vals
-                    != jnp.asarray(
-                        comp_list[last_order_j].init, order_vals.dtype
+            seg_has_row = seg_reduce(
+                jax.ops.segment_max, (isrow_w & alive_w).astype(jnp.int32)
+            ) > 0
+            big = np.iinfo(np.int64).max
+            seg_minrow = seg_reduce(
+                jax.ops.segment_min, jnp.where(isrow_w & alive_w, rowidx_w, big)
+            )
+            seg_reprs = [
+                seg_reduce(jax.ops.segment_max, jnp.where(alive_w, r, neg))
+                for r in reprs_w
+            ]
+            seg_comps = []
+            comp_list = list(self.store_layout.components)
+            last_order_j = 0
+            for j, comp in enumerate(comp_list):
+                v = comps_w[j]
+                fill = jnp.asarray(comp.init, v.dtype)
+                v = jnp.where(alive_w, v, fill)
+                if comp.combine == "add":
+                    seg_comps.append(seg_reduce(jax.ops.segment_sum, v))
+                    last_order_j = j
+                elif comp.combine == "min":
+                    seg_comps.append(seg_reduce(jax.ops.segment_min, v))
+                    last_order_j = j
+                elif comp.combine == "max":
+                    seg_comps.append(seg_reduce(jax.ops.segment_max, v))
+                    last_order_j = j
+                else:  # argset: payload of the preceding order component's winner
+                    order_vals = jnp.where(
+                        alive_w,
+                        comps_w[last_order_j],
+                        jnp.asarray(
+                            comp_list[last_order_j].init,
+                            comps_w[last_order_j].dtype,
+                        ),
                     )
-                )
-                seg_comps.append(
-                    jax.ops.segment_sum(
+                    winner = alive_w & (
+                        order_vals == seg_comps[last_order_j][seg]
+                    ) & (
+                        order_vals
+                        != jnp.asarray(
+                            comp_list[last_order_j].init, order_vals.dtype
+                        )
+                    )
+                    seg_comps.append(seg_reduce(
+                        jax.ops.segment_sum,
                         jnp.where(winner, v, jnp.zeros_like(v)),
-                        seg,
-                        num_segments=m,
-                    )
-                )
+                    ))
 
-        # ---- rewrite the store: drop every gathered session, re-insert the
-        # merged session set (fresh slot indices 0..count-1 per key)
-        state = dict(state)
-        del_mask = ~isrow & alive
-        tgt_del = jnp.where(del_mask, slot, jnp.int32(cap))
-        occ = state["occ"].at[tgt_del].set(False).at[cap].set(False)
-        grave = state["grave"].at[tgt_del].set(True).at[cap].set(False)
-        state["occ"], state["grave"] = occ, grave
-        # rank of each segment within its key (new slot index)
-        key_boundary = kh != prev_kh
-        key_id = jnp.cumsum(key_boundary.astype(jnp.int32)) - 1
-        key_first_seg = jax.ops.segment_min(seg, key_id, num_segments=m)
-        rank = seg - key_first_seg[key_id]  # per item; valid at boundaries
-        winner = boundary & seg_alive[seg]
-        sess_ovf = jnp.sum(winner & (rank >= S))
-        ins_act = winner & (rank < S)
-        state, ins_slots, probe_rounds, probe_lane_rounds = probe_insert(
-            state, cap, kh, rank.astype(jnp.int64),
-            [r[seg] for r in seg_reprs],
-            jnp.zeros(m, jnp.int32), ins_act,
-        )
-        tgt_ins = jnp.where(ins_act, ins_slots, jnp.int32(cap))
-        state["sess_start"] = state["sess_start"].at[tgt_ins].set(seg_start[seg])
-        state["sess_end"] = state["sess_end"].at[tgt_ins].set(seg_end[seg])
-        for j in range(ncomp):
-            col = state[f"a{j}"]
-            state[f"a{j}"] = col.at[tgt_ins].set(seg_comps[j][seg].astype(col.dtype))
-        state["dirty"] = state["dirty"].at[tgt_ins].set(True)
-        state["dirty"] = state["dirty"].at[cap].set(False)
+            # ---- rewrite the store: drop every gathered session, re-insert
+            # the merged session set (fresh slot indices 0..count-1 per key)
+            store = dict(state)
+            del_mask = ~isrow_w & alive_w
+            tgt_del = jnp.where(del_mask, slot_w, jnp.int32(cap))
+            store["occ"] = (
+                store["occ"].at[tgt_del].set(False).at[cap].set(False)
+            )
+            store["grave"] = (
+                store["grave"].at[tgt_del].set(True).at[cap].set(False)
+            )
+            # rank of each segment within its key (new slot index)
+            key_boundary = kh_w != prev_kh
+            key_id = jnp.cumsum(key_boundary.astype(jnp.int32)) - 1
+            key_first_seg = jax.ops.segment_min(seg, key_id, num_segments=width)
+            rank = seg - key_first_seg[key_id]  # per item; valid at boundaries
+            winner = boundary & seg_alive[seg]
+            sess_ovf = jnp.sum(winner & (rank >= S))
+            ins_act = winner & (rank < S)
+            store, ins_slots, probe_rounds, probe_lane_rounds = probe_insert(
+                store, cap, kh_w, rank.astype(jnp.int64),
+                [r[seg] for r in seg_reprs],
+                jnp.zeros(width, jnp.int32), ins_act,
+            )
+            tgt_ins = jnp.where(ins_act, ins_slots, jnp.int32(cap))
+            store["sess_start"] = (
+                store["sess_start"].at[tgt_ins].set(seg_start[seg])
+            )
+            store["sess_end"] = store["sess_end"].at[tgt_ins].set(seg_end[seg])
+            for j in range(ncomp):
+                col = store[f"a{j}"]
+                store[f"a{j}"] = col.at[tgt_ins].set(
+                    seg_comps[j][seg].astype(col.dtype)
+                )
+            store["dirty"] = store["dirty"].at[tgt_ins].set(True)
+            store["dirty"] = store["dirty"].at[cap].set(False)
+
+            # ---- emissions: tombstones for touched stored sessions (part
+            # A, per item), merged aggregates per row-containing segment
+            # (part B, at boundary items)
+            tomb = del_mask & seg_has_row[seg]
+            emit_seg = winner & seg_has_row[seg]
+            nn = 2 * width
+            out_env: Dict[str, DCol] = {}
+            for k, colk in enumerate(self.agg.schema.key_columns):
+                data_a = self._decode_key64(reprs_w[k], colk.type)
+                data_b = self._decode_key64(seg_reprs[k][seg], colk.type)
+                out_env[colk.name] = DCol(
+                    jnp.concatenate([data_a, data_b]),
+                    jnp.concatenate([tomb, emit_seg]),
+                    colk.type,
+                )
+            comp_idx = 1
+            row_ts_a = comps_w[0]
+            row_ts_b = seg_comps[0][seg]
+            for spec in self.agg_specs:
+                nc = len(spec.device.components)
+                ca = [comps_w[comp_idx + j] for j in range(nc)]
+                cb = [seg_comps[comp_idx + j][seg] for j in range(nc)]
+                da, va = spec.device.finalize(ca)
+                db, vb = spec.device.finalize(cb)
+                out_env[spec.out_name] = DCol(
+                    jnp.concatenate([da, db]),
+                    jnp.concatenate([va & tomb, vb & emit_seg]),
+                    spec.device.result_type,
+                )
+                comp_idx += nc
+            out_ts = jnp.concatenate([row_ts_a, row_ts_b])
+            ones = jnp.ones(nn, bool)
+            out_env["ROWTIME"] = DCol(out_ts, ones, T.BIGINT)
+            out_env["WINDOWSTART"] = DCol(
+                jnp.concatenate([start_w, seg_start[seg]]), ones, T.BIGINT
+            )
+            out_env["WINDOWEND"] = DCol(
+                jnp.concatenate([end_w, seg_end[seg]]), ones, T.BIGINT
+            )
+            mask = jnp.concatenate([tomb, emit_seg])
+            # post-agg projections (HAVING rejected upstream for sessions)
+            for op in self.post_ops:
+                c2 = JaxExprCompiler(out_env, nn, self.dictionary)
+                if isinstance(op, st.TableSelect):
+                    new_env: Dict[str, DCol] = {}
+                    src_keys = [k2.name for k2 in op.source.schema.key_columns]
+                    out_keys = [k2.name for k2 in op.schema.key_columns]
+                    for nname, oname in zip(out_keys, src_keys):
+                        if oname in out_env:
+                            new_env[nname] = out_env[oname]
+                    for name, e in op.selects:
+                        new_env[name] = c2.compile(e)
+                    for p in ("ROWTIME", "WINDOWSTART", "WINDOWEND"):
+                        new_env[p] = out_env[p]
+                    out_env = new_env
+                else:
+                    raise DeviceUnsupported(f"{type(op).__name__} over SESSION")
+            emits = self._pack_emits(out_env, mask, out_ts)
+            # per-record oracle order: a record's tombstones (by session
+            # start), then its merged session
+            ord_row = jnp.where(seg_minrow[seg] == big, 0, seg_minrow[seg])
+            emits["ord_a"] = jnp.concatenate([ord_row, ord_row])
+            emits["ord_b"] = jnp.concatenate(
+                [start_w, jnp.full(width, big, jnp.int64)]
+            )
+            if width < m:  # parts A and B back to their lanes of 2m
+                emits = {
+                    name: jnp.zeros((2 * m,) + v.shape[1:], v.dtype)
+                    .at[:width].set(v[:width]).at[m:m + width].set(v[width:])
+                    for name, v in emits.items()
+                }
+            return store, {
+                **emits,
+                "sess_ovf": sess_ovf,
+                "session_work_lanes": jnp.int32(width),
+                "probe_rounds": probe_rounds,
+                "probe_lane_rounds": probe_lane_rounds,
+            }
+
+        # the merge's per-item work at a narrow static width whenever the
+        # live items fit it, else at the whole width: one result either way
+        width = min(session_merge.WORK_LANES, m)
+        if width < m:
+            state, emits = jax.lax.cond(
+                n_alive <= width,
+                functools.partial(merge, width), functools.partial(merge, m),
+                state,
+            )
+        else:
+            state, emits = merge(m, state)
         batch_max = jnp.max(jnp.where(active, ts, neg))
         state["max_ts"] = jnp.maximum(state["max_ts"], batch_max)
         if self._needs_seq:
             state["agg_seq"] = state["agg_seq"] + n
-
-        # ---- emissions: tombstones for touched stored sessions (part A,
-        # per item), merged aggregates per row-containing segment (part B,
-        # at boundary items)
-        tomb = del_mask & seg_has_row[seg]
-        emit_seg = winner & seg_has_row[seg]
-        nn = 2 * m
-        out_env: Dict[str, DCol] = {}
-        for k, colk in enumerate(self.agg.schema.key_columns):
-            data_a = self._decode_key64(reprs_m[k], colk.type)
-            data_b = self._decode_key64(seg_reprs[k][seg], colk.type)
-            out_env[colk.name] = DCol(
-                jnp.concatenate([data_a, data_b]),
-                jnp.concatenate([tomb, emit_seg]),
-                colk.type,
-            )
-        comp_idx = 1
-        row_ts_a = comps_m[0]
-        row_ts_b = seg_comps[0][seg]
-        for spec in self.agg_specs:
-            nc = len(spec.device.components)
-            ca = [comps_m[comp_idx + j] for j in range(nc)]
-            cb = [seg_comps[comp_idx + j][seg] for j in range(nc)]
-            da, va = spec.device.finalize(ca)
-            db, vb = spec.device.finalize(cb)
-            out_env[spec.out_name] = DCol(
-                jnp.concatenate([da, db]),
-                jnp.concatenate([va & tomb, vb & emit_seg]),
-                spec.device.result_type,
-            )
-            comp_idx += nc
-        out_ts = jnp.concatenate([row_ts_a, row_ts_b])
-        ones = jnp.ones(nn, bool)
-        out_env["ROWTIME"] = DCol(out_ts, ones, T.BIGINT)
-        out_env["WINDOWSTART"] = DCol(
-            jnp.concatenate([start, seg_start[seg]]), ones, T.BIGINT
-        )
-        out_env["WINDOWEND"] = DCol(
-            jnp.concatenate([end, seg_end[seg]]), ones, T.BIGINT
-        )
-        mask = jnp.concatenate([tomb, emit_seg])
-        # post-agg projections (HAVING rejected upstream for sessions)
-        for op in self.post_ops:
-            c2 = JaxExprCompiler(out_env, nn, self.dictionary)
-            if isinstance(op, st.TableSelect):
-                new_env: Dict[str, DCol] = {}
-                src_keys = [k2.name for k2 in op.source.schema.key_columns]
-                out_keys = [k2.name for k2 in op.schema.key_columns]
-                for nname, oname in zip(out_keys, src_keys):
-                    if oname in out_env:
-                        new_env[nname] = out_env[oname]
-                for name, e in op.selects:
-                    new_env[name] = c2.compile(e)
-                for p in ("ROWTIME", "WINDOWSTART", "WINDOWEND"):
-                    new_env[p] = out_env[p]
-                out_env = new_env
-            else:
-                raise DeviceUnsupported(f"{type(op).__name__} over SESSION")
-        emits = self._pack_emits(out_env, mask, out_ts)
         emits["tombstone"] = jnp.concatenate(
             [jnp.ones(m, bool), jnp.zeros(m, bool)]
         )
-        # per-record oracle order: a record's tombstones (by session start),
-        # then its merged session
-        ord_row = jnp.where(seg_minrow[seg] == big, 0, seg_minrow[seg])
-        emits["ord_a"] = jnp.concatenate([ord_row, ord_row])
-        emits["ord_b"] = jnp.concatenate([start, jnp.full(m, big, jnp.int64)])
-        emits["sess_ovf"] = sess_ovf
         # the merge's own account of its work: live items (rows and the
-        # stored sessions gathered beside them) against its static width
-        emits["session_items"] = jnp.sum(alive, dtype=jnp.int32)
+        # stored sessions gathered beside them) against its static width,
+        # and the width its per-item work ran at
+        emits["session_items"] = n_alive
         emits["session_lanes"] = jnp.int32(m)
-        emits["probe_rounds"] = probe_rounds
-        emits["probe_lane_rounds"] = probe_lane_rounds
         emits["occupancy"] = jnp.sum(state["occ"] | state["grave"])
         emits["graves"] = jnp.sum(state["grave"])
         emits["overflow"] = state["overflow"]

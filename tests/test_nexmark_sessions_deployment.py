@@ -6,8 +6,9 @@ on the CPU at the configuration's ``rehearse`` engine properties, through
 the reference's sessions, the controls read not correct, a held-back bid
 bridges two stored sessions, the hot bidder moves between batches, a key
 with more sessions than the step gathers grows them and re-runs the batch
-to the same sink, the step's counters equal what the reference counts, and
-the row sort in runs equals one sort.
+to the same sink, the step's counters equal what the reference counts, the
+merge over the live prefix of its items equals the merge over all of them,
+and the row sort in runs equals one sort.
 """
 
 from __future__ import annotations
@@ -279,30 +280,155 @@ def test_more_sessions_than_slots_grow_and_rerun_the_batch_to_the_same_sink():
     assert got == want
 
 
-def test_step_counters_equal_what_the_reference_counts(served):
-    """``device.step`` ``session_items`` = a step's rows plus the stored
-    sessions it gathered (the reference's sessions of each bidder of the
-    batch, before it), ``session_lanes`` its static width, and
-    ``emit.decode`` ``tombstones`` the retractions on the sink."""
-    corpus = served.corpus
+def _items_per_step(corpus) -> list:
+    """Each step's live items as the reference counts them: its rows plus
+    the sessions of each bidder of the batch before it."""
     seen: dict = {}
-    items = 0
+    per_step = []
     for lo in range(0, N_EVENTS, CAPACITY):
         bidders = set(corpus.bidder[lo:lo + CAPACITY].tolist())
-        items += min(CAPACITY, N_EVENTS - lo) + sum(seen.get(b, 0) for b in bidders)
+        per_step.append(
+            min(CAPACITY, N_EVENTS - lo) + sum(seen.get(b, 0) for b in bidders))
         batch_sessions = dep.reference(corpus, min(lo + CAPACITY, N_EVENTS))
         seen = {}
         for b, _s, _e in batch_sessions:
             seen[b] = seen.get(b, 0) + 1
-    step = served.stages["device.step"]
+    return per_step
+
+
+def _check_step_counters(stages, records, per_step, work_of) -> None:
+    step = stages["device.step"]
     steps = N_EVENTS // CAPACITY
     assert step["sampled"] == steps
-    assert step["session_items"] == items
+    assert step["session_items"] == sum(per_step)
     assert step["session_lanes"] == steps * CAPACITY * 5
+    assert step["session_work_lanes"] == sum(work_of(items) for items in per_step)
     assert step["probe_rounds"] > 0 and step["probe_lane_rounds"] > 0
-    assert served.stages["emit.decode"]["tombstones"] == sum(
-        1 for _k, _w, v in served.records if v is None) > 0
-    assert "session.grow" not in served.stages
+    assert stages["emit.decode"]["tombstones"] == sum(
+        1 for _k, _w, v in records if v is None) > 0
+    assert "session.grow" not in stages
+
+
+def test_step_counters_equal_what_the_reference_counts(served):
+    """``device.step`` ``session_items`` = a step's rows plus the stored
+    sessions it gathered (the reference's sessions of each bidder of the
+    batch, before it), ``session_lanes`` its static width,
+    ``session_work_lanes`` the width its merge's per-item work ran at (the
+    static width here: ``WORK_LANES`` is wider than these steps), and
+    ``emit.decode`` ``tombstones`` the retractions on the sink."""
+    _check_step_counters(served.stages, served.records,
+                         _items_per_step(served.corpus), lambda items: CAPACITY * 5)
+
+
+def test_step_counters_book_the_narrow_width_where_the_items_fit(served, monkeypatch):
+    """At a ``WORK_LANES`` some of the corpus's steps fit and some do not:
+    ``session_work_lanes`` books ``WORK_LANES`` for each step whose live
+    items (as the reference counts them) fit it and the static width for
+    the others, and the sink is the one the whole width leaves."""
+    monkeypatch.setattr(session_merge, "WORK_LANES", 284)
+    run = Served()
+    try:
+        run.feed(served.corpus.payloads, served.corpus.ts)
+        stages, records = run.stages(), run.records()
+    finally:
+        run.stop()
+    assert records == served.records
+    _check_step_counters(stages, records, _items_per_step(served.corpus),
+                         lambda items: 284 if items <= 284 else CAPACITY * 5)
+
+
+def _feeds_of(case: str):
+    """The feeds of a case of ``test_the_narrow_merge_equals_the_whole_one``,
+    each produced and drained before the next."""
+    if case == "fits":  # 40 bids, then 35 that extend, join or trail them
+        return [[_bid(7000 + i % 20, T0 + 100 * i) for i in range(40)],
+                [_bid(7000 + i % 20, T0 + 4_000 + 100 * i) for i in range(30)]
+                + [_bid(7000 + i, T0 - 1_000 - i) for i in range(5)]]
+    if case == "overflows":  # full batches of distinct bidders
+        return [[_bid(8000 + i, T0 + i) for i in range(CAPACITY)],
+                [_bid(8000 + i, T0 + 500 + i) for i in range(CAPACITY)]]
+    if case == "bridge":  # a held-back bid between two stored sessions
+        return [[_bid(42, T0), _bid(42, T0 + 15_000)], [_bid(42, T0 + 7_500)]]
+    if case == "expired":  # the stored sessions left the 24 h grace behind
+        later = T0 + 25 * 3_600_000
+        return [[_bid(9000 + i, T0 + 10 * i) for i in range(30)],
+                [_bid(9000 + i, later + 10 * i) for i in range(30)]
+                + [_bid(9000, T0 + 5)]]
+    if case == "grow":  # six sessions of one bidder: the step grows to 8
+        first = [_bid(9, T0 + 11_000 * i) for i in range(6)] + [
+            _bid(100 + i, T0) for i in range(CAPACITY - 6)]
+        return [first, [_bid(9, T0 + 55_001)] + [
+            _bid(100 + i, T0 + 1) for i in range(CAPACITY - 1)]]
+    corpus = dep.make_corpus(4_294_967_311, SIZES, N_EVENTS)
+    return [corpus.payloads]
+
+
+def _store_of(executor) -> dict:
+    """The device store's arrays without the dump slot (the last: each
+    scatter sends a lane that writes nothing there), and its scalars."""
+    dev = executor.device
+    cap = getattr(dev, "c", dev).store_capacity
+    return {name: (a[..., :-1] if a.ndim and a.shape[-1] == cap + 1 else a)
+            for name, a in ((k, np.asarray(v)) for k, v in dev.state.items())}
+
+
+def _serve_at(work: int, feeds, props, monkeypatch):
+    """(sink records, store, ``device.step`` stage) of one served run whose
+    session step is traced with ``WORK_LANES`` = ``work``."""
+    monkeypatch.setattr(session_merge, "WORK_LANES", work)
+    run = Served(props)
+    try:
+        for payloads in feeds:
+            run.feed(payloads)
+        return run.records(), _store_of(run.ex), run.stages()["device.step"]
+    finally:
+        run.stop()
+
+
+MESH = {"ksql.runtime.backend": "distributed", "ksql.device.shards": 4}
+
+
+@pytest.mark.parametrize("case, work, backend, narrow", [
+    ("fits", 64, "device", "all"),
+    ("overflows", 32, "device", "none"),
+    ("bridge", 4, "device", "all"),
+    ("expired", 64, "device", "all"),
+    ("grow", CAPACITY, "device", "some"),
+    ("corpus", 284, "device", "some"),
+    ("fits", 64, "mesh", "all"),
+    ("overflows", 32, "mesh", "none"),
+    ("bridge", 4, "mesh", "all"),
+    ("expired", 64, "mesh", "all"),
+    ("grow", CAPACITY, "mesh", "all"),
+    ("corpus", 72, "mesh", "some"),
+])
+def test_the_narrow_merge_equals_the_whole_one(case, work, backend, narrow, monkeypatch):
+    """The session step's merge, store rewrite and emission over the first
+    ``WORK_LANES`` merged positions when the live items fit them, and over
+    all ``n x (S + 1)`` when they do not, leave the sink records (in order,
+    tombstones too) and the store that the whole width alone leaves; on
+    one device and on the four-shard mesh (each shard takes its own
+    branch).  ``session_work_lanes`` books ``WORK_LANES`` for a step that
+    fit and the whole width for one that did not (the mesh: summed over
+    its shards)."""
+    feeds = _feeds_of(case)
+    props = MESH if backend == "mesh" else {}
+    whole = _serve_at(1 << 30, feeds, props, monkeypatch)
+    records, store, step = _serve_at(work, feeds, props, monkeypatch)
+    assert records == whole[0] and records
+    assert store.keys() == whole[1].keys()
+    for name, a in store.items():
+        assert np.array_equal(a, whole[1][name]), name
+    assert whole[2]["session_work_lanes"] == whole[2]["session_lanes"]
+    assert step["session_items"] == whole[2]["session_items"]
+    assert step["session_lanes"] == whole[2]["session_lanes"]
+    units = step["sampled"] * (4 if backend == "mesh" else 1)
+    booked = {
+        "all": step["session_work_lanes"] == work * units,
+        "none": step["session_work_lanes"] == step["session_lanes"],
+        "some": work * units < step["session_work_lanes"] < step["session_lanes"],
+    }
+    assert booked[narrow], (step, work * units)
 
 
 @pytest.mark.parametrize("n, run", [(64, 8), (100, 8), (256, 16), (37, 4), (8, 8)])

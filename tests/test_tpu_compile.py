@@ -242,8 +242,12 @@ def test_config5_session_at_engine_defaults(one_chip):
 def test_q11_session_cell_step_at_its_shapes(one_chip, monkeypatch):
     """The benchmark cell ``q11_sessions.backlog``'s step at its shapes
     (32,768 lanes, 2^21 slots): its row sort runs in ``SORT_RUN``-row runs
-    inside a loop (one sort of 32,768 rows took 83 s here), so the step
-    compiles under the ceiling, and it counts the work its merge did."""
+    inside a loop (one sort of 32,768 rows took 83 s here), and its merge,
+    store rewrite and emission are one conditional of two branches — over
+    the first ``WORK_LANES`` merged positions and over all 163,840 items —
+    so the step compiles under the ceiling, its emit columns keep their
+    327,680 lanes, and it counts the work its merge did."""
+    from ksql_tpu.ops import session_merge
     from ksql_tpu.runtime import lowering
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -254,12 +258,14 @@ def test_q11_session_cell_step_at_its_shapes(one_chip, monkeypatch):
                    capacity=config["engine_props"]["ksql.batch.capacity"],
                    store_capacity=config["engine_props"]["ksql.state.slots"])
     assert dev.session and (dev.capacity, dev.store_capacity) == (32_768, 1 << 21)
+    assert session_merge.WORK_LANES < 5 * 32_768
     state = _state(dev, one_chip)
     arrays = _on(one_chip, dev.layout.array_structs())
-    _compile(dev._step, state, arrays)
+    text = _compile(dev._step, state, arrays).as_text()
+    assert len(re.findall(r"= .* conditional\(", text)) == 1
     emits = jax.eval_shape(dev._trace_step, state, arrays)[1]
     assert emits["emit_mask"].shape == (2 * 5 * 32_768,)
-    for name in ("session_items", "session_lanes"):
+    for name in ("session_items", "session_lanes", "session_work_lanes"):
         assert emits[name].shape == () and emits[name].dtype == np.int32, name
     assert emits["probe_rounds"].shape == emits["probe_lane_rounds"].shape == ()
 
