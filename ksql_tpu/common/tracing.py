@@ -119,6 +119,11 @@ is never timed and reports no time):
                     its per-item work ran at: ``WORK_LANES`` where the live
                     items fitted it, n x (slots + 1) where they did not
                     (the three summed over the mesh's shards);
+                    a top-K fold's (TOPK / TOPKDISTINCT state,
+                    ``hash_store._vec_topk``): ``topk_rows``, the rows it
+                    took, ``topk_admitted``, the batch's entries in their
+                    slot's new top-K, and ``topk_slots``, the slots it
+                    rewrote (the three summed over the mesh's shards);
                     the load scalars read there: ``occupancy``, slots taken,
                     and ``graves`` among them, the mesh's of its fullest
                     shard;

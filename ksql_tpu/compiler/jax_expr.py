@@ -20,7 +20,7 @@ to its interpreter (InterpretedExpressionFactory).
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +68,10 @@ class DCol:
     # companion per-element payload (histogram counts): decoded as the MAP
     # values parallel to ``data``'s keys
     aux: Optional[jnp.ndarray] = None
+    # an ARRAY<STRUCT> vector column (the variadic TOPK): per struct field
+    # its name, (rows, K) data and (rows, K) element bits; ``valid`` marks
+    # the present entries
+    fields: Optional[Tuple[Tuple[str, jnp.ndarray, jnp.ndarray], ...]] = None
 
     @property
     def hashed(self) -> bool:

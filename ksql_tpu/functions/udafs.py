@@ -198,7 +198,9 @@ def register_all(reg: FunctionRegistry) -> None:
                 {"sort_col": v, **{f"col{i}": e[i] for i in range(extra)}}
                 for v, e, _ in s
             ])(extra),
-            device_kind=None,
+            # on the device: the sort values' top-K state with one width-K
+            # payload column per extra column (numeric extras only)
+            device_kind="topk",
             literal_params=1,
         ))
     reg.register_udaf(Udaf(

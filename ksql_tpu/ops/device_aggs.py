@@ -6,9 +6,10 @@ host Udaf in functions/udafs.py) decomposes into 'add'/'min'/'max' state
 components that hash_store.scatter_combine folds in O(batch) scatters, plus
 a ``finalize`` that maps slot state → output column (the result() analog).
 
-Families whose state is inherently variable-size per key (collect_list,
-topk, histogram, count_distinct exact) have no device decomposition and keep
-the query on the row oracle.
+Families whose state is variable-size per key fold into bounded per-slot
+vector state (collect_list / collect_set capped at their limit, topk at k,
+histogram at 1,000 entries); count_distinct exact has no device
+decomposition and keeps the query on the row oracle.
 """
 
 from __future__ import annotations
@@ -37,7 +38,9 @@ class DeviceAgg:
     components: Tuple[AggComponent, ...]
     # (args, row_active) -> per-component contribution arrays
     contribs: Callable[[Sequence[DCol], jnp.ndarray], List[jnp.ndarray]]
-    # component slot arrays -> (data, valid)
+    # component slot arrays -> (data, valid); a vector result adds its
+    # element bits, a map its counts, and an ARRAY<STRUCT> result is a
+    # whole DCol with its ``fields``
     finalize: Callable[[Sequence[jnp.ndarray]], Tuple[jnp.ndarray, jnp.ndarray]]
     result_type: SqlType
     # when set, table-aggregation undo uses these contributions instead of
@@ -406,12 +409,24 @@ def compile_device_agg(
             result_type=result_type,
         )
     if kind == "topk":
-        # TOPK / TOPKDISTINCT over numerics/temporals: width-k sorted state
+        # TOPK / TOPKDISTINCT over numerics/temporals: width-k sorted state.
+        # The variadic TOPK(sort_col, col0, .., k) adds one width-k payload
+        # component per extra column and one of their null bits; the fold
+        # moves each payload with its sort value
+        # (hash_store._vec_topk) and the result is ARRAY<STRUCT<..>>
         t = arg_types[0]
+        extras = list(arg_types[1:-1])
         if t.base in (SqlBaseType.STRING, SqlBaseType.BYTES):
             raise DeviceUnsupported("string ordering on device")
         if t.base in (SqlBaseType.ARRAY, SqlBaseType.MAP, SqlBaseType.STRUCT):
             raise DeviceUnsupported(f"{fname} over nested types on device")
+        for e in extras:
+            if e.base in (SqlBaseType.STRING, SqlBaseType.BYTES,
+                          SqlBaseType.ARRAY, SqlBaseType.MAP, SqlBaseType.STRUCT):
+                raise DeviceUnsupported(
+                    f"{fname} with a {e.base.name} column beside its sort "
+                    "value on device"
+                )
         k = literals[0] if literals else None
         if not isinstance(k, int) or k <= 0 or k > 256:
             raise DeviceUnsupported(f"{fname} k {k!r} on device")
@@ -422,28 +437,51 @@ def compile_device_agg(
             sentinel = np.iinfo(vdt).min
 
         distinct = fname.upper() == "TOPKDISTINCT"
+        pdts = [_vec_dtype(e) for e in extras]
 
         def tk_contribs(args, act, seq=None):
             v = args[0]
             ok = act & v.valid
-            return [
+            out = [
                 ok.astype(jnp.int32),
                 jnp.where(ok, v.data, sentinel).astype(vdt),
             ]
+            if extras:
+                nulls = jnp.zeros(ok.shape, jnp.int8)
+                for i, (e, pdt) in enumerate(zip(args[1:1 + len(extras)], pdts)):
+                    out.append(jnp.where(ok & e.valid, e.data, 0).astype(pdt))
+                    nulls = nulls | ((~e.valid).astype(jnp.int8) << i)
+                out.append(nulls)
+            return out
 
-        def tk_finalize(comps):
-            count, data = comps
+        def present_of(count, data):
             if distinct:
                 # distinct count isn't tracked; dtype-floor values (-inf /
                 # INT_MIN) read as absent — the one documented parity edge
-                present = data != jnp.asarray(sentinel, data.dtype)
-            else:
-                cnt = jnp.minimum(count, k).astype(jnp.int32)
-                present = (
-                    jnp.arange(k, dtype=jnp.int32)[None, :] < cnt[:, None]
-                )
-            return data, present, present
+                return data != jnp.asarray(sentinel, data.dtype)
+            cnt = jnp.minimum(count, k).astype(jnp.int32)
+            return jnp.arange(k, dtype=jnp.int32)[None, :] < cnt[:, None]
 
+        def tk_finalize(comps):
+            count, data = comps[:2]
+            present = present_of(count, data)
+            if not extras:
+                return data, present, present
+            names = [name for name, _ in result_type.element.fields]
+            nulls = comps[-1]
+            fields = [(names[0], data, present)] + [
+                (names[1 + i], pay, present & ((nulls >> i) & 1 == 0))
+                for i, pay in enumerate(comps[2:-1])
+            ]
+            return DCol(data, present, result_type, elem_valid=present,
+                        fields=tuple(fields))
+
+        payloads = tuple(
+            AggComponent("topk_pay", np.dtype(pdt).name, 0, width=k)
+            for pdt in pdts
+        )
+        if extras:
+            payloads += (AggComponent("topk_pay", "int8", 0, width=k),)
         return DeviceAgg(
             components=(
                 AggComponent("add", "int32", 0),
@@ -451,7 +489,7 @@ def compile_device_agg(
                     "topk", np.dtype(vdt).name, sentinel, width=k,
                     mode="distinct" if distinct else "",
                 ),
-            ),
+            ) + payloads,
             contribs=tk_contribs,
             finalize=tk_finalize,
             result_type=result_type,

@@ -47,11 +47,13 @@ grows it.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ksql_tpu.ops.session_merge import _sort_keys
 
 MAX_PROBES = 1024
 
@@ -97,15 +99,27 @@ class AggComponent:
       component selects the fold: 'append' (collect_list / earliest-N,
       capped at K), 'ring' (latest-N, circular overwrite), 'set'
       (collect_set, membership-deduped append).
-    * ``topk`` — self-contained width-K descending top-K of non-sentinel
-      contributions; ``mode='distinct'`` dedups values (topkdistinct).
+    * ``topk`` — width-K descending top-K of non-sentinel contributions;
+      ``mode='distinct'`` dedups values (topkdistinct).  The ``topk_pay``
+      components that follow it (width K) ride with its values: the
+      variadic TOPK's extra columns and their null bits.
     """
 
-    combine: str  # 'add' | 'min' | 'max' | 'argset' | 'vec_count' | 'vec_data' | 'vec_valid' | 'topk'
+    combine: str  # 'add' | 'min' | 'max' | 'argset' | 'vec_count' | 'vec_data' | 'vec_valid' | 'topk' | 'topk_pay'
     dtype: str  # numpy dtype name
     init: float  # fill value for empty slots
     width: int = 1
     mode: str = ""
+
+    @property
+    def vector(self) -> bool:
+        """The store column is (slots, width): wider than 1, or a vector
+        kind at width 1 (TOPK(v, 1), LATEST_BY_OFFSET(v, 1))."""
+        return self.width > 1 or self.combine in _VECTOR_COMBINES
+
+
+#: component kinds whose store column is (slots, width) at any width
+_VECTOR_COMBINES = ("vec_data", "vec_valid", "hist_count", "topk", "topk_pay")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,7 +153,7 @@ def init_store(layout: StoreLayout) -> Dict[str, jnp.ndarray]:
     for i in range(layout.num_keys):
         store[f"key{i}"] = jnp.zeros(c1, jnp.int64)
     for j, comp in enumerate(layout.components):
-        shape = c1 if comp.width == 1 else (c1, comp.width)
+        shape = (c1, comp.width) if comp.vector else c1
         store[f"a{j}"] = jnp.full(shape, comp.init, dtype=np.dtype(comp.dtype))
     return store
 
@@ -373,15 +387,12 @@ def _slot_ranks(eff: jnp.ndarray) -> jnp.ndarray:
     return jnp.zeros(n, jnp.int32).at[order].set(idx - run_start)
 
 
-def _sort_desc(x: jnp.ndarray) -> jnp.ndarray:
-    return jnp.sort(x, axis=-1)[..., ::-1]
-
-
 def _desc_key(vals: jnp.ndarray) -> jnp.ndarray:
-    """A monotone-decreasing sort key (no overflow at dtype min)."""
+    """A monotone-decreasing sort key (no overflow at dtype min), int64 or
+    float64."""
     if jnp.issubdtype(vals.dtype, jnp.integer):
-        return ~vals
-    return -vals
+        return ~vals.astype(jnp.int64)
+    return -vals.astype(jnp.float64)
 
 
 def _batch_membership(cnt_col, data_col, vbit_col, K, eff0, vals, vbits):
@@ -570,49 +581,125 @@ def _vec_hist(store, layout, j, contribs, slots, dump):
     store[f"a{j + 3}"] = num_col
 
 
-def _vec_topk(store, comp, j, contrib, slots, dump):
-    """Top-K fold: per-slot batch candidates (sorted) merged with the stored
-    K values; sentinel (= comp.init, the dtype floor) marks empty entries."""
+#: touched slots the top-K fold merges at a time (one lane a slot, in a loop
+#: over as many such chunks as the batch touched slots): a served tick of
+#: 4,096 NEXmark bids touches ~115-220 auctions, a fill batch of 32,768 ~2,300
+TOPK_LANES = 512
+
+
+@jax.named_scope("topk_fold")
+def _vec_topk(store, layout, j, contribs, slots, dump):
+    """Top-K fold: components j (the K sort values, descending; sentinel =
+    ``init``, the dtype floor, marks an empty entry) and the ``topk_pay``
+    components after it (width K, moved with their values).
+
+    The batch's entries are ordered by (slot, value descending, arrival)
+    (``session_merge._sort_keys``: runs of ``SORT_RUN`` rows merged
+    pairwise); only a slot's first K of them can enter its list.  Then for
+    each touched slot the stored list and those candidates, both
+    descending, are merged by counting: an entry's new place is its place
+    in its own list plus the entries of the other list that precede it.
+    At a tie the stored entry precedes the batch's and the batch's keep
+    their arrival order, so a new entry that ties the K-th stays out: the
+    order of a stable sort of (stored ++ batch), ksqlDB's ``TopkKudaf``.
+    ``mode='distinct'`` drops a value the slot holds or the batch repeats.
+    Returns (rows folded, entries admitted to a top-K, slots rewritten)."""
+    comp = layout.components[j]
     K = comp.width
-    col = store[f"a{j}"]
-    dt = col.dtype
-    sent = jnp.asarray(comp.init, dt)
-    vals = contrib.astype(dt)
+    n_pay = 0
+    while (j + 1 + n_pay < len(layout.components)
+           and layout.components[j + 1 + n_pay].combine == "topk_pay"):
+        n_pay += 1
+    names = [f"a{j + t}" for t in range(1 + n_pay)]
+    col = store[names[0]]
+    sent = jnp.asarray(comp.init, col.dtype)
+    vals = contribs[j].astype(col.dtype)
     n = vals.shape[0]
-    eff = jnp.where((vals != sent) & (slots != dump), slots, dump)
-    order = jnp.lexsort((jnp.arange(n), _desc_key(vals), eff))
-    so_eff, so_v = eff[order], vals[order]
-    if comp.mode == "distinct":
-        # in-batch dedup BEFORE windowing: duplicates would otherwise
-        # consume candidate-window slots and hide distinct values ranked
-        # past position K
-        dup = (
-            (so_eff == jnp.concatenate([jnp.full((1,), -1, so_eff.dtype), so_eff[:-1]]))
-            & (so_v == jnp.concatenate([so_v[:1], so_v[:-1]]))
-        ).at[0].set(False)
-        so_eff = jnp.where(dup, dump, so_eff)
-        so_v = jnp.where(dup, sent, so_v)
-        order2 = jnp.lexsort((jnp.arange(n), _desc_key(so_v), so_eff))
-        so_eff, so_v = so_eff[order2], so_v[order2]
+    live = (vals != sent) & (slots != dump)
+    eff = jnp.where(live, slots, dump)
+    so_eff, _, rows = _sort_keys((
+        eff.astype(jnp.int64), _desc_key(vals), jnp.arange(n, dtype=jnp.int32)
+    ))
+    so_eff = so_eff.astype(jnp.int32)
+    sorted_cols = [vals[rows]] + [
+        contribs[j + t][rows].astype(store[names[t]].dtype)
+        for t in range(1, 1 + n_pay)
+    ]
     idx = jnp.arange(n, dtype=jnp.int32)
-    prev = jnp.concatenate([jnp.full((1,), -1, so_eff.dtype), so_eff[:-1]])
-    run_start = jax.lax.cummax(jnp.where(so_eff != prev, idx, -1).at[0].set(0))
-    winner = (idx == run_start) & (so_eff != dump)
-    offs = idx[:, None] + jnp.arange(K, dtype=jnp.int32)[None, :]
-    gidx = jnp.minimum(offs, n - 1)
-    cand = jnp.where(
-        (so_eff[gidx] == so_eff[:, None]) & (offs < n), so_v[gidx], sent
-    )
-    allv = jnp.concatenate([cand, col[so_eff]], axis=1)
     if comp.mode == "distinct":
-        s = _sort_desc(allv)
-        dup = jnp.concatenate(
-            [jnp.zeros((n, 1), bool), s[:, 1:] == s[:, :-1]], axis=1
+        # a value repeated inside a slot's run keeps its first entry: the
+        # runs compacted (order kept), so a duplicate takes no candidate place
+        so_v = sorted_cols[0]
+        dup = jnp.concatenate([
+            jnp.zeros(1, bool),
+            (so_eff[1:] == so_eff[:-1]) & (so_v[1:] == so_v[:-1]),
+        ])
+        at = jnp.where(dup, n, jnp.cumsum(~dup, dtype=jnp.int32) - 1)
+        so_eff = jnp.full(n + 1, dump, jnp.int32).at[at].set(so_eff)[:n]
+        sorted_cols = [jnp.full(n + 1, sent).at[at].set(so_v)[:n]]
+    starts = (so_eff != dump) & jnp.concatenate(
+        [jnp.ones(1, bool), so_eff[1:] != so_eff[:-1]]
+    )
+    n_slots = jnp.sum(starts, dtype=jnp.int32)
+    width = min(n, TOPK_LANES)
+    n_chunks = -(-n // width)
+    # the sorted position of each touched slot's first entry, slots in order
+    first = jnp.full(n_chunks * width + 1, n, jnp.int32).at[
+        jnp.where(starts, jnp.cumsum(starts, dtype=jnp.int32) - 1, n_chunks * width)
+    ].set(idx)[:-1]
+    lane = jnp.arange(width, dtype=jnp.int32)[:, None]
+    k_idx = jnp.arange(K, dtype=jnp.int32)
+
+    def merge_chunk(carry):
+        chunk, admitted, cols = carry
+        pos = jax.lax.dynamic_slice_in_dim(first, chunk * width, width)
+        ok = pos < n
+        at = jnp.minimum(pos, n - 1)
+        slot = jnp.where(ok, so_eff[at], dump)
+        offs = at[:, None] + k_idx[None, :]
+        g = jnp.minimum(offs, n - 1)
+        c_live = ok[:, None] & (offs < n) & (so_eff[g] == slot[:, None])
+        cands = [c[g] for c in sorted_cols]
+        stored = [c[slot] for c in cols]
+        s_live = ok[:, None] & (stored[0] != sent)
+        if comp.mode == "distinct":
+            c_live = c_live & ~jnp.any(
+                (cands[0][:, :, None] == stored[0][:, None, :])
+                & s_live[:, None, :], axis=2,
+            )
+        c_key, s_key = _desc_key(cands[0]), _desc_key(stored[0])
+        c_rank = jnp.cumsum(c_live, axis=1, dtype=jnp.int32) - 1 + jnp.sum(
+            s_live[:, None, :] & (s_key[:, None, :] <= c_key[:, :, None]),
+            axis=2, dtype=jnp.int32,
         )
-        allv = jnp.where(dup, sent, s)
-    top = _sort_desc(allv)[:, :K]
-    tgt = jnp.where(winner, so_eff, dump)
-    store[f"a{j}"] = col.at[tgt].set(top)
+        s_rank = k_idx[None, :] + jnp.sum(
+            c_live[:, None, :] & (c_key[:, None, :] < s_key[:, :, None]),
+            axis=2, dtype=jnp.int32,
+        )
+        c_in = c_live & (c_rank < K)
+        c_at = jnp.where(c_in, c_rank, K)
+        s_at = jnp.where(s_live & (s_rank < K), s_rank, K)
+        tgt = jnp.where(ok, slot, dump)
+        new_cols = []
+        for t, (c, cand, old) in enumerate(zip(cols, cands, stored)):
+            merged = (
+                jnp.full((width, K + 1), comp.init if t == 0 else 0, c.dtype)
+                .at[lane, c_at].set(cand)
+                .at[lane, s_at].set(old)
+            )
+            new_cols.append(c.at[tgt].set(merged[:, :K]))
+        return chunk + 1, admitted + jnp.sum(c_in, dtype=jnp.int32), new_cols
+
+    # initial carries derive from varying inputs, as in ``_insert_lanes``
+    zero = jnp.sum((slots * 0).astype(jnp.int32))
+    _, admitted, cols = jax.lax.while_loop(
+        lambda carry: carry[0] * width < n_slots,
+        merge_chunk,
+        (zero, zero, [store[name] for name in names]),
+    )
+    for name, c in zip(names, cols):
+        store[name] = c
+    return jnp.sum(live, dtype=jnp.int32), admitted, n_slots
 
 
 @jax.named_scope("scatter_combine")
@@ -622,6 +709,7 @@ def scatter_combine(
     slots: jnp.ndarray,
     contribs: Sequence[jnp.ndarray],
     vec_undo: bool = False,
+    stats: Optional[Dict[str, jnp.ndarray]] = None,
 ) -> Dict[str, jnp.ndarray]:
     """Fold per-row contributions into the store (KudafAggregator.apply
     analog, batched: duplicate slots accumulate in one scatter).
@@ -630,7 +718,9 @@ def scatter_combine(
     nearest preceding orderable component is combined, the row whose
     contribution equals the slot's NEW order value (unique sequence numbers
     guarantee a single winner) writes the payload.  'vec_count'/'topk' head
-    vector-state groups (collect/topk families, see AggComponent)."""
+    vector-state groups (collect/topk families, see AggComponent).  Where
+    ``stats`` is given, the top-K folds add their counts to it
+    (``topk_rows``, ``topk_admitted``, ``topk_slots``)."""
     store = dict(store)
     dump = jnp.int32(layout.capacity)
     last_order: int = 0
@@ -653,7 +743,13 @@ def scatter_combine(
             j += 3
             continue
         if comp.combine == "topk":
-            _vec_topk(store, comp, j, contrib, slots, dump)
+            counts = _vec_topk(store, layout, j, contribs, slots, dump)
+            if stats is not None:
+                for name, v in zip(("topk_rows", "topk_admitted", "topk_slots"), counts):
+                    stats[name] = stats.get(name, 0) + v
+            j += 1
+            continue
+        if comp.combine == "topk_pay":  # folded with the topk before it
             j += 1
             continue
         ref = col.at[slots]

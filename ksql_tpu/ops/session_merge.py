@@ -79,7 +79,8 @@ def _lex_less(a, b) -> jnp.ndarray:
 
 def _sort_keys(keys: Tuple[jnp.ndarray, ...]) -> Tuple[jnp.ndarray, ...]:
     """``keys`` = (key, ts, row index) sorted by all three (the index makes
-    every triple distinct).  Up to ``SORT_RUN`` items one sort does it;
+    every triple distinct; the top-K fold, ``hash_store._vec_topk``, sorts
+    (slot, value descending, row index) with it).  Up to ``SORT_RUN`` items one sort does it;
     beyond, the items are sorted in runs of ``SORT_RUN`` by one sort inside
     a loop (compiled once), and the runs merged pairwise: an item's place
     in a merged pair is its place in its own run plus the items of the

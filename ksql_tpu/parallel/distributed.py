@@ -32,7 +32,7 @@ from ksql_tpu.compiler.jax_expr import DeviceUnsupported
 from ksql_tpu.parallel.mesh import SHARD_AXIS
 from ksql_tpu.parallel.repartition import all_to_all_exchange, shard_of
 from ksql_tpu.runtime.lowering import (
-    CompiledDeviceQuery, _session_work_of, _sliced_lanes_of,
+    CompiledDeviceQuery, _session_work_of, _sliced_lanes_of, _topk_of,
 )
 from ksql_tpu.runtime.oracle import SinkEmit
 
@@ -527,6 +527,7 @@ class DistributedDeviceQuery:
                 ),
                 **_sliced_lanes_of(emits),
                 **_session_work_of(emits),
+                **_topk_of(emits),
             )
         if "find_rounds" in emits and tracing.active() is not None:
             # a stream-table join's lookups: the longest loop among the

@@ -287,6 +287,44 @@ def _session_work_of(emits: Dict[str, Any]) -> Dict[str, int]:
     }
 
 
+def _topk_of(emits: Dict[str, Any]) -> Dict[str, int]:
+    """A top-K fold's ``topk_rows`` / ``topk_admitted`` / ``topk_slots`` as
+    ``device.step`` counter fields (the mesh: summed over its shards);
+    nothing for a step that has none."""
+    if "topk_rows" not in emits:
+        return {}
+    return {
+        k: int(np.asarray(emits[k]).sum())
+        for k in ("topk_rows", "topk_admitted", "topk_slots")
+    }
+
+
+def _decode_struct_lists(
+    emits: Dict[str, Any], idx: np.ndarray, col, present: np.ndarray,
+    dictionary,
+) -> List[List[Dict[str, Any]]]:
+    """An ARRAY<STRUCT> emit column (``v_<col>.<field>`` / ``e_<col>.<field>``
+    at (rows, K), ``present`` marking the entries) as a list of field dicts
+    a row."""
+    flat = present.reshape(-1)
+    fields = col.type.element.fields
+    values = [
+        decode_value(
+            np.asarray(emits[f"v_{col.name}.{name}"])[idx].reshape(-1)[flat],
+            np.asarray(emits[f"e_{col.name}.{name}"])[idx].reshape(-1)[flat],
+            ftype, dictionary,
+        )
+        for name, ftype in fields
+    ]
+    names = [name for name, _ in fields]
+    structs = [dict(zip(names, entry)) for entry in zip(*values)]
+    out, at = [], 0
+    for count in present.sum(axis=1).tolist():
+        out.append(structs[at:at + count])
+        at += count
+    return out
+
+
 def _sliced_lanes_of(emits: Dict[str, Any]) -> Dict[str, int]:
     """A sliced step's ``sliced_lanes`` as a ``device.step`` counter field
     (the mesh: its busiest shard's); nothing for a step that has none."""
@@ -1463,7 +1501,7 @@ class CompiledDeviceQuery:
                 literals=lits,
             )
             if self.session and any(
-                c.width > 1 for c in device.components
+                c.vector for c in device.components
             ):
                 # session segment-merge folds components pairwise; vector
                 # state (collect/topk) has no pairwise combine formulation
@@ -4317,8 +4355,10 @@ class CompiledDeviceQuery:
                 store, slot_or_dump, payload, contribs
             )
         else:
+            topk_stats: Dict[str, jnp.ndarray] = {}
             store = scatter_combine(
-                store, self.store_layout, slot_or_dump, contribs
+                store, self.store_layout, slot_or_dump, contribs,
+                stats=topk_stats,
             )
         batch_max_ts = jnp.max(
             jnp.where(active, payload["ts"], np.iinfo(np.int64).min)
@@ -4406,6 +4446,9 @@ class CompiledDeviceQuery:
             # load readback): lower-bounds the admission floor ensure_ring_for
             # sizes the ring against
             emits["smax_ts"] = store["max_ts"]
+        else:
+            # a top-K fold's rows, entries admitted and slots rewritten
+            emits.update({k: v.astype(jnp.int32) for k, v in topk_stats.items()})
         return store, emits
 
     def _finalized_env(
@@ -4456,7 +4499,9 @@ class CompiledDeviceQuery:
                 else f"KSQL_AGG_VARIABLE_{i}"
             )
             fin = spec.device.finalize(comps)
-            if len(fin) == 4:  # map result: (keys2d, row_valid, present2d, counts2d)
+            if isinstance(fin, DCol):  # ARRAY<STRUCT> result, by field
+                env[out_name] = fin
+            elif len(fin) == 4:  # map result: (keys2d, row_valid, present2d, counts2d)
                 data, valid, present, counts = fin
                 env[out_name] = DCol(
                     data, present, spec.device.result_type,
@@ -4562,8 +4607,15 @@ class CompiledDeviceQuery:
             d = env.get(col.name)
             if d is None:
                 raise DeviceUnsupported(f"sink column {col.name} not computed on device")
-            out[f"v_{col.name}"] = d.data
             out[f"m_{col.name}"] = d.valid
+            if d.fields is not None:
+                # ARRAY<STRUCT> column: the present entries in m_, each
+                # field's (rows, K) values and element bits beside them
+                for field, data, elem_valid in d.fields:
+                    out[f"v_{col.name}.{field}"] = data
+                    out[f"e_{col.name}.{field}"] = elem_valid
+                continue
+            out[f"v_{col.name}"] = d.data
             if d.data.ndim == 2:  # vector column: per-element null bits
                 out[f"e_{col.name}"] = (
                     d.elem_valid if d.elem_valid is not None else d.valid
@@ -4880,6 +4932,7 @@ class CompiledDeviceQuery:
                 sampled=1,
                 **_sliced_lanes_of(emits),
                 **_session_work_of(emits),
+                **_topk_of(emits),
             )
         headroom = self.capacity * self.expansion
         if self.pipeline:
@@ -5060,8 +5113,15 @@ class CompiledDeviceQuery:
         schema = schema if schema is not None else self._emit_schema()
         cols: Dict[str, List[Any]] = {}
         for col in schema.columns():
-            data = np.asarray(emits[f"v_{col.name}"])[idx]
             valid = np.asarray(emits[f"m_{col.name}"])[idx]
+            if f"v_{col.name}" not in emits:
+                # ARRAY<STRUCT> column emitted by field: a struct a present
+                # entry, regrouped per row
+                cols[col.name] = _decode_struct_lists(
+                    emits, idx, col, valid, self.dictionary
+                )
+                continue
+            data = np.asarray(emits[f"v_{col.name}"])[idx]
             if data.ndim == 2 and f"c_{col.name}" in emits:
                 # map column (histogram): present elements decode as keys,
                 # the count companion as values, regrouped per row

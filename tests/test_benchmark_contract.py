@@ -117,6 +117,20 @@ def _nexmark_q11_sessions_feed(engine, poll):
             poll()
 
 
+def _nexmark_q19_topk_feed(engine, poll):
+    """Bids over a few auctions, prices repeating: each poll's batches
+    rewrite stored top lists, some bids enter them and some do not."""
+    topic = engine.broker.topic("bids")
+    for i in range(N_EVENTS):
+        topic.produce(Record(
+            key=None, timestamp=TS0 + 100 * i,
+            value='{"AUCTION":%d,"BIDDER":%d,"PRICE":%d,"CHANNEL":"Google","URL":"u",'
+                  '"DATETIME":%d,"EXTRA":"x"}' % (1000 + i % 7, 1000 + i % 13,
+                                                 100 + i * i % 97, TS0 + 100 * i)))
+        if (i + 1) % (N_EVENTS // N_POLLS) == 0:
+            poll()
+
+
 class Run:
     """What one configuration's statements booked over a few hundred
     records, driven as the harness's fill drives them: ``poll_once()`` until
@@ -186,6 +200,11 @@ def nexmark_q11_sessions():
 
 
 @pytest.fixture(scope="module")
+def nexmark_q19_topk():
+    return Run("nexmark_q19_topk", _nexmark_q19_topk_feed)
+
+
+@pytest.fixture(scope="module")
 def pageviews_count_mesh4():
     """The same statements and feed on four of ``conftest.py``'s virtual
     devices (``ksql.runtime.backend=distributed``)."""
@@ -212,14 +231,14 @@ def test_layer_metric_reads_what_the_program_books(metric_name, request):
 
 def test_the_harness_finds_what_it_reaches_for(pageviews_count, clicks_users_join,
                                                pageviews_count_mesh4, pageviews_hopping_stats,
-                                               nexmark_q11_sessions):
+                                               nexmark_q11_sessions, nexmark_q19_topk):
     """What ``benchmark/run.py`` takes hold of besides the stages:
     ``FlightRecorder.observer`` and each trace's ``_t0`` and spans
     (``TickLog``), ``KsqlServer.engine_lock`` (the fill, ``_quiescent``) and
     the executor's ``_native_fields``, held to the configuration's
     ``native_ingest``."""
     for run in (pageviews_count, clicks_users_join, pageviews_count_mesh4,
-                pageviews_hopping_stats, nexmark_q11_sessions):
+                pageviews_hopping_stats, nexmark_q11_sessions, nexmark_q19_topk):
         kept = run.stage_stats["tick"]["n"]
         assert kept >= N_POLLS and len(run.traces) == kept
         for trace in run.traces:

@@ -270,6 +270,47 @@ def test_q11_session_cell_step_at_its_shapes(one_chip, monkeypatch):
     assert emits["probe_rounds"].shape == emits["probe_lane_rounds"].shape == ()
 
 
+@pytest.mark.parametrize("form", ["variadic", "plain"])
+def test_q19_topk_cell_step_at_its_shapes(one_chip, monkeypatch, form):
+    """The benchmark cell ``q19_topk.backlog``'s step at its shapes (32,768
+    lanes, 2^21 slots, K = 10): the variadic ``TOPK(PRICE, BIDDER,
+    DATETIME, 10)`` with its two payloads, and the plain ``TOPK(PRICE,
+    10)`` (146 s here while the fold sorted each row's 2K candidates).  The
+    fold's row order runs in ``SORT_RUN``-row runs and its merge is a loop
+    over chunks of touched slots, so the step compiles under the ceiling;
+    its emit columns are (lanes, K) a struct field, and it counts its fold."""
+    from ksql_tpu.runtime import lowering
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark/configs/nexmark_q19_topk.json")) as f:
+        config = json.load(f)
+    statements = list(config["statements"])
+    if form == "plain":
+        statements[1] = statements[1].replace("TOPK(PRICE, BIDDER, DATETIME, 10)",
+                                              "TOPK(PRICE, 10)")
+    monkeypatch.setattr(lowering, "_device_memory_bytes", lambda: HBM_BYTES)
+    dev = _lowered(statements,
+                   capacity=config["engine_props"]["ksql.batch.capacity"],
+                   store_capacity=config["engine_props"]["ksql.state.slots"])
+    assert (dev.capacity, dev.store_capacity) == (32_768, 1 << 21)
+    assert dev.window is None and not dev.session
+    state = _state(dev, one_chip)
+    arrays = _on(one_chip, dev.layout.array_structs())
+    _compile(dev._step, state, arrays)
+    emits = jax.eval_shape(dev._trace_step, state, arrays)[1]
+    assert emits["emit_mask"].shape == (32_768,)
+    assert emits["m_TOP_BIDS"].shape == (32_768, 10)
+    if form == "variadic":
+        assert "v_TOP_BIDS" not in emits
+        for field in ("sort_col", "col0", "col1"):
+            assert emits[f"v_TOP_BIDS.{field}"].shape == (32_768, 10), field
+            assert emits[f"e_TOP_BIDS.{field}"].shape == (32_768, 10), field
+    else:
+        assert emits["v_TOP_BIDS"].shape == (32_768, 10)
+    for name in ("topk_rows", "topk_admitted", "topk_slots"):
+        assert emits[name].shape == () and emits[name].dtype == np.int32, name
+
+
 def test_fused_tap_kernel_sixteen_lanes(one_chip):
     from ksql_tpu.server import tap_kernel
     from ksql_tpu.server.rest import PushQuerySession
